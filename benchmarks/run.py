@@ -18,6 +18,7 @@ from benchmarks import (ablation, arch_partition, batching, bubbles,
                         fig5_dynamic, fig6_fig7_bandwidth, kernels_bench,
                         multihop, multitenant, planner, resilience,
                         roofline, routing, table1_latency, table2_context)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 MODULES = {
     "fig1": fig1_locality,
@@ -49,6 +50,7 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(MODULES))
     ap.add_argument("--out", default="experiments/bench")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(MODULES)
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)

@@ -1,10 +1,12 @@
-"""End-to-end driver: serve a small model with batched requests through the
-full COACH system — offline partition, real JAX end/cloud segments with the
-quantized wire, semantic cache, early exits, adaptive precision, pipeline
-accounting.
+"""End-to-end example: serve a model through the full COACH system —
+offline partition, real JAX end/cloud segments with the quantized wire,
+semantic cache, early exits, adaptive precision, pipeline accounting.
 
   PYTHONPATH=src python examples/collaborative_serving.py \
-      [--arch gemma2-2b] [--requests 200] [--correlation high]
+      [--arch h2o-danube-3-4b] --smoke [--requests 200] [--correlation high]
+
+Without ``--smoke`` the model runs at its published widths (a chip's
+worth of weights).
 """
 
 import sys

@@ -119,7 +119,12 @@ class CollabRuntime:
     ``cut_group`` may be a single group index (classic end->cloud split)
     or an increasing sequence of indices (end -> edge tiers -> cloud, one
     ``WirePacket`` per hop).  ``default_bits`` is likewise an int or a
-    per-hop sequence."""
+    per-hop sequence.
+
+    ``params`` is the full parameter tree, or the per-segment list that
+    ``split_params_multi`` returns for these cuts: splitting inside the
+    jitted program that makes the weights means the full tree never sits
+    on the device beside its segments (``repro.launch.serve``)."""
 
     def __init__(self, cfg: ModelConfig, params,
                  cut_group: Union[int, Sequence[int]],
@@ -134,7 +139,9 @@ class CollabRuntime:
         assert len(bits) == self.n_hops, "need one default_bits per hop"
         self.default_bits_per_hop = bits
         self.default_bits = bits[0]
-        self.p_segments = split_params_multi(params, cfg, self.cuts)
+        self.p_segments = params if isinstance(params, list) \
+            else split_params_multi(params, cfg, self.cuts)
+        assert len(self.p_segments) == self.n_segments
         self._seg_fns = (
             [jax.jit(self._first_forward)]
             + [jax.jit(self._mid_forward)] * (self.n_hops - 1)
@@ -206,12 +213,12 @@ class CollabRuntime:
         pack + semantic probe in a single HBM read of the boundary
         activation (``kernels.boundary``), returning ``(WirePacket,
         BoundaryProbe)`` instead — the probe outputs replace the raw
-        activation, so nothing re-reads the fp32 tensor (which is donated
-        to the fused pass on accelerator backends)."""
+        activation, so nothing re-reads it."""
         if k > 0:
             assert isinstance(x, WirePacket) and x.hop == k - 1, \
                 f"segment {k} consumes the hop-{k - 1} packet"
-            x = x.dequantize()
+            # the receiving tier continues in its own weights' dtype
+            x = x.dequantize(jax.tree.leaves(self.p_segments[k])[0].dtype)
         h = self._seg_fns[k](self.p_segments[k], x)
         if k == self.n_hops:
             return h
@@ -316,7 +323,6 @@ def make_collab_pipeline_step(cfg: ModelConfig, mesh, *, bits: int = 8,
     from jax.sharding import PartitionSpec as P
 
     assert "pod" in mesh.axis_names, "multi-pod mesh required"
-    auto = frozenset(a for a in mesh.axis_names if a != "pod")
 
     def local_groups_fwd(groups, h, positions):
         return _run_groups(groups, h, cfg, positions)
@@ -366,23 +372,13 @@ def make_collab_pipeline_step(cfg: ModelConfig, mesh, *, bits: int = 8,
             # pod 0 holds zeros; reduce so the (replicated) output is pod 1's
             return lax.psum(outs, "pod")
 
-        if hasattr(jax, "shard_map"):  # jax >= 0.6 API
-            fn = jax.shard_map(
-                spmd, mesh=mesh,
-                in_specs=(P("pod"), P()),
-                out_specs=P(),
-                check_vma=False,
-                axis_names=frozenset({"pod"}),
-            )
-        else:  # jax 0.4.x: experimental API (check_rep, auto)
-            from jax.experimental.shard_map import shard_map as _shard_map
-            fn = _shard_map(
-                spmd, mesh=mesh,
-                in_specs=(P("pod"), P()),
-                out_specs=P(),
-                check_rep=False,
-                auto=auto,
-            )
+        fn = jax.shard_map(
+            spmd, mesh=mesh,
+            in_specs=(P("pod"), P()),
+            out_specs=P(),
+            check_vma=False,
+            axis_names=frozenset({"pod"}),
+        )
         # final norm + head on the pipeline output (cloud side)
         h = fn((params["groups"],), tokens)
         h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)

@@ -33,6 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.semantic_cache import HIGHEST, VMEM_LIMIT, seq_block
+from repro.kernels.uaq import pack4
+
 
 def _boundary_kernel(x_ref, c_ref, payload_ref, scale_ref, zp_ref,
                      feat_ref, sep_ref, best_ref, sims_ref, acc_ref, *,
@@ -47,15 +50,8 @@ def _boundary_kernel(x_ref, c_ref, payload_ref, scale_ref, zp_ref,
     scale = jnp.maximum(hi - lo, 1e-8) / qmax
     zp = jnp.round(-lo / scale)
     q = jnp.clip(jnp.round(x / scale + zp), 0.0, qmax).astype(jnp.int32)
-    if bits == 4:
-        if q.shape[2] % 2:
-            # odd channel count: zero-nibble pad in the quantized domain
-            # (scale/zp computed on the true D values stay exact)
-            q = jnp.concatenate([q, jnp.zeros_like(q[..., :1])], axis=2)
-        payload_ref[...] = (q[..., 0::2] | (q[..., 1::2] << 4)
-                            ).astype(jnp.uint8)
-    else:
-        payload_ref[...] = q.astype(jnp.uint8)
+    # int4: the wire's half-split nibble pack (``uaq.pack4``)
+    payload_ref[...] = pack4(q) if bits == 4 else q.astype(jnp.uint8)
     scale_ref[...] = scale
     zp_ref[...] = zp
 
@@ -75,7 +71,8 @@ def _boundary_kernel(x_ref, c_ref, payload_ref, scale_ref, zp_ref,
         c = c_ref[...].astype(jnp.float32)  # (L, D)
         cn = c / jnp.maximum(
             jnp.sqrt(jnp.sum(c * c, axis=1, keepdims=True)), 1e-12)
-        sims = (jnp.dot(fn, cn.T, preferred_element_type=jnp.float32)
+        sims = (jnp.dot(fn, cn.T, precision=HIGHEST,
+                        preferred_element_type=jnp.float32)
                 + 1.0) * 0.5  # Eq. 8 -> [0,1]
         L = sims.shape[1]
         t_h = jnp.max(sims, axis=1)
@@ -108,7 +105,7 @@ def fused_boundary(x: jnp.ndarray, centers: jnp.ndarray, bits: int,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     bb = min(block_b, B)
-    bs = min(block_s, S)
+    bs = seq_block(S, bb, D, block_s)
     pad_b = -B % bb
     pad_s = -S % bs
     if pad_b or pad_s:
@@ -143,6 +140,7 @@ def fused_boundary(x: jnp.ndarray, centers: jnp.ndarray, bits: int,
             jax.ShapeDtypeStruct((Bp, L), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(x, centers)
     return (payload[:B, :S], scale[:B, :S], zp[:B, :S], feat[:B],

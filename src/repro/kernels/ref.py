@@ -6,7 +6,13 @@ shape/dtype/bit sweeps in tests/test_kernels.py (interpret mode on CPU).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# the probe's cosine matmul runs at full f32 accuracy on every backend
+# (the TPU's default f32 matmul rounds its inputs to bf16), so kernel and
+# reference agree on a chip as they do in interpret mode
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ----------------------------------------------------------------- UAQ ref
@@ -26,25 +32,24 @@ def uaq_rowwise_ref(x: jnp.ndarray, bits: int):
 
 
 def pack4_ref(q: jnp.ndarray) -> jnp.ndarray:
-    """Pack uint4 values (..., N) -> (..., ceil(N/2)) bytes, little-nibble
-    first.  An odd channel count is zero-nibble padded: the pad lives in
-    the *quantized* domain (a spare high nibble of the last byte), so the
-    row's scale/zero-point — computed on the true N values — are untouched
-    and ``unpack4_ref(..., n=N)`` recovers the row exactly."""
-    if q.shape[-1] % 2:
-        q = jnp.concatenate(
-            [q, jnp.zeros_like(q[..., :1])], axis=-1)
-    lo = q[..., 0::2].astype(jnp.uint8)
-    hi = q[..., 1::2].astype(jnp.uint8)
-    return lo | (hi << 4)
+    """Pack uint4 values (..., N) -> (..., H) bytes, H = ceil(N/2): byte
+    ``i`` holds channel ``i`` in its low nibble and channel ``i + H`` in
+    its high nibble (the layout of ``uaq.pack4``, written independently
+    here: zero-pad to 2H, then split the channel axis into its halves).
+    An odd N's pad is a zero nibble in the *quantized* domain, so the
+    row's scale/zero-point are untouched and ``unpack4_ref(..., n=N)``
+    recovers the row exactly."""
+    n = q.shape[-1]
+    h = (n + 1) // 2
+    q2 = jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, 2 * h - n)])
+    halves = q2.reshape(*q.shape[:-1], 2, h).astype(jnp.int32)
+    return (halves[..., 0, :] + halves[..., 1, :] * 16).astype(jnp.uint8)
 
 
 def unpack4_ref(p: jnp.ndarray, n: int | None = None) -> jnp.ndarray:
-    """Unpack nibbles (..., P) -> (..., 2P), sliced to the true channel
+    """Unpack nibbles (..., H) -> (..., 2H), sliced to the true channel
     count ``n`` when the producer zero-padded an odd N."""
-    lo = p & 0xF
-    hi = p >> 4
-    q = jnp.stack([lo, hi], axis=-1).reshape(*p.shape[:-1], -1)
+    q = jnp.concatenate([p & 0xF, p >> 4], axis=-1)
     return q if n is None else q[..., :n]
 
 
@@ -81,9 +86,7 @@ def fused_boundary_ref(x: jnp.ndarray, centers: jnp.ndarray, bits: int):
     zp = jnp.round(-lo / scale)
     q = jnp.clip(jnp.round(xf / scale + zp), 0.0, qmax).astype(jnp.int32)
     if bits == 4:
-        if D % 2:
-            q = jnp.concatenate([q, jnp.zeros_like(q[..., :1])], axis=-1)
-        payload = ((q[..., 0::2] | (q[..., 1::2] << 4))).astype(jnp.uint8)
+        payload = pack4_ref(q)
     else:
         payload = q.astype(jnp.uint8)
     f = jnp.sum(xf, axis=1) / S  # GAP (sum-then-divide, like the kernel)
@@ -92,7 +95,8 @@ def fused_boundary_ref(x: jnp.ndarray, centers: jnp.ndarray, bits: int):
     c = centers.astype(jnp.float32)
     cn = c / jnp.maximum(
         jnp.sqrt(jnp.sum(c * c, axis=1, keepdims=True)), 1e-12)
-    sims = (jnp.dot(fn, cn.T, preferred_element_type=jnp.float32)
+    sims = (jnp.dot(fn, cn.T, precision=HIGHEST,
+                    preferred_element_type=jnp.float32)
             + 1.0) * 0.5  # Eq. 8 -> [0,1]
     L = sims.shape[1]
     t_h = jnp.max(sims, axis=1)
@@ -115,7 +119,8 @@ def semantic_probe_ref(x: jnp.ndarray, centers: jnp.ndarray):
     fn = f / jnp.maximum(jnp.linalg.norm(f, axis=1, keepdims=True), 1e-12)
     cn = centers.astype(jnp.float32)
     cn = cn / jnp.maximum(jnp.linalg.norm(cn, axis=1, keepdims=True), 1e-12)
-    sims = (fn @ cn.T + 1.0) * 0.5  # Eq. 8, mapped to [0,1]
+    sims = (jnp.dot(fn, cn.T, precision=HIGHEST)
+            + 1.0) * 0.5  # Eq. 8, mapped to [0,1]
     t_h = jnp.max(sims, axis=1)
     best = jnp.argmax(sims, axis=1).astype(jnp.int32)
     masked = jnp.where(

@@ -21,6 +21,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the probe's cosine matmul runs at full f32 accuracy (the TPU's default
+# f32 matmul rounds its inputs to bf16), as the jnp reference's does
+HIGHEST = jax.lax.Precision.HIGHEST
+
+# f32 bytes of one (bb, bs, D) working tile: the sequence block shrinks
+# as D grows so the double-buffered input tile and the kernel body's f32
+# temporaries stay inside VMEM_LIMIT at production widths (D = 3840 gives
+# bs = 32 at bb = 8), while test-sized D keeps a single sequence block
+TILE_BYTES = 4 << 20
+VMEM_LIMIT = 32 << 20  # scoped VMEM the (B, S) tiled kernels may use
+
+
+def seq_block(S: int, bb: int, D: int, block_s: int) -> int:
+    """Sequence rows per grid step: at most ``block_s`` and the largest
+    multiple of 8 whose f32 (bb, bs, D) tile fits ``TILE_BYTES`` (or the
+    whole sequence, when that is shorter)."""
+    fit = max(8, TILE_BYTES // (4 * bb * D) // 8 * 8)
+    return min(S, block_s, fit)
+
 
 def _probe_kernel(x_ref, c_ref, sep_ref, best_ref, sims_ref, acc_ref, *,
                   n_s_blocks: int, seq_len: int):
@@ -40,7 +59,8 @@ def _probe_kernel(x_ref, c_ref, sep_ref, best_ref, sims_ref, acc_ref, *,
         c = c_ref[...].astype(jnp.float32)  # (L, D)
         cn = c / jnp.maximum(
             jnp.sqrt(jnp.sum(c * c, axis=1, keepdims=True)), 1e-12)
-        sims = (jnp.dot(fn, cn.T, preferred_element_type=jnp.float32)
+        sims = (jnp.dot(fn, cn.T, precision=HIGHEST,
+                        preferred_element_type=jnp.float32)
                 + 1.0) * 0.5  # Eq. 8 -> [0,1]
         L = sims.shape[1]
         t_h = jnp.max(sims, axis=1)
@@ -69,7 +89,7 @@ def semantic_probe(x: jnp.ndarray, centers: jnp.ndarray,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     bb = min(block_b, B)
-    bs = min(block_s, S)
+    bs = seq_block(S, bb, D, block_s)
     pad_b = -B % bb
     pad_s = -S % bs
     if pad_b or pad_s:
@@ -94,6 +114,7 @@ def semantic_probe(x: jnp.ndarray, centers: jnp.ndarray,
             jax.ShapeDtypeStruct((Bp, L), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(x, centers)
     return sep[:B, 0], best[:B, 0], sims[:B]

@@ -10,7 +10,11 @@ TPU adaptation (vs the paper's GPU/CPU quantizer):
     resident in VMEM (lane-aligned, N % 128 == 0 for production shapes);
   - reductions run on the VPU over the 128-lane axis;
   - int4 values are packed two-per-byte with shift/or on int32 then cast,
-    halving ICI/DCN bytes (the roofline's collective term).
+    halving ICI/DCN bytes (the roofline's collective term); byte ``i``
+    pairs channel ``i`` with channel ``i + ceil(N/2)`` (``pack4``), so
+    packing and unpacking use contiguous lane slices only;
+  - any row count M: rows are zero-padded up to a block multiple and the
+    pad rows sliced off.
 
 Validated against ``ref.uaq_*`` in interpret mode (tests/test_kernels.py).
 """
@@ -24,6 +28,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def pack4(q: jnp.ndarray) -> jnp.ndarray:
+    """The int4 wire format: uint4 values (..., N) -> (..., H) bytes,
+    H = ceil(N/2), byte ``i`` holding channel ``i`` in its low nibble and
+    channel ``i + H`` in its high nibble.  Two contiguous half slices are
+    what Mosaic lowers (a stride-2 channel slice is not).  An odd N pads
+    one zero nibble in the *quantized* domain, so the row's scale and
+    zero-point, computed on the true N values, are untouched."""
+    h = (q.shape[-1] + 1) // 2
+    lo, hi = q[..., :h], q[..., h:]
+    if hi.shape[-1] < h:
+        hi = jnp.concatenate([hi, jnp.zeros_like(lo[..., :1])], axis=-1)
+    return (lo | (hi << 4)).astype(jnp.uint8)
+
+
 def _quant_kernel(x_ref, out_ref, scale_ref, zp_ref, *, bits: int):
     x = x_ref[...].astype(jnp.float32)  # (bm, N)
     qmax = float((1 << bits) - 1)
@@ -32,17 +50,8 @@ def _quant_kernel(x_ref, out_ref, scale_ref, zp_ref, *, bits: int):
     scale = jnp.maximum(hi - lo, 1e-8) / qmax
     zp = jnp.round(-lo / scale)
     q = jnp.clip(jnp.round(x / scale + zp), 0.0, qmax).astype(jnp.int32)
-    if bits == 4:
-        if q.shape[1] % 2:
-            # odd channel count: pad one zero *nibble* (quantized domain),
-            # so scale/zp — computed on the true N values above — are
-            # untouched; the consumer slices back with the true N
-            q = jnp.concatenate([q, jnp.zeros_like(q[:, :1])], axis=1)
-        lo_nib = q[:, 0::2]
-        hi_nib = q[:, 1::2]
-        out_ref[...] = (lo_nib | (hi_nib << 4)).astype(jnp.uint8)
-    else:
-        out_ref[...] = q.astype(jnp.uint8)
+    # int4: the consumer slices an odd N's pad nibble back off with N
+    out_ref[...] = pack4(q) if bits == 4 else q.astype(jnp.uint8)
     scale_ref[...] = scale
     zp_ref[...] = zp
 
@@ -50,15 +59,26 @@ def _quant_kernel(x_ref, out_ref, scale_ref, zp_ref, *, bits: int):
 def _dequant_kernel(p_ref, scale_ref, zp_ref, out_ref, *, bits: int,
                     out_dtype, n: int):
     p = p_ref[...].astype(jnp.int32)
+    scale, zp = scale_ref[...], zp_ref[...]
+
+    def dq(q):
+        return ((q.astype(jnp.float32) - zp) * scale).astype(out_dtype)
+
     if bits == 4:
-        lo = p & 0xF
-        hi = p >> 4
-        bm, half = p.shape
-        q = jnp.stack([lo, hi], axis=-1).reshape(bm, half * 2)[:, :n]
+        # low nibbles are channels [0, H), high nibbles [H, n): two
+        # lane-contiguous stores, no interleave
+        h = p.shape[1]
+        out_ref[:, :h] = dq(p & 0xF)
+        out_ref[:, h:] = dq(p >> 4)[:, :n - h]
     else:
-        q = p
-    x = (q.astype(jnp.float32) - zp_ref[...]) * scale_ref[...]
-    out_ref[...] = x.astype(out_dtype)
+        out_ref[...] = dq(p)
+
+
+def _row_block(M: int, block_m: int):
+    """Rows per grid step and the zero-row pad that makes them divide M
+    (pad rows quantize to a constant row and are sliced off)."""
+    bm = min(block_m, M)
+    return bm, -M % bm
 
 
 def uaq_quantize(x: jnp.ndarray, bits: int, block_m: int = 256,
@@ -70,13 +90,14 @@ def uaq_quantize(x: jnp.ndarray, bits: int, block_m: int = 256,
     M, N = x.shape
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    bm = min(block_m, M)
-    assert M % bm == 0, f"M={M} % block_m={bm}"
+    bm, pad = _row_block(M, block_m)
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    Mp = M + pad
     n_out = (N + 1) // 2 if bits == 4 else N
-    grid = (M // bm,)
-    return pl.pallas_call(
+    packed, scale, zp = pl.pallas_call(
         functools.partial(_quant_kernel, bits=bits),
-        grid=grid,
+        grid=(Mp // bm,),
         in_specs=[pl.BlockSpec((bm, N), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((bm, n_out), lambda i: (i, 0)),
@@ -84,12 +105,13 @@ def uaq_quantize(x: jnp.ndarray, bits: int, block_m: int = 256,
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((M, n_out), jnp.uint8),
-            jax.ShapeDtypeStruct((M, 1), jnp.float32),
-            jax.ShapeDtypeStruct((M, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Mp, n_out), jnp.uint8),
+            jax.ShapeDtypeStruct((Mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Mp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
+    return packed[:M], scale[:M], zp[:M]
 
 
 def uaq_dequantize(packed: jnp.ndarray, scale: jnp.ndarray, zp: jnp.ndarray,
@@ -103,19 +125,22 @@ def uaq_dequantize(packed: jnp.ndarray, scale: jnp.ndarray, zp: jnp.ndarray,
     assert N <= n_in * 8 // bits
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    bm = min(block_m, M)
-    assert M % bm == 0
-    grid = (M // bm,)
-    return pl.pallas_call(
+    bm, pad = _row_block(M, block_m)
+    if pad:
+        packed, scale, zp = (jnp.pad(a, ((0, pad), (0, 0)))
+                             for a in (packed, scale, zp))
+    Mp = M + pad
+    out = pl.pallas_call(
         functools.partial(_dequant_kernel, bits=bits, out_dtype=out_dtype,
                           n=N),
-        grid=grid,
+        grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, n_in), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
         interpret=interpret,
     )(packed, scale, zp)
+    return out[:M]

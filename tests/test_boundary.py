@@ -226,3 +226,81 @@ def test_async_engine_decisions_identical_with_fused_probes():
     assert s.mean_bits == a.mean_bits
     assert s.accuracy == a.accuracy
     assert abs(s.pipeline.makespan - a.pipeline.makespan) < 1e-6
+
+
+# ------------------------------------------------ int4 wire layout, ragged
+@pytest.mark.parametrize("n", [6, 5])
+def test_pack4_pairs_channel_halves(n):
+    """Wire format: byte i carries channel i (low nibble) and channel
+    i + ceil(n/2) (high nibble); an odd n leaves the last high nibble 0.
+    The kernels' pack and the reference's are written apart, so both are
+    pinned to this numpy layout."""
+    from repro.kernels.uaq import pack4
+    q = (np.arange(n) % 16).astype(np.uint8)[None]
+    h = (n + 1) // 2
+    hi = np.concatenate([q[0, h:], np.zeros(2 * h - n, np.uint8)])
+    want = q[0, :h] | (hi << 4)
+    for pack in (ref.pack4_ref, pack4):
+        packed = np.asarray(pack(jnp.asarray(q)))
+        np.testing.assert_array_equal(packed[0], want)
+        np.testing.assert_array_equal(
+            np.asarray(ref.unpack4_ref(jnp.asarray(packed), n=n)), q)
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("M", [300, 13])
+def test_uaq_kernel_pads_ragged_rows(M, bits):
+    """Any row count runs: rows are zero-padded to the row block and
+    sliced off, leaving the real rows as the block-aligned call gives
+    them, and the round trip stays within half a quantum."""
+    from repro.kernels.uaq import uaq_dequantize, uaq_quantize
+    x = jax.random.normal(jax.random.PRNGKey(5), (512, 66)) * 2.0
+    p, s, z = uaq_quantize(x[:M], bits, interpret=True)
+    pa, sa, za = uaq_quantize(x, bits, interpret=True)
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(pa[:M]))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sa[:M]))
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(za[:M]))
+    y = uaq_dequantize(p, s, z, bits, n=66, interpret=True)
+    assert y.shape == (M, 66)
+    err = np.abs(np.asarray(y) - np.asarray(x[:M]))
+    assert (err <= np.asarray(s) * 0.5 * (1 + 1e-3)).all()
+
+
+def test_wire_calls_counted_by_path():
+    """``ops.PATHS`` records which path every wire call took: 4/8 bits
+    with the kernel on are Pallas (compiled on TPU, interpret mode
+    elsewhere), other widths and off-TPU fused passes the jnp
+    reference."""
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 32))
+    c = jax.random.normal(jax.random.PRNGKey(7), (3, 32))
+    before = ops.PATHS.copy()
+    ops.quantize_activation(x, 8)
+    ops.quantize_activation(x, 6)
+    p, s, z = ops.quantize_activation(x, 4, use_kernel=False)
+    ops.dequantize_activation(p, s, z, 4, channels=32)
+    ops.boundary_pass(x, c, 8)
+    got = ops.PATHS - before
+    tpu = jax.default_backend() == "tpu"
+    kernel = "pallas" if tpu else "interpret"
+    assert got == {("quantize", 8, kernel): 1, ("quantize", 6, "ref"): 1,
+                   ("quantize", 4, "ref"): 1,
+                   ("dequantize", 4, kernel): 1,
+                   ("boundary", 8, "pallas" if tpu else "ref"): 1}
+
+
+def test_runtime_from_presplit_bf16_segments():
+    """The runtime takes the per-segment list ``split_params_multi``
+    returns (made under one jit, as ``repro.launch.serve`` does) and the
+    receiving tier continues in the weights' dtype."""
+    from repro.core.collab import split_params_multi
+    cfg = get_config("h2o-danube-3-4b").reduced()
+    params = M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
+    segs = jax.jit(lambda p: split_params_multi(p, cfg, (1,)))(params)
+    rt_s = CollabRuntime(cfg, segs, cut_group=1)
+    rt_p = CollabRuntime(cfg, params, cut_group=1)
+    x = _inputs(cfg, jax.random.PRNGKey(8))
+    pkt, _ = rt_s.end_step(x, bits=4)
+    out = rt_s.cloud_step(pkt)
+    assert out.dtype == jnp.bfloat16 and out.shape == (2, cfg.vocab_size)
+    np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(
+        rt_p.cloud_step(rt_p.end_step(x, bits=4)[0]), np.float32))
