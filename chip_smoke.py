@@ -20,7 +20,7 @@ chip this one holds):
      without it: relative error < 0.05 (the bound of tests/test_collab.py).
 
 Earlier lines report the device, set-up and compile seconds, requests and
-wall time per request, peak device memory, and the path (pallas / ref)
+the stream's wall time, peak device memory, and the path (pallas / ref)
 of every wire call.  The last line is one JSON object,
 ``{"ok": true, "device": {...}}``.  Without a TPU, or outside a checkout
 of this repository, it exits non-zero before printing any result.
@@ -145,8 +145,7 @@ def main() -> int:
         f"dtype={jax.tree.leaves(rt.p_segments[0])[0].dtype} "
         f"cut_group={rt.cut}/{cfg.num_groups}")
     log(f"init_s={served.init_s:.3f} compile_s={served.warmup_s:.3f} "
-        f"requests={args.requests} wall_s={served.wall_s:.3f} "
-        f"per_request_ms={served.wall_s / args.requests * 1e3:.3f}")
+        f"requests={args.requests} wall_s={served.wall_s:.3f}")
     check(len(stats.pipeline.tasks) == args.requests, "request count")
     paths = dict(sorted(ops.PATHS.items()))
     log("wire_calls " + " ".join(f"{op}/{b}b/{p}={n}"

@@ -34,6 +34,7 @@ from repro.kernels import ops as KOPS
 from repro.models import layers as L
 from repro.models import model as M
 from repro.models.config import ModelConfig
+from repro.obs import served
 
 
 # ---------------------------------------------------------------- splitting
@@ -219,7 +220,11 @@ class CollabRuntime:
                 f"segment {k} consumes the hop-{k - 1} packet"
             # the receiving tier continues in its own weights' dtype
             x = x.dequantize(jax.tree.leaves(self.p_segments[k])[0].dtype)
-        h = self._seg_fns[k](self.p_segments[k], x)
+        if served.on():
+            with served.span(served.SEGMENT, k=k):
+                h = self._seg_fns[k](self.p_segments[k], x)
+        else:
+            h = self._seg_fns[k](self.p_segments[k], x)
         if k == self.n_hops:
             return h
         if centers is not None:

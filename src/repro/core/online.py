@@ -17,6 +17,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import served
+
 
 def gap_features(x: np.ndarray, layout: Optional[str] = None) -> np.ndarray:
     """Global Average Pooling: (C,H,W) -> (C,)  or (S,D) -> (D,)  or batched
@@ -169,14 +171,32 @@ class ProbeResult:
         space.  ``valid`` is the trained-label index map; with fewer
         than two trained centers there is no genuine second-highest
         degree, so the separability is forced to 0 (never
-        exit-eligible), matching ``separability``."""
+        exit-eligible), matching ``separability``.  Each of the three
+        reads is a device-to-host pull when the inputs live on a device."""
+        if served.on():
+            with served.span(served.PROBE_RESULT):
+                return cls._lift(sims, sep, best, valid, n_labels, _pull)
+        return cls._lift(sims, sep, best, valid, n_labels, _read)
+
+    @classmethod
+    def _lift(cls, sims, sep, best, valid, n_labels: int, pull
+              ) -> "ProbeResult":
         full = np.zeros(n_labels)
         valid = np.asarray(valid)
         if valid.size:
-            full[valid] = np.asarray(sims, dtype=float)
-        b = int(valid[int(best)]) if valid.size else 0
-        s = float(sep) if valid.size >= 2 else 0.0
+            full[valid] = pull("sims", np.asarray, sims)
+        b = int(valid[pull("best", int, best)]) if valid.size else 0
+        s = pull("sep", float, sep) if valid.size >= 2 else 0.0
         return cls(sims=full, sep=s, best=b)
+
+
+def _read(site: str, read, x):
+    return read(x)
+
+
+def _pull(site: str, read, x):
+    with served.span(served.D2H, site=site):
+        return read(x)
 
 
 @dataclasses.dataclass
@@ -353,6 +373,9 @@ class OnlineScheduler:
         ``ProbeResult.from_fused``."""
         cache = self.cache if segment == 0 \
             else self.hop_probes[segment - 1].cache
+        if served.on():
+            with served.span(served.PROBE_CENTERS):
+                return cache.trained_view()
         return cache.trained_view()
 
     def step(self, feat: np.ndarray, bandwidth_bps: Optional[float] = None,

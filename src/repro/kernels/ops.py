@@ -29,6 +29,7 @@ from repro.kernels import ref
 from repro.kernels.boundary import fused_boundary
 from repro.kernels.uaq import uaq_dequantize, uaq_quantize
 from repro.kernels.semantic_cache import semantic_probe
+from repro.obs import served
 
 KERNEL_BITS = (4, 8)
 
@@ -77,6 +78,11 @@ def dequantize_activation(packed, scale, zp, bits: int = 8,
     """``channels`` is the true channel count when the 4-bit payload was
     packed from an odd N (defaults to the payload's full width)."""
     kernel = _count("dequantize", bits, use_kernel and bits in KERNEL_BITS)
+    if served.on():
+        with served.span(served.DEQUANTIZE):
+            return _dequantize(packed, scale, zp, bits=bits,
+                               out_dtype=out_dtype, use_kernel=kernel,
+                               channels=channels)
     return _dequantize(packed, scale, zp, bits=bits, out_dtype=out_dtype,
                        use_kernel=kernel, channels=channels)
 
@@ -120,6 +126,9 @@ def boundary_pass(x, centers, bits: int = 8, use_kernel: bool = True):
     produces the wire packet fields *and* the semantic-probe outputs."""
     kernel = _count("boundary", bits, use_kernel and bits in KERNEL_BITS
                     and jax.default_backend() == "tpu")
+    if served.on():
+        with served.span(served.BOUNDARY):
+            return _boundary_fn(int(bits), kernel)(x, centers)
     return _boundary_fn(int(bits), kernel)(x, centers)
 
 

@@ -1,0 +1,161 @@
+"""Spans of the served request path on a real clock, for the profiler.
+
+``trace.py`` records virtual-clock spans for the simulator pin; this
+module records what the host does per request while a profiler session
+runs (``jax.profiler.trace`` / ``start_trace``): each span opens a
+``jax.profiler.TraceAnnotation``, so it sits in the trace's host plane
+beside the device's ops, and is copied into a bounded in-memory buffer
+stamped on the profiler's own host clock (``time.time_ns``; the trace
+counts from its ``profile_start_time``).  Span names (the closed
+vocabulary):
+
+=======================  ===================================================
+``coach.decide``         one request's decision; sets the current request
+                         id, which every later span carries until the next
+``coach.step``           the Eq. 10/11 decision on the host
+``coach.plan``           the per-task plan (``plan_for``)
+``coach.account``        accounting and center update
+``coach.probe_centers``  the trained-center view a fused pass probes
+``coach.segment``        dispatch of segment ``k`` (meta ``k``)
+``coach.boundary``       dispatch of the fused boundary pass
+``coach.dequantize``     dispatch of the wire dequantize
+``coach.probe_result``   lifting the fused probe to label space
+``coach.d2h``            one device-to-host pull: the wait plus the copy
+                         (meta ``site``)
+``coach.gc``             a collector pause (meta ``generation``)
+=======================  ===================================================
+
+Counts are counts of spans.  Sites follow ``trace.py``'s contract of
+zero cost when disabled: each is guarded by ``if on():`` (one call,
+``TraceAnnotation.is_enabled``), so with no profiler session a site
+allocates and records nothing.  The buffer, like the profiler session,
+is one per process; spans are for the serving thread.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import gc
+import time
+from typing import NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+DECIDE = "coach.decide"
+STEP = "coach.step"
+PLAN = "coach.plan"
+ACCOUNT = "coach.account"
+PROBE_CENTERS = "coach.probe_centers"
+SEGMENT = "coach.segment"
+BOUNDARY = "coach.boundary"
+DEQUANTIZE = "coach.dequantize"
+PROBE_RESULT = "coach.probe_result"
+D2H = "coach.d2h"
+GC = "coach.gc"
+
+SPANS = (DECIDE, STEP, PLAN, ACCOUNT, PROBE_CENTERS, SEGMENT, BOUNDARY,
+         DEQUANTIZE, PROBE_RESULT, D2H, GC)
+
+MAX_SPANS = 1 << 18
+
+on = TraceAnnotation.is_enabled
+
+
+class Span(NamedTuple):
+    name: str
+    req: Optional[int]       # request id current when the span opened
+    parent: Optional[int]    # index of the enclosing span in ``spans``
+    t0: int                  # ns, ``time.time_ns``
+    t1: Optional[int]        # None while the span is open
+    meta: dict
+
+
+class Recording(NamedTuple):
+    spans: tuple
+    dropped: int             # spans not kept: the buffer was full
+
+
+_buf: list = []
+_stack: list = []            # (index or None, entry or None) of open spans
+_dropped = 0
+_req: Optional[int] = None
+
+
+def _open(name: str, meta: dict) -> Optional[list]:
+    global _dropped
+    parent = _stack[-1][0] if _stack else None
+    if len(_buf) >= MAX_SPANS:
+        _dropped += 1
+        _stack.append((None, None))
+        return None
+    entry = [name, _req, parent, 0, None, meta]
+    _buf.append(entry)
+    _stack.append((len(_buf) - 1, entry))
+    return entry
+
+
+class _Recording:
+    """One span while the profiler runs.  The annotation stamps its start
+    when it is made; the copy's start is stamped right after, with no
+    allocation between, so that no collection falls between the two."""
+
+    __slots__ = ("name", "meta", "ann")
+
+    def __init__(self, name: str, meta: dict):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        entry = _open(self.name, self.meta)
+        ann = TraceAnnotation(self.name, **self.meta)
+        if entry is not None:
+            entry[3] = time.time_ns()
+        self.ann = ann
+        ann.__enter__()
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        _, entry = _stack.pop()
+        if entry is not None:
+            entry[4] = time.time_ns()
+
+
+_OFF = contextlib.nullcontext()
+
+
+def span(name: str, req: Optional[int] = None, **meta):
+    """Context manager for one span; ``req`` makes that request current.
+    A no-op while no profiler session runs."""
+    global _req
+    if not on():
+        return _OFF
+    if req is not None:
+        _req = req
+    return _Recording(name, meta)
+
+
+_gc_open: list = []
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        if on():
+            pause = _Recording(GC, {"generation": info["generation"]})
+            pause.__enter__()
+            _gc_open.append(pause)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)
+
+
+def recorded() -> Recording:
+    return Recording(tuple(Span(*e) for e in _buf), _dropped)
+
+
+def clear() -> None:
+    """Empty the buffer; call it outside every span."""
+    global _dropped, _req
+    _buf.clear()
+    _dropped = 0
+    _req = None

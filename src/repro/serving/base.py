@@ -23,6 +23,7 @@ from repro.core import sim
 from repro.core.costs import DeviceProfile, LinkProfile
 from repro.core.pipeline import PipelineResult, TaskPlan
 from repro.core.schedule import StageTimes
+from repro.obs import served
 
 
 @dataclasses.dataclass
@@ -233,19 +234,31 @@ class EngineBase:
         precomputed Eq. 8-10 outputs instead of re-deriving similarities
         from the features — the single HBM read that quantized the wire
         packet also decided the task."""
+        if served.on():
+            with served.span(served.DECIDE, req=task.id):
+                return self._decide(task, bw, classify)
+        return self._decide(task, bw, classify)
+
+    def _decide(self, task, bw: float, classify):
         out = classify(task)
         feats, pred = out[0], out[1]
         probes = out[2] if len(out) > 2 else None
         if probes is not None and isinstance(probes, ON.ProbeResult):
             probes = (probes,)
         hop_feats = self._hop_feats(feats)
-        if self.sched.hop_probes:
-            dec = self.sched.step_cascade(hop_feats, bandwidth_bps=bw,
-                                          probes=probes)
+        if served.on():
+            with served.span(served.STEP):
+                dec = self._step(hop_feats, bw, probes)
         else:
-            dec = self.sched.step(hop_feats[0], bandwidth_bps=bw,
-                                  probe=probes[0] if probes else None)
+            dec = self._step(hop_feats, bw, probes)
         return dec, feats, pred
+
+    def _step(self, hop_feats, bw: float, probes) -> ON.OnlineDecision:
+        if self.sched.hop_probes:
+            return self.sched.step_cascade(hop_feats, bandwidth_bps=bw,
+                                           probes=probes)
+        return self.sched.step(hop_feats[0], bandwidth_bps=bw,
+                               probe=probes[0] if probes else None)
 
     def plan_for(self, dec: ON.OnlineDecision, bw: float,
                  hop_bits: Optional[Sequence[int]] = None
@@ -260,6 +273,14 @@ class EngineBase:
         A hop-level exit (``dec.exit_hop = k >= 1``) carries full-length
         stage durations plus the exit marker: the executors run compute
         ``0..k`` / links ``0..k-1`` and release everything downstream."""
+        if served.on():
+            with served.span(served.PLAN):
+                return self._plan_for(dec, bw, hop_bits)
+        return self._plan_for(dec, bw, hop_bits)
+
+    def _plan_for(self, dec: ON.OnlineDecision, bw: float,
+                  hop_bits: Optional[Sequence[int]]
+                  ) -> Tuple[TaskPlan, float]:
         st = self.st
         bf = self.batch_fixed
         if dec.early_exit:
@@ -297,6 +318,13 @@ class EngineBase:
         sync, async, and multi-tenant engines, so the three can never
         diverge).  ``acc`` accumulates ``exits`` (int), ``wire`` (float,
         bits), ``bits`` (list), ``correct`` (list)."""
+        if served.on():
+            with served.span(served.ACCOUNT):
+                return self._account(dec, feats, pred, task, wire_bits, acc)
+        return self._account(dec, feats, pred, task, wire_bits, acc)
+
+    def _account(self, dec: ON.OnlineDecision, feats, pred, task,
+                 wire_bits: float, acc: dict) -> None:
         hop_feats = self._hop_feats(feats)
         if dec.exit_hop == 0:         # classic end-device exit: no wire
             acc["exits"] += 1
