@@ -18,6 +18,14 @@ chip this one holds):
      half a quantum per element, at 4 and 8 bits.
   4. Logits through the 8-bit wire against the same segments chained
      without it: relative error < 0.05 (the bound of tests/test_collab.py).
+  5. Attention: both segments, traced at the benchmark's 2048 tokens,
+     take the fused flash kernel (``ops.PATHS`` "pallas", a
+     ``coach_flash_attention`` call in each program); the kernel at
+     danube's shape against the jnp reference, both measured from a
+     float32 reference at HIGHEST precision.
+
+Run it with the machine's ``LIBTPU_INIT_ARGS`` as they are: they carry a
+compiler workaround that guards kernel numerics.
 
 Earlier lines report the device, set-up and compile seconds, requests and
 the stream's wall time, peak device memory, and the path (pallas / ref)
@@ -45,6 +53,8 @@ FEAT_RTOL = 1e-5    # of max |feat|
 SIMS_ATOL = 1e-5    # similarities in [0, 1]; both dots at HIGHEST
 SEP_RTOL = 1e-3     # separability divides by the top-2 gap
 SPLIT_RTOL = 0.05   # phase 4, 8-bit wire
+ATTN_S = 2048       # phase 5: the benchmark's prompt length
+ATTN_RTOL = 1e-2    # phase 5, of max |out|: twice the bf16 reference's own
 
 
 def log(msg: str) -> None:
@@ -113,6 +123,61 @@ def split_vs_chain(rt, tokens, bits):
     return rel
 
 
+def segments_take_kernel(rt):
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import attention, ops
+
+    att = {k: n for k, n in sorted(ops.PATHS.items()) if k[0] == "attention"}
+    log("attention_traces " + " ".join(f"S{s}/{p}={n}"
+                                       for (_, s, p), n in att.items()))
+    check(all(p == "pallas" for _, _, p in att), "a served attention "
+          "layer did not take the flash kernel")
+    D = rt.cfg.d_model
+    inputs = [jax.ShapeDtypeStruct((1, ATTN_S), jnp.int32),
+              jax.ShapeDtypeStruct((1, ATTN_S, D), jnp.bfloat16)]
+    for k, fn in ((0, rt._first_forward), (rt.n_hops, rt._last_forward)):
+        before = ops.PATHS.copy()
+        jaxpr = str(jax.make_jaxpr(fn)(rt.p_segments[k], inputs[min(k, 1)]))
+        took = {p: n for (op, s, p), n in (ops.PATHS - before).items()
+                if op == "attention" and s == ATTN_S}
+        log(f"segment {k} S={ATTN_S} attention_paths={took} "
+            f"kernel_calls={jaxpr.count(attention.NAME)}")
+        check(set(took) == {"pallas"}, f"segment {k} attention path {took}")
+        check(attention.NAME in jaxpr, f"segment {k} has no flash kernel")
+
+
+def attention_vs_ref(cfg):
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import attention, ref
+
+    H, KV, hd, W = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                    cfg.sliding_window)
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q, k, v = (jax.random.normal(key, (1, ATTN_S, n, hd), jnp.bfloat16)
+               for key, n in zip(ks, (H, KV, KV)))
+    scale = 1.0 / np.sqrt(hd)
+    got = jax.jit(lambda *a: attention.causal_attention(*a, scale, W))(
+        q, k, v)
+    want = jax.jit(lambda *a: ref.causal_attention_ref(*a, scale, W))(
+        q, k, v)
+    with jax.default_matmul_precision("highest"):
+        exact = jax.jit(lambda *a: ref.causal_attention_ref(
+            *(x.astype(jnp.float32) for x in a), scale, W))(q, k, v)
+    got, want, exact = (np.asarray(a.astype(jnp.float32))
+                        for a in (got, want, exact))
+    top = float(np.abs(exact).max())
+    e_kernel = float(np.abs(got - exact).max()) / top
+    e_ref = float(np.abs(want - exact).max()) / top
+    e_pair = float(np.abs(got - want).max()) / top
+    log(f"attention_vs_ref shape=(1, {ATTN_S}, {H}/{KV}, {hd}) window={W} "
+        f"kernel_err={e_kernel:.4g} ref_err={e_ref:.4g} "
+        f"kernel_vs_ref={e_pair:.4g} (of max |out| {top:.4g})")
+    check(np.isfinite(got).all(), "non-finite attention output")
+    check(e_kernel <= ATTN_RTOL, f"flash kernel error {e_kernel}")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--requests", type=int, default=16)
@@ -147,7 +212,8 @@ def main() -> int:
     log(f"init_s={served.init_s:.3f} compile_s={served.warmup_s:.3f} "
         f"requests={args.requests} wall_s={served.wall_s:.3f}")
     check(len(stats.pipeline.tasks) == args.requests, "request count")
-    paths = dict(sorted(ops.PATHS.items()))
+    paths = {k: n for k, n in sorted(ops.PATHS.items())
+             if k[0] != "attention"}
     log("wire_calls " + " ".join(f"{op}/{b}b/{p}={n}"
                                  for (op, b, p), n in paths.items()))
     for bits in WIRE_BITS:
@@ -171,6 +237,10 @@ def main() -> int:
     rel8 = split_vs_chain(rt, batch, 8)
     check(rel8 < SPLIT_RTOL, f"8-bit split logits rel_err {rel8} >= 0.05")
     split_vs_chain(rt, batch, 4)  # reported, not bounded
+
+    # ---- 5. attention
+    segments_take_kernel(rt)
+    attention_vs_ref(cfg)
 
     peak = dev.memory_stats()["peak_bytes_in_use"]
     log(f"peak_bytes_in_use={peak} ({peak / 2**30:.3f} GiB)")

@@ -37,8 +37,10 @@ KERNEL_BITS = (4, 8)
 # ran: "pallas" = a Pallas kernel compiled for the TPU, "interpret" = the
 # same kernel in interpret mode (off TPU), "ref" = the jnp reference.  It
 # is bumped in the Python wrappers, so it counts eager calls: a call traced
-# inside an outer jit counts once per trace.  chip_smoke.py reads it to
-# show which path the served hops took.
+# inside an outer jit counts once per trace.  ``layers.attention_full``
+# counts each traced attention layer under ("attention", S, path), S the
+# sequence length.  chip_smoke.py reads it to show which path the served
+# hops and segments took.
 PATHS: collections.Counter = collections.Counter()
 
 

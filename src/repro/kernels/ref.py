@@ -108,6 +108,28 @@ def fused_boundary_ref(x: jnp.ndarray, centers: jnp.ndarray, bits: int):
     return payload, scale, zp, f, sep, best, sims
 
 
+# ------------------------------------------------------------ attention ref
+def causal_attention_ref(q, k, v, scale: float, window=None):
+    """Causal grouped-query attention, the math of ``layers._attend``:
+    q (B, S, H, hd), k / v (B, S, KV, hd) -> (B, S, H, hd).  Scores in the
+    inputs' dtype, then f32 times ``scale``; keys ``q - window < k <= q``
+    visible (``None``: every earlier key); f32 softmax cast to the value
+    dtype before PV."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    qr = q.reshape(B, S, KV, H // KV, hd)
+    logits = jnp.einsum("bqkrd,bskd->bkrqs", qr, k).astype(jnp.float32) \
+        * scale
+    pos = jnp.arange(S, dtype=jnp.int32)
+    vis = pos[None, :] <= pos[:, None]
+    if window is not None:
+        vis &= pos[None, :] > pos[:, None] - window
+    logits = jnp.where(vis[None, None, None], logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkrqs,bskd->bqkrd", w, v)
+    return out.reshape(B, S, H, hd)
+
+
 # ------------------------------------------------------- semantic cache ref
 def semantic_probe_ref(x: jnp.ndarray, centers: jnp.ndarray):
     """Fused GAP + cosine similarity + top-2 separability (Eq. 8-10).

@@ -4,6 +4,8 @@
 Pure JAX, explicit parameter pytrees (dicts).  Attention over long sequences
 uses a query-chunked ``lax.scan`` so (S x S) score matrices are never
 materialized — required for the 32k prefill shapes on the dry-run mesh.
+Causal global / local attention on an unsharded TPU program takes the
+fused flash kernel instead (``kernels.attention``; ``attention_path``).
 """
 
 from __future__ import annotations
@@ -15,8 +17,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import attention as FA
+from repro.kernels.ops import PATHS
 from repro.models.config import LayerSpec, ModelConfig
-from repro.models.shardctx import constrain
+from repro.models.shardctx import _specs, constrain
 
 # Query-chunk length for memory-efficient full-sequence attention.
 Q_CHUNK = 1024
@@ -119,12 +123,16 @@ def _scores_mask(q_pos, k_pos, cfg: ModelConfig, spec: LayerSpec, causal: bool):
     return m
 
 
+def _scale(cfg: ModelConfig, hd: int) -> float:
+    return cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(hd)
+
+
 def _attend(q, k, v, mask, cfg: ModelConfig):
     """q: (B,Q,H,hd)  k/v: (B,K,KV,hd)  mask: (Q,K) or (B,Q,K)."""
     B, Q, H, hd = q.shape
     KV = k.shape[2]
     rep = H // KV
-    scale = cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(hd)
+    scale = _scale(cfg, hd)
     qr = q.reshape(B, Q, KV, rep, hd)
     logits = jnp.einsum("bqkrd,bskd->bkrqs", qr, k).astype(jnp.float32) * scale
     if cfg.attn_logit_softcap:
@@ -140,12 +148,34 @@ def _attend(q, k, v, mask, cfg: ModelConfig):
     return constrain(out.reshape(B, Q, H * hd), "attn_out")
 
 
+def attention_path(cfg: ModelConfig, spec: LayerSpec, S: int) -> str:
+    """Which attention ``attention_full`` runs, as ``ops.PATHS`` labels it:
+    "pallas", the fused flash kernel, for causal global / local attention
+    without a logit softcap in a TPU program that no activation-sharding
+    context partitions (a Pallas call cannot be), when the kernel's
+    blocks tile ``S``; else "ref", the query-chunked jnp path."""
+    fused = (jax.default_backend() == "tpu" and _specs() is None
+             and cfg.causal and spec.attn_kind in ("global", "local")
+             and not cfg.attn_logit_softcap
+             and FA.tiles(S))
+    return "pallas" if fused else "ref"
+
+
 def attention_full(params, x, cfg: ModelConfig, spec: LayerSpec, positions=None):
-    """Full-sequence attention (train / prefill), query-chunked over S."""
+    """Full-sequence attention (train / prefill): the flash kernel, or
+    query-chunked over S (``attention_path``).  Each trace counts its path
+    in ``ops.PATHS`` under ("attention", S, path)."""
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     q, k, v = _qkv(params, x, cfg, positions)
+    path = attention_path(cfg, spec, S)
+    PATHS["attention", S, path] += 1
+    if path != "ref":
+        window = cfg.sliding_window if spec.attn_kind == "local" else None
+        out = FA.causal_attention(q, k, v, _scale(cfg, q.shape[-1]), window,
+                                  path == "interpret")
+        return out.reshape(B, S, -1) @ params["wo"], (k, v)
     causal = cfg.causal
     kpos = jnp.arange(S, dtype=jnp.int32)
 

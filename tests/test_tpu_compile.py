@@ -1,5 +1,7 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a TPU
-v5e, at the width of h2o-danube-3-4b (D = 3840), without a chip.
+v5e, at the width of h2o-danube-3-4b (D = 3840), without a chip; the
+flash-attention kernel at danube's and Mixtral's heads, and the cloud
+segment's program with its attention on that kernel.
 
 The TPU compiler refuses what interpret mode accepts: lane slices it
 cannot lower, tiles that overflow VMEM, grids that do not divide the
@@ -12,6 +14,7 @@ describing it loads the TPU library, which one process at a time may
 hold, so only the worker that runs this file does so.
 """
 
+import math
 import os
 
 import jax
@@ -19,9 +22,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.core.collab import CollabRuntime, split_params_multi
+from repro.kernels.attention import flash_attention
 from repro.kernels.boundary import fused_boundary
 from repro.kernels.semantic_cache import semantic_probe
 from repro.kernels.uaq import uaq_dequantize, uaq_quantize
+from repro.models import layers
+from repro.models import model as M
 
 D = 3840          # h2o-danube-3-4b d_model
 L = 16            # semantic-cache labels served by repro.launch.serve
@@ -88,3 +96,36 @@ def test_uaq_dequantize_compiles_for_v5e(one_chip, bits, M):
     s = jax.ShapeDtypeStruct((M, 1), jnp.float32, sharding=one_chip)
     _compile(lambda a, b, c: uaq_dequantize(a, b, c, bits, jnp.bfloat16,
                                             n=D, interpret=False), p, s, s)
+
+
+# the benchmark's prompts, and serve()'s 8-token ones (a single block)
+@pytest.mark.parametrize("arch,S", [("h2o-danube-3-4b", 2048),
+                                    ("mixtral-8x7b", 2048),
+                                    ("h2o-danube-3-4b", 8)])
+def test_flash_attention_compiles_for_v5e(one_chip, arch, S):
+    cfg = get_config(arch)
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q = jax.ShapeDtypeStruct((1, S, H, hd), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, S, KV, hd), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(lambda a, b, c: flash_attention(
+        a, b, c, 1.0 / math.sqrt(hd), cfg.sliding_window, interpret=False),
+        q, kv, kv)
+
+
+def test_cloud_segment_takes_flash_kernel_for_v5e(one_chip, monkeypatch):
+    """``_last_forward`` at the benchmark's (1, 2048, 3840), with the
+    dispatch a TPU process makes: the kernel is in the program and no
+    query chunk's bf16[8, 2048, 1024, 4] scores remain."""
+    cfg = get_config("h2o-danube-3-4b")
+    segs = jax.eval_shape(lambda: split_params_multi(
+        M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16), cfg, (3,)))
+    segs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), segs)
+    rt = CollabRuntime(cfg, list(segs), 3)
+    h = jax.ShapeDtypeStruct((1, 2048, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    monkeypatch.setattr(layers, "attention_path", lambda *a: "pallas")
+    text = _compile(rt._last_forward, segs[-1], h).as_text()
+    assert "coach_flash_attention" in text
+    assert "bf16[8,2048,1024,4]" not in text
