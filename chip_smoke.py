@@ -23,6 +23,13 @@ chip this one holds):
      ``coach_flash_attention`` call in each program); the kernel at
      danube's shape against the jnp reference, both measured from a
      float32 reference at HIGHEST precision.
+  6. Experts: the 4-layer Mixtral-8x7B stage the benchmark serves.  Both
+     segments, traced at 2048 tokens, take the grouped-matmul kernel
+     (``ops.PATHS`` ("experts", 2048, "pallas"), ``coach_grouped_matmul``
+     calls in each program); one expert layer at published width through
+     the kernel against the float32 dropless reference, and the same
+     reference with float8 inputs to every product, which the tolerance
+     below must refuse.
 
 Run it with the machine's ``LIBTPU_INIT_ARGS`` as they are: they carry a
 compiler workaround that guards kernel numerics.
@@ -55,6 +62,11 @@ SEP_RTOL = 1e-3     # separability divides by the top-2 gap
 SPLIT_RTOL = 0.05   # phase 4, 8-bit wire
 ATTN_S = 2048       # phase 5: the benchmark's prompt length
 ATTN_RTOL = 1e-2    # phase 5, of max |out|: twice the bf16 reference's own
+MIXTRAL_LAYERS = 4  # phase 6: the benchmark's stage, cut after one layer
+# phase 6, of max |out|: the kernel rounds the gated hidden state and
+# each expert's output to bf16 (2^-9 relative); float8 inputs (2^-4)
+# lie well beyond it
+EXPERT_RTOL = 1.5e-2
 
 
 def log(msg: str) -> None:
@@ -70,7 +82,7 @@ def boundary_vs_ref(rt, centers, tokens, bits):
     import jax
     from repro.kernels import ops, ref
 
-    h = rt._seg_fns[0](rt.p_segments[0], tokens)
+    h, _ = rt._seg_fns[0](rt.p_segments[0], tokens)
     want = jax.jit(ref.fused_boundary_ref, static_argnums=2)(h, centers, bits)
     got = ops.boundary_pass(h, centers, bits)
     (p, s, z, f, sep, best, sims), (pr, sr, zr, fr, sepr, bestr, simsr) = \
@@ -114,7 +126,7 @@ def split_vs_chain(rt, tokens, bits):
 
     pkt, h = rt.segment_step(0, tokens, bits=bits)
     out = np.asarray(rt.cloud_step(pkt).astype(jnp.float32))
-    ref = np.asarray(rt._seg_fns[-1](rt.p_segments[-1], h)
+    ref = np.asarray(rt._seg_fns[-1](rt.p_segments[-1], h)[0]
                      .astype(jnp.float32))
     rel = float(np.abs(out - ref).max() / np.abs(ref).max())
     log(f"split_vs_chain bits={bits} logits={out.shape} rel_err={rel:.4g}")
@@ -176,6 +188,74 @@ def attention_vs_ref(cfg):
         f"kernel_vs_ref={e_pair:.4g} (of max |out| {top:.4g})")
     check(np.isfinite(got).all(), "non-finite attention output")
     check(e_kernel <= ATTN_RTOL, f"flash kernel error {e_kernel}")
+
+
+def mixtral_stage():
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.core.collab import CollabRuntime, split_params_multi
+    from repro.kernels import grouped_matmul, ops
+    from repro.models import model as M
+
+    cfg = dataclasses.replace(get_config("mixtral-8x7b"),
+                              num_layers=MIXTRAL_LAYERS)
+    segs = jax.eval_shape(lambda: split_params_multi(
+        M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16), cfg, (1,)))
+    rt = CollabRuntime(cfg, list(segs), 1)
+    inputs = [jax.ShapeDtypeStruct((1, ATTN_S), jnp.int32),
+              jax.ShapeDtypeStruct((1, ATTN_S, cfg.d_model), jnp.bfloat16)]
+    for k, fn in ((0, rt._first_forward), (1, rt._last_forward)):
+        before = ops.PATHS.copy()
+        jaxpr = str(jax.make_jaxpr(fn)(segs[k], inputs[k]))
+        took = {p: n for (op, s, p), n in (ops.PATHS - before).items()
+                if op == "experts" and s == ATTN_S}
+        calls = jaxpr.count(f"name={grouped_matmul.NAME}")
+        log(f"mixtral segment {k} S={ATTN_S} layers="
+            f"{len(rt._expert_layers[k])} expert_paths={took} "
+            f"kernel_calls={calls}")
+        check(set(took) == {"pallas"}, f"segment {k} expert path {took}")
+        check(calls == 2, f"segment {k}: {calls} grouped-matmul calls in "
+              "the layer, not gate/up and down")
+
+
+def expert_layer_vs_ref():
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.kernels import ref
+    from repro.models import moe
+
+    cfg = get_config("mixtral-8x7b")
+    p = moe.init_moe(cfg, jax.random.PRNGKey(3), jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, ATTN_S, cfg.d_model),
+                          jnp.bfloat16)
+    got = jax.jit(lambda p, x: moe.moe_ffn(p, x, cfg)[0])(p, x)
+    args = (p["router"], p["w_gate"], p["w_up"], p["w_down"])
+
+    def fp8(a):  # e4m3 with one scale per matrix (per token row for x)
+        axes = tuple(range(1, a.ndim)) if a.ndim == 3 else -1
+        s = jnp.max(jnp.abs(a), axis=axes, keepdims=True) / 240.0
+        return jax.lax.reduce_precision(a.astype(jnp.float32) / s, 4, 3) * s
+
+    exact = jax.jit(lambda x, *w: ref.moe_dropless_ref(
+        x, *w, cfg.experts_per_token))(x[0], *args)
+    low = jax.jit(lambda x, r, *w: ref.moe_dropless_ref(
+        fp8(x), r, *map(fp8, w), cfg.experts_per_token))(x[0], *args)
+    got, exact, low = (np.asarray(a, np.float32) for a in (got[0], exact, low))
+    top = float(np.abs(exact).max())
+    e_kernel = float(np.abs(got - exact).max()) / top
+    e_fp8 = float(np.abs(low - exact).max()) / top
+    log(f"expert_layer_vs_ref shape=({ATTN_S}, {cfg.d_model}) experts="
+        f"{cfg.num_experts} top{cfg.experts_per_token} d_ff={cfg.d_ff} "
+        f"kernel_err={e_kernel:.4g} fp8_err={e_fp8:.4g} tol={EXPERT_RTOL} "
+        f"(of max |out| {top:.4g})")
+    check(np.isfinite(got).all(), "non-finite expert output")
+    check(e_kernel <= EXPERT_RTOL, f"expert layer error {e_kernel}")
+    check(e_fp8 > EXPERT_RTOL, f"float8 products pass the tolerance "
+          f"({e_fp8}): it cannot tell the precision")
 
 
 def main() -> int:
@@ -241,6 +321,11 @@ def main() -> int:
     # ---- 5. attention
     segments_take_kernel(rt)
     attention_vs_ref(cfg)
+
+    # ---- 6. experts, with danube's weights freed
+    del served, rt, stats
+    mixtral_stage()
+    expert_layer_vs_ref()
 
     peak = dev.memory_stats()["peak_bytes_in_use"]
     log(f"peak_bytes_in_use={peak} ({peak / 2**30:.3f} GiB)")

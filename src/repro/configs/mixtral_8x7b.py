@@ -1,5 +1,5 @@
-"""mixtral-8x7b [moe] — 8 experts top-2, sliding-window attention
-[arXiv:2401.04088]."""
+"""mixtral-8x7b [moe] — 8 experts top-2, dropless routing, sliding-window
+attention [arXiv:2401.04088]."""
 from repro.models.config import LayerSpec, ModelConfig
 
 CONFIG = ModelConfig(
@@ -16,6 +16,7 @@ CONFIG = ModelConfig(
     sliding_window=4096,
     num_experts=8,
     experts_per_token=2,
+    dropless=True,
     tie_embeddings=False,
     citation="arXiv:2401.04088",
 )

@@ -30,9 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels import grouped_matmul as GM
 from repro.kernels import ops as KOPS
 from repro.models import layers as L
 from repro.models import model as M
+from repro.models import moe as MOE
 from repro.models.config import ModelConfig
 from repro.obs import served
 
@@ -70,13 +72,67 @@ def split_params(params, cfg: ModelConfig, cut_group: int):
 
 
 def _run_groups(groups, h, cfg: ModelConfig, positions):
-    def group_body(hh, gp):
-        for i, spec in enumerate(cfg.pattern):
-            hh, _, _ = M._block_full(gp[i], hh, cfg, spec, positions,
-                                     False, hh.shape[1])
-        return hh, None
-    h, _ = lax.scan(group_body, h, groups)
+    """The groups' layers over ``h``; their expert layers' per-expert
+    counts go to the open ``moe.collect_loads``, (layers, E) in order.
+    The scan does not slice the expert weights: each layer reads its
+    own as a ``Slab`` of the whole stack (a slice would be copied)."""
+    held = [{w: g["moe"][w] for w in MOE.EXPERT_WEIGHTS} if "moe" in g
+            else {} for g in groups]
+    scanned = tuple(dict(g, moe={k: v for k, v in g["moe"].items()
+                                 if k not in held[i]}) if held[i] else g
+                    for i, g in enumerate(groups))
+
+    def group_body(hh, xs):
+        index, gp = xs
+        with MOE.collect_loads() as got:
+            for i, spec in enumerate(cfg.pattern):
+                p = gp[i]
+                if held[i]:
+                    p = dict(p, moe=dict(p["moe"], **{
+                        w: GM.Slab(a, index) for w, a in held[i].items()}))
+                hh, _, _ = M._block_full(p, hh, cfg, spec, positions,
+                                         False, hh.shape[1])
+        return hh, jnp.stack(got) if got else None
+    n = jax.tree.leaves(groups)[0].shape[0]
+    h, loads = lax.scan(group_body, h, (jnp.arange(n), scanned))
+    if loads is not None:
+        MOE.record_loads(loads.reshape(-1, loads.shape[-1]))
     return h
+
+
+def _positions(B: int, S: int) -> jnp.ndarray:
+    return jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+
+
+def _first_forward(cfg: ModelConfig, p, inputs):
+    B, S = inputs.shape[:2]
+    h = M._embed({**p}, cfg, inputs)
+    return _run_groups(p["groups"], h, cfg, _positions(B, S))
+
+
+def _mid_forward(cfg: ModelConfig, p, h):
+    B, S = h.shape[:2]
+    return _run_groups(p["groups"], h, cfg, _positions(B, S))
+
+
+def _last_forward(cfg: ModelConfig, p, h):
+    B, S = h.shape[:2]
+    h = _run_groups(p["groups"], h, cfg, _positions(B, S))
+    h = L.rms_norm(h, p["final_norm"], cfg.norm_eps)
+    return M._lm_head(p, cfg, h[:, -1])
+
+
+def _program(fn, cfg: ModelConfig):
+    """``fn``'s jitted program for ``cfg``, under ``fn``'s name, returning
+    ``(out, loads)``: the per-expert token counts of its expert layers,
+    (layers, E) int32, or None where it has none.  It holds no runtime,
+    so a runtime's weights go with its last reference."""
+    def program(p, x):
+        with MOE.collect_loads() as got:
+            out = fn(cfg, p, x)
+        return out, jnp.concatenate(got) if got else None
+    program.__name__ = program.__qualname__ = fn.__name__
+    return jax.jit(program)
 
 
 # ---------------------------------------------------------------- runtime
@@ -143,10 +199,17 @@ class CollabRuntime:
         self.p_segments = params if isinstance(params, list) \
             else split_params_multi(params, cfg, self.cuts)
         assert len(self.p_segments) == self.n_segments
+        # each segment's program returns (output, expert loads)
         self._seg_fns = (
-            [jax.jit(self._first_forward)]
-            + [jax.jit(self._mid_forward)] * (self.n_hops - 1)
-            + [jax.jit(self._last_forward)])
+            [_program(_first_forward, cfg)]
+            + [_program(_mid_forward, cfg)] * (self.n_hops - 1)
+            + [_program(_last_forward, cfg)])
+        # the model's index of each expert layer, by segment
+        bounds = (0,) + self.cuts + (cfg.num_groups,)
+        self._expert_layers = [
+            [g * cfg.group_size + i for g in range(bounds[k], bounds[k + 1])
+             for i, spec in enumerate(cfg.pattern) if spec.moe]
+            for k in range(self.n_segments)]
         self._probe = KOPS.probe_cache
 
     @property
@@ -168,33 +231,18 @@ class CollabRuntime:
 
     @property
     def _end_fn(self):
-        return self._seg_fns[0]
+        return lambda p, x: self._seg_fns[0](p, x)[0]
 
     @property
     def _cloud_fn(self):
-        return self._seg_fns[-1]
+        return lambda p, h: self._seg_fns[-1](p, h)[0]
 
-    # ---- per-segment forwards (jitted)
-    @staticmethod
-    def _positions(B: int, S: int) -> jnp.ndarray:
-        return jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-
+    # ---- the end and cloud forwards, as traced into ``_seg_fns``
     def _first_forward(self, p, inputs):
-        cfg = self.cfg
-        B, S = inputs.shape[:2]
-        h = M._embed({**p}, cfg, inputs)
-        return _run_groups(p["groups"], h, cfg, self._positions(B, S))
-
-    def _mid_forward(self, p, h):
-        B, S = h.shape[:2]
-        return _run_groups(p["groups"], h, self.cfg, self._positions(B, S))
+        return _first_forward(self.cfg, p, inputs)
 
     def _last_forward(self, p, h):
-        cfg = self.cfg
-        B, S = h.shape[:2]
-        h = _run_groups(p["groups"], h, cfg, self._positions(B, S))
-        h = L.rms_norm(h, p["final_norm"], cfg.norm_eps)
-        return M._lm_head(p, cfg, h[:, -1])
+        return _last_forward(self.cfg, p, h)
 
     def _quantize(self, h, hop: int, bits: Optional[int]) -> WirePacket:
         bits = bits or self.default_bits_per_hop[hop]
@@ -222,9 +270,11 @@ class CollabRuntime:
             x = x.dequantize(jax.tree.leaves(self.p_segments[k])[0].dtype)
         if served.on():
             with served.span(served.SEGMENT, k=k):
-                h = self._seg_fns[k](self.p_segments[k], x)
+                h, loads = self._seg_fns[k](self.p_segments[k], x)
+            if loads is not None:
+                served.hold_loads(self._expert_layers[k], loads)
         else:
-            h = self._seg_fns[k](self.p_segments[k], x)
+            h, _ = self._seg_fns[k](self.p_segments[k], x)
         if k == self.n_hops:
             return h
         if centers is not None:

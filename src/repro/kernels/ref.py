@@ -155,3 +155,45 @@ def semantic_probe_ref(x: jnp.ndarray, centers: jnp.ndarray):
 
 def jax_one_hot(idx, n, dtype=bool):
     return (idx[:, None] == jnp.arange(n)[None, :]).astype(dtype)
+
+
+# ---------------------------------------------------- grouped matmul ref
+def grouped_matmul_ref(lhs, rhs, group_sizes, act=None):
+    """``grouped_matmul``'s math per group: lhs (M, K) rows sorted by
+    group, rhs a tuple of one or two (G, K, N) -> (M, N); row ``i`` of
+    group ``g`` is ``lhs[i] @ rhs[0][g]``, or with two matrices
+    ``act(lhs[i] @ rhs[0][g]) * (lhs[i] @ rhs[1][g])``, products in f32,
+    cast to ``lhs``'s dtype.  Rows after the last group's are zero."""
+    ends = jnp.cumsum(group_sizes)
+    gid = jnp.searchsorted(ends, jnp.arange(lhs.shape[0]), side="right")
+    out = 0.0
+    for g in range(rhs[0].shape[0]):
+        y = [jnp.dot(lhs, w[g], preferred_element_type=jnp.float32)
+             for w in rhs]
+        y = act(y[0]) * y[1] if len(y) == 2 else y[0]
+        out = out + jnp.where((gid == g)[:, None], y, 0.0)
+    return out.astype(lhs.dtype)
+
+
+# -------------------------------------------- dropless mixture of experts
+def moe_dropless_ref(x, router, w_gate, w_up, w_down, k: int,
+                     act=jax.nn.silu):
+    """The published top-k mixture of experts, routing every token:
+    x (T, D), router (D, E), w_gate / w_up (E, D, F), w_down (E, F, D)
+    -> (T, D) float32.  A float32 softmax router, the top-k gates
+    renormalized to sum to one; each expert's gated FFN applied to every
+    token, weighted by that token's gate for the expert (zero where the
+    expert is not among its top k).  Everything in float32 at the
+    highest matmul precision: the plain reference, one expert at a time."""
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        x = f32(x)
+        probs = jax.nn.softmax(x @ f32(router), axis=-1)
+        top, ids = jax.lax.top_k(probs, k)
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        y = jnp.zeros_like(x)
+        for e in range(router.shape[1]):
+            gate = jnp.sum(jnp.where(ids == e, top, 0.0), axis=-1)
+            h = act(x @ f32(w_gate[e])) * (x @ f32(w_up[e]))
+            y = y + gate[:, None] * (h @ f32(w_down[e]))
+    return y

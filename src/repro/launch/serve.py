@@ -95,7 +95,7 @@ def serve(arch: str, *, smoke: bool = False, requests: int = 200,
 
     calib_tasks = stream.tasks(300)
     calib_inp = jnp.concatenate([task_input(t) for t in calib_tasks], axis=0)
-    h_calib = rt._seg_fns[0](rt.p_end, calib_inp)
+    h_calib, _ = rt._seg_fns[0](rt.p_end, calib_inp)
     # same sum/seq_len GAP expression as kernels.boundary's epilogue
     feats = np.asarray(jnp.sum(h_calib.astype(jnp.float32), axis=1)
                        / h_calib.shape[1])

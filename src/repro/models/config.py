@@ -64,6 +64,9 @@ class ModelConfig:
     shared_expert: bool = False  # llama4: always-on shared expert
     router_aux_coef: float = 0.01
     capacity_factor: float = 1.25
+    # the published routing computes every assignment (mixtral); the
+    # capacity factor then sets nothing (``moe.capacity``)
+    dropless: bool = False
 
     # --- SSM (mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0

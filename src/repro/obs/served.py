@@ -23,6 +23,9 @@ vocabulary):
 ``coach.d2h``            one device-to-host pull: the wait plus the copy
                          (meta ``site``)
 ``coach.gc``             a collector pause (meta ``generation``)
+``coach.experts``        one expert layer's routing in the request: tokens
+                         per expert (meta ``loads``, a tuple) and the
+                         model's index of the layer (meta ``layer``)
 =======================  ===================================================
 
 Counts are counts of spans.  Sites follow ``trace.py``'s contract of
@@ -30,6 +33,12 @@ zero cost when disabled: each is guarded by ``if on():`` (one call,
 ``TraceAnnotation.is_enabled``), so with no profiler session a site
 allocates and records nothing.  The buffer, like the profiler session,
 is one per process; spans are for the serving thread.
+
+The segment programs return their expert layers' loads on the device;
+``hold_loads`` keeps them, and the engine's ``record_experts``, once the
+request's result is on the host, pulls them (the device has long
+finished them) and records the ``coach.experts`` spans.  Both run only
+under a session.
 """
 
 from __future__ import annotations
@@ -39,6 +48,7 @@ import gc
 import time
 from typing import NamedTuple, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 DECIDE = "coach.decide"
@@ -52,9 +62,10 @@ DEQUANTIZE = "coach.dequantize"
 PROBE_RESULT = "coach.probe_result"
 D2H = "coach.d2h"
 GC = "coach.gc"
+EXPERTS = "coach.experts"
 
 SPANS = (DECIDE, STEP, PLAN, ACCOUNT, PROBE_CENTERS, SEGMENT, BOUNDARY,
-         DEQUANTIZE, PROBE_RESULT, D2H, GC)
+         DEQUANTIZE, PROBE_RESULT, D2H, GC, EXPERTS)
 
 MAX_SPANS = 1 << 18
 
@@ -149,6 +160,28 @@ def _on_gc(phase: str, info: dict) -> None:
 gc.callbacks.append(_on_gc)
 
 
+_held: list = []             # (layer indices, device loads) not yet recorded
+
+
+def hold_loads(layers, loads) -> None:
+    """Keep a segment's expert loads, (layers, E) on the device, for
+    ``record_experts``."""
+    _held.append((layers, loads))
+
+
+def record_experts() -> None:
+    """One ``coach.experts`` span per expert layer held, and forget them.
+    Call it after the request's result is on the host."""
+    if not _held:
+        return
+    rows = jax.device_get([loads for _, loads in _held])
+    for (layers, _), got in zip(_held, rows):
+        for layer, loads in zip(layers, got.tolist()):
+            with span(EXPERTS, layer=layer, loads=tuple(loads)):
+                pass
+    _held.clear()
+
+
 def recorded() -> Recording:
     return Recording(tuple(Span(*e) for e in _buf), _dropped)
 
@@ -157,5 +190,6 @@ def clear() -> None:
     """Empty the buffer; call it outside every span."""
     global _dropped, _req
     _buf.clear()
+    _held.clear()
     _dropped = 0
     _req = None

@@ -241,6 +241,8 @@ class EngineBase:
 
     def _decide(self, task, bw: float, classify):
         out = classify(task)
+        if served.on():  # the result is on the host: the loads are done
+            served.record_experts()
         feats, pred = out[0], out[1]
         probes = out[2] if len(out) > 2 else None
         if probes is not None and isinstance(probes, ON.ProbeResult):

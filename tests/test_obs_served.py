@@ -1,9 +1,10 @@
 """Served-path spans (``repro.obs.served``) through ``CoachEngine`` and
 ``CollabRuntime.end_step_fused`` on a tiny model: nothing is recorded
 without a profiler session; under one every span of the vocabulary
-appears with its nesting and request id, three pulls per request, each
-start within 50 us of its event in the written ``.xplane.pb``, and a
-forced collection is one ``coach.gc`` span."""
+appears with its nesting and request id (``coach.experts`` in a model
+with expert layers, after the request's result), three pulls per
+request, each start within 50 us of its event in the written
+``.xplane.pb``, and a forced collection is one ``coach.gc`` span."""
 
 import gc
 import glob
@@ -25,10 +26,12 @@ from repro.serving.engine import CoachEngine
 
 N_LABELS = 4
 PERIOD = 3e-3
+# a dense model has no expert layers
+DENSE_SPANS = set(served.SPANS) - {served.EXPERTS}
 
 
-def _build():
-    cfg = get_config("h2o-danube-3-4b").reduced()
+def _build(arch="h2o-danube-3-4b"):
+    cfg = get_config(arch).reduced()
     rt = CollabRuntime(cfg, M.init_params(cfg, jax.random.PRNGKey(0)),
                        cut_group=1)
     stream = CorrelatedTaskStream(n_labels=N_LABELS, dim=cfg.d_model,
@@ -40,7 +43,7 @@ def _build():
                            .astype(np.int32))[None]
 
     calib = stream.tasks(40)
-    h = rt._seg_fns[0](rt.p_end, jnp.concatenate([tokens(t) for t in calib]))
+    h, _ = rt._seg_fns[0](rt.p_end, jnp.concatenate([tokens(t) for t in calib]))
     feats = np.asarray(jnp.mean(h.astype(jnp.float32), axis=1))
     st = StageTimes(T_e=2e-3, T_t=3e-3, T_c=2e-3, T_t_par=0, T_c_par=0,
                     latency=7e-3, first_tx_offset=2e-3,
@@ -72,11 +75,9 @@ def path():
     return engine, stream, classify
 
 
-@pytest.fixture(scope="module")
-def traced(path, tmp_path_factory):
+def _trace(path, out):
     engine, stream, classify = path
     tasks = stream.tasks(3)
-    out = tmp_path_factory.mktemp("trace")
     served.clear()
     with jax.profiler.trace(str(out)):
         engine.run_stream(tasks, PERIOD, classify)
@@ -93,6 +94,11 @@ def traced(path, tmp_path_factory):
     return tasks, rec, forced, pb
 
 
+@pytest.fixture(scope="module")
+def traced(path, tmp_path_factory):
+    return _trace(path, tmp_path_factory.mktemp("trace"))
+
+
 def test_off_records_nothing(path):
     engine, stream, classify = path
     served.clear()
@@ -105,7 +111,7 @@ def test_spans_nest_under_their_request(traced):
     tasks, rec, _, _ = traced
     spans = rec.spans
     assert rec.dropped == 0
-    assert set(served.SPANS) <= {s.name for s in spans}
+    assert DENSE_SPANS <= {s.name for s in spans}
     assert all(s.t1 is not None and s.t0 <= s.t1 for s in spans)
     for s in spans:
         if s.parent is not None:
@@ -138,10 +144,9 @@ def test_spans_nest_under_their_request(traced):
             assert names.count(name) == 1, name
 
 
-def test_memory_copy_matches_profiler_trace(traced):
+def _matches_profiler_trace(rec, pb, names):
     from jax.profiler import ProfileData
 
-    _, rec, _, pb = traced
     pd = ProfileData.from_file(pb)
     start = next(dict(p.stats)["profile_start_time"] for p in pd.planes
                  if p.name == "Task Environment")
@@ -152,11 +157,40 @@ def test_memory_copy_matches_profiler_trace(traced):
                 for e in line.events:
                     if e.name in served.SPANS:
                         events.setdefault(e.name, []).append(e.start_ns)
-    for name in served.SPANS:
+    for name in names:
         mine = sorted(s.t0 - start for s in rec.spans if s.name == name)
         theirs = sorted(events.get(name, []))
         assert len(mine) == len(theirs) > 0, name
         assert np.max(np.abs(np.subtract(mine, theirs))) <= 50e3, name
+
+
+def test_memory_copy_matches_profiler_trace(traced):
+    _, rec, _, pb = traced
+    _matches_profiler_trace(rec, pb, DENSE_SPANS)
+
+
+def test_expert_loads_per_layer_after_the_result(tmp_path):
+    """A model with expert layers: one ``coach.experts`` span per layer
+    and request, under its decision and after the probe's pulls, whose
+    loads count every token's top-k assignments."""
+    engine, stream, classify = _build("mixtral-8x7b")
+    cfg = engine.rt.cfg
+    engine.run_stream(stream.tasks(2), PERIOD, classify)   # compiles
+    tasks, rec, _, pb = _trace((engine, stream, classify), tmp_path)
+    spans = rec.spans
+    _matches_profiler_trace(rec, pb, set(served.SPANS))
+    for task in tasks:
+        mine = [s for s in spans if s.req == task.id]
+        decide = next(i for i, s in enumerate(spans)
+                      if s.name == served.DECIDE and s.req == task.id)
+        pulled = max(s.t1 for s in mine if s.name == served.PROBE_RESULT)
+        experts = [s for s in mine if s.name == served.EXPERTS]
+        assert [s.meta["layer"] for s in experts] \
+            == list(range(cfg.num_layers))
+        for s in experts:
+            assert s.parent == decide and s.t0 >= pulled
+            assert len(s.meta["loads"]) == cfg.num_experts
+            assert sum(s.meta["loads"]) == 8 * cfg.experts_per_token
 
 
 def test_forced_collection_is_one_gc_span(traced):

@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a TPU
 v5e, at the width of h2o-danube-3-4b (D = 3840), without a chip; the
-flash-attention kernel at danube's and Mixtral's heads, and the cloud
-segment's program with its attention on that kernel.
+flash-attention kernel at danube's and Mixtral's heads, the cloud
+segment's program with its attention on that kernel, and a Mixtral stage's
+cloud segment with its experts on the grouped-matmul kernel.
 
 The TPU compiler refuses what interpret mode accepts: lane slices it
 cannot lower, tiles that overflow VMEM, grids that do not divide the
@@ -14,6 +15,7 @@ describing it loads the TPU library, which one process at a time may
 hold, so only the worker that runs this file does so.
 """
 
+import dataclasses
 import math
 import os
 
@@ -28,7 +30,7 @@ from repro.kernels.attention import flash_attention
 from repro.kernels.boundary import fused_boundary
 from repro.kernels.semantic_cache import semantic_probe
 from repro.kernels.uaq import uaq_dequantize, uaq_quantize
-from repro.models import layers
+from repro.models import layers, moe
 from repro.models import model as M
 
 D = 3840          # h2o-danube-3-4b d_model
@@ -129,3 +131,23 @@ def test_cloud_segment_takes_flash_kernel_for_v5e(one_chip, monkeypatch):
     text = _compile(rt._last_forward, segs[-1], h).as_text()
     assert "coach_flash_attention" in text
     assert "bf16[8,2048,1024,4]" not in text
+
+
+def test_mixtral_stage_takes_grouped_matmul_for_v5e(one_chip, monkeypatch):
+    """``_last_forward`` of the benchmark's 4-layer Mixtral stage at
+    (1, 2048, 4096), with the dispatch a TPU process makes: every expert
+    layer runs the grouped-matmul kernel, and the capacity dispatch's
+    padded (1, 8, 2049, 14336) expert buffer is gone."""
+    cfg = dataclasses.replace(get_config("mixtral-8x7b"), num_layers=4)
+    segs = jax.eval_shape(lambda: split_params_multi(
+        M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16), cfg, (1,)))
+    segs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), segs)
+    rt = CollabRuntime(cfg, list(segs), 1)
+    h = jax.ShapeDtypeStruct((1, 2048, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    monkeypatch.setattr(layers, "attention_path", lambda *a: "pallas")
+    monkeypatch.setattr(moe, "expert_path", lambda *a: "pallas")
+    text = _compile(rt._last_forward, segs[-1], h).as_text()
+    assert "coach_grouped_matmul" in text
+    assert "bf16[1,8,2049,14336]" not in text
