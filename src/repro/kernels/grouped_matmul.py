@@ -7,17 +7,25 @@ rows after those of the groups before it.  A dropless expert layer
 (``models/moe.py``) runs its routed rows through it, so that each
 expert multiplies only the tokens routed to it.
 
-Grid (N blocks, row-block visits), the second sequential.  A visit is
-one (group, row block) pair: every row block a group touches, groups in
+Grid (N blocks, row-block visits), both sequential.  A visit is one
+(group, row block) pair: every row block a group touches, groups in
 order, so a block that two groups share is visited once for each of
 them, consecutively, and each visit stores only its own group's rows
 (the layout of the megablox ``gmm`` that ships with JAX).  The number of
 visits is known only on the device; the grid takes it as a traced size,
-and the index maps read each visit's group and row block from
-scalar-prefetched tables.  Each step multiplies a (block_m, K) row block
-by a (K, block_n) block of the group's matrix: K is not split, so a
-group's weight block stays in VMEM across all of its row blocks and is
-read from HBM once per N block.
+and the index maps read each visit's row block from scalar-prefetched
+tables.  Each step multiplies a (block_m, K) row block by a (K, block_n)
+block of the group's matrix: K is not split, so a group's weight block
+stays in VMEM across all of its row blocks and is read from HBM once per
+N block.
+
+The weight blocks are copied by hand into two VMEM slots per matrix, in
+the order the grid uses them: the (group, N block) pairs of the first N
+block, then those of the next.  At a pair's first visit the kernel waits
+for its block and starts the copy of the next pair's into the other
+slot, so each copy has the whole of a group's visits to land in, not
+the one step that the automatic pipeline gives it.  The row block and
+the output stay on the automatic pipeline.
 
 A matrix may be passed as one layer of a stack, ``Slab(stack, index)``
 with ``stack`` (L, G, K, N): the kernel reads that layer's blocks in
@@ -40,6 +48,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -48,8 +57,8 @@ from repro.kernels import ref
 NAME = "coach_grouped_matmul"  # the pallas_call's name, as traced
 # row block and N block (PERF.md: the tile sweep at Mixtral's shapes on a
 # v5e); K is never split
-BLOCK_M, BLOCK_N = 256, 1024
-VMEM_LIMIT = 96 * 2**20  # a (K, block_n) block of each matrix, twice
+BLOCK_M, BLOCK_N = 128, 1024
+VMEM_LIMIT = 96 * 2**20  # two (K, block_n) slots of each matrix, rows
 
 
 class Slab(NamedTuple):
@@ -66,10 +75,26 @@ def block_n(N: int) -> int:
     return BLOCK_N if N % BLOCK_N == 0 else N
 
 
-def visits(group_sizes, m: int, bm: int):
-    """(row offsets (G + 1,), group of each visit, row block of each
-    visit, number of visits): the visit tables, padded to the most visits
-    ``m`` rows can need, ``m // bm + G - 1``."""
+def padded(sizes, bm: Optional[int] = None):
+    """Each group's rows rounded up to whole row blocks (``BLOCK_M``), as
+    the dropless layer lays them out, so that no two groups share one."""
+    bm = BLOCK_M if bm is None else bm
+    return (sizes + bm - 1) // bm * bm
+
+
+class Visits(NamedTuple):
+    """The visit tables, padded to the most visits ``m`` rows can need,
+    ``m // bm + G - 1``.  The groups with rows are the experts a visit
+    can name, numbered in order."""
+    offsets: jax.Array   # (G + 1,) each group's first row, then M
+    order: jax.Array     # (G,) the group of each expert; the rest 0
+    expert: jax.Array    # (V,) the expert of each visit
+    tile: jax.Array      # (V,) the row block of each visit
+    experts: jax.Array   # (1,) how many groups have rows
+    n: jax.Array         # () how many visits there are
+
+
+def visits(group_sizes, m: int, bm: int) -> Visits:
     G = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
@@ -79,19 +104,71 @@ def visits(group_sizes, m: int, bm: int):
     v = jnp.arange(m // bm + G - 1, dtype=jnp.int32)
     gid = jnp.minimum(jnp.searchsorted(upto, v, side="right"), G - 1)
     tile = first[gid] + v - (upto[gid] - count[gid])
+    used = group_sizes > 0
+    rank = jnp.cumsum(used) - 1
+    order = jnp.zeros(G, jnp.int32).at[jnp.where(used, rank, G)].set(
+        jnp.arange(G, dtype=jnp.int32), mode="drop")
     offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
-    return (offsets.astype(jnp.int32), gid.astype(jnp.int32),
-            jnp.clip(tile, 0, m // bm - 1).astype(jnp.int32), upto[-1])
+    i32 = lambda a: a.astype(jnp.int32)
+    return Visits(i32(offsets), order, i32(jnp.maximum(rank[gid], 0)),
+                  i32(jnp.clip(tile, 0, m // bm - 1)),
+                  i32(jnp.sum(used, keepdims=True)), upto[-1])
 
 
-def _kernel(offsets, gid, tile, layer, lhs_ref, *refs, bm: int, act):
-    *rhs_refs, out_ref = refs
-    v = pl.program_id(1)
+def fetch_schedule(group_sizes, bm: Optional[int] = None):
+    """On the host, for the kernel's ``group_sizes`` (each group's rows):
+    (rows it computes, share of its weight-block copies started two or
+    more visits before their first use).  A copy starts at the first
+    visit of the pair before it, so its lead is that group's visits; the
+    chain runs on from one N block into the next, and the first copy of
+    all, which nothing precedes, is left out."""
+    bm = BLOCK_M if bm is None else bm
+    sizes = np.asarray(group_sizes, np.int64)
+    ends = np.cumsum(sizes)
+    count = np.where(sizes > 0, (ends - 1) // bm - (ends - sizes) // bm + 1,
+                     0)
+    lead = count[count > 0]
+    if not lead.size:
+        return 0, 0.0
+    # each expert's copy is started at its predecessor's first visit
+    return int(count.sum()) * bm, float(np.mean(np.roll(lead, 1) >= 2))
+
+
+def _kernel(offsets, order, expert, tile, experts, layer, lhs_ref, *refs,
+            bm: int, bn: int, act):
+    r = (len(refs) - 2) // 2
+    w_hbm, out_ref, slots, sems = refs[:r], refs[r], refs[r + 1:-1], refs[-1]
+    n, v = pl.program_id(0), pl.program_id(1)
+    e = experts[0]
+    p = n * e + expert[v]      # this visit's (group, N block) pair, in order
+    slot = p % 2
+
+    def copies(q, s):
+        cols = pl.ds(pl.multiple_of(q // e * bn, bn), bn)
+        return [pltpu.make_async_copy(w.at[layer[0], order[q % e], :, cols],
+                                      buf.at[s], sems.at[i, s])
+                for i, (w, buf) in enumerate(zip(w_hbm, slots))]
+
+    @pl.when((v == 0) | (expert[v] != expert[jnp.maximum(v - 1, 0)]))
+    def _():
+        @pl.when(p == 0)
+        def _():
+            for c in copies(0, 0):
+                c.start()
+
+        for c in copies(p, slot):
+            c.wait()
+
+        @pl.when(p + 1 < pl.num_programs(0) * e)
+        def _():
+            for c in copies(p + 1, 1 - slot):
+                c.start()
+
     x = lhs_ref[...]
-    acc = [jnp.dot(x, r[...], preferred_element_type=jnp.float32)
-           for r in rhs_refs]
+    acc = [jnp.dot(x, buf[slot], preferred_element_type=jnp.float32)
+           for buf in slots]
     y = act(acc[0]) * acc[1] if len(acc) == 2 else acc[0]
-    g = gid[v]
+    g = order[expert[v]]
     row = tile[v] * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     mine = (row >= offsets[g]) & (row < offsets[g + 1])
     out_ref[...] = jnp.where(mine, y, out_ref[...].astype(jnp.float32)
@@ -107,28 +184,28 @@ def _grouped(lhs, rhs: tuple, layer, group_sizes, act, interpret: bool):
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     m = M + pad
-    offsets, gid, tile, n_visits = visits(group_sizes.astype(jnp.int32),
-                                          m, bm)
+    vis = visits(group_sizes.astype(jnp.int32), m, bm)
     out = pl.pallas_call(
-        functools.partial(_kernel, bm=bm, act=act),
+        functools.partial(_kernel, bm=bm, bn=bn, act=act),
         out_shape=jax.ShapeDtypeStruct((m, N), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(N // bn, n_visits),
+            num_scalar_prefetch=6,
+            grid=(N // bn, vis.n),
             in_specs=[pl.BlockSpec((bm, K),
-                                   lambda n, v, o, g, t, l: (t[v], 0))]
-            + [pl.BlockSpec((None, None, K, bn),
-                            lambda n, v, o, g, t, l: (l[0], g[v], 0, n))
-               for _ in rhs],
+                                   lambda n, v, o, g, e, t, *_: (t[v], 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in rhs],
             out_specs=pl.BlockSpec((bm, bn),
-                                   lambda n, v, o, g, t, l: (t[v], n)),
+                                   lambda n, v, o, g, e, t, *_: (t[v], n)),
+            scratch_shapes=[pltpu.VMEM((2, K, bn), w.dtype) for w in rhs]
+            + [pltpu.SemaphoreType.DMA((len(rhs), 2))],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
-        interpret=interpret,
+        interpret=pltpu.InterpretParams() if interpret else False,
         name=NAME,
-    )(offsets, gid, tile, layer, lhs, *rhs)
+    )(vis.offsets, vis.order, vis.expert, vis.tile, vis.experts, layer,
+      lhs, *rhs)
     return out[:M] if pad else out
 
 

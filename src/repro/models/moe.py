@@ -160,7 +160,7 @@ def _dropless_ffn(params, x, gates, ids, act, interpret: bool):
     rank = jnp.take_along_axis(jnp.cumsum(oh, axis=0), flat[:, None],
                                axis=1)[:, 0] - 1
     bm = GM.BLOCK_M
-    padded = (sizes + bm - 1) // bm * bm
+    padded = GM.padded(sizes, bm)
     row = (jnp.cumsum(padded) - padded)[flat] + rank  # (n,)
     m = -(-(n + E * (bm - 1)) // bm) * bm  # the most rows padding can need
     # the token of each row; the sentinel row G*T gathers zeros

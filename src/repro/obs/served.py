@@ -24,8 +24,13 @@ vocabulary):
                          (meta ``site``)
 ``coach.gc``             a collector pause (meta ``generation``)
 ``coach.experts``        one expert layer's routing in the request: tokens
-                         per expert (meta ``loads``, a tuple) and the
-                         model's index of the layer (meta ``layer``)
+                         per expert (meta ``loads``, a tuple), the
+                         model's index of the layer (meta ``layer``), and
+                         from ``grouped_matmul.fetch_schedule`` on the
+                         loads as the dropless layer pads them, the rows
+                         its kernel computes (meta ``rows``) and the
+                         share of its weight-block copies started two or
+                         more visits ahead (meta ``early``)
 =======================  ===================================================
 
 Counts are counts of spans.  Sites follow ``trace.py``'s contract of
@@ -49,7 +54,10 @@ import time
 from typing import NamedTuple, Optional
 
 import jax
+import numpy as np
 from jax.profiler import TraceAnnotation
+
+from repro.kernels import grouped_matmul as GM
 
 DECIDE = "coach.decide"
 STEP = "coach.step"
@@ -174,10 +182,12 @@ def record_experts() -> None:
     Call it after the request's result is on the host."""
     if not _held:
         return
-    rows = jax.device_get([loads for _, loads in _held])
-    for (layers, _), got in zip(_held, rows):
-        for layer, loads in zip(layers, got.tolist()):
-            with span(EXPERTS, layer=layer, loads=tuple(loads)):
+    got = jax.device_get([loads for _, loads in _held])
+    for (layers, _), held in zip(_held, got):
+        for layer, loads in zip(layers, held.tolist()):
+            rows, early = GM.fetch_schedule(GM.padded(np.asarray(loads)))
+            with span(EXPERTS, layer=layer, loads=tuple(loads), rows=rows,
+                      early=early):
                 pass
     _held.clear()
 

@@ -39,18 +39,35 @@ def f32(x):
 
 # uneven groups with an empty and a single-row one, M not a multiple of
 # the block (rows past the last group are undefined); one group in the
-# middle of four; groups filling whole blocks
-@pytest.mark.parametrize("sizes,M", [((5, 0, 17, 1, 9), 35),
-                                     ((0, 0, 3, 0), 3),
-                                     ((16, 16, 16), 48)],
-                         ids=["uneven", "single-group", "aligned"])
+# middle of four; groups filling whole blocks.  Then, with each matrix a
+# slab of a layer stack read at a layer other than 0: a one-visit group
+# before a four-visit one and the reverse, empty groups between
+# non-empty ones, and one group alone; with 2 or 3 N blocks, so that the
+# weight copies run on from one N block into the next
+@pytest.mark.parametrize("sizes,M,N,layer", [
+    ((5, 0, 17, 1, 9), 35, 256, None),
+    ((0, 0, 3, 0), 3, 256, None),
+    ((16, 16, 16), 48, 256, None),
+    ((7, 50), 57, 256, 1),
+    ((50, 7), 57, 384, 2),
+    ((9, 0, 0, 20, 0, 3), 32, 256, 1),
+    ((0, 40, 0), 40, 384, 2),
+], ids=["uneven", "single-group", "aligned", "one-then-many",
+        "many-then-one", "empty-between", "alone"])
 @pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
-def test_kernel_matches_per_group_product(sizes, M, gated, small_blocks):
-    G, K, N = len(sizes), 64, 256
+def test_kernel_matches_per_group_product(sizes, M, N, layer, gated,
+                                          small_blocks):
+    G, K = len(sizes), 64
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     lhs = jax.random.normal(ks[0], (M, K))
-    w = [jax.random.normal(k, (G, K, N)) for k in ks[1:]]
-    rhs = tuple(w) if gated else w[0]
+    if layer is None:
+        w = [jax.random.normal(k, (G, K, N)) for k in ks[1:]]
+        mats = w
+    else:
+        stacks = [jax.random.normal(k, (3, G, K, N)) for k in ks[1:]]
+        w = [a[layer] for a in stacks]
+        mats = [GM.Slab(a, jnp.int32(layer)) for a in stacks]
+    rhs = tuple(mats) if gated else mats[0]
     got = f32(GM.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
                                 interpret=True))
     assert got.shape == (M, N)
@@ -63,6 +80,46 @@ def test_kernel_matches_per_group_product(sizes, M, gated, small_blocks):
         np.testing.assert_allclose(got[start:start + n], want, rtol=1e-4,
                                    atol=1e-4)
         start += n
+
+
+def _copy_leads(sizes, bm, n_blocks):
+    """The kernel's schedule stepped through by hand: the grid's visits in
+    order, a weight copy started at each (group, N block) pair's first
+    visit for the pair after it, and each copy's lead in visits."""
+    steps = []
+    for nb in range(n_blocks):
+        start = 0
+        for g, s in enumerate(sizes):
+            if s:
+                steps += [(g, nb)] * ((start + s - 1) // bm - start // bm + 1)
+            start += s
+    firsts = [i for i, p in enumerate(steps) if not i or steps[i - 1] != p]
+    return [b - a for a, b in zip(firsts, firsts[1:])]
+
+
+@pytest.mark.parametrize("sizes,bm,rows,early", [
+    ((7, 50), 16, 5 * 16, 1 / 2),       # a shared block, visited twice
+    ((128, 0, 384, 128), 128, 640, 1 / 3),
+    ((512,) * 8, 128, 4096, 1.0),
+    ((0, 300, 0), 128, 384, 1.0),       # one group, block after block
+    ((0, 0), 128, 0, 0.0),
+], ids=["shared", "padded", "balanced", "alone", "empty"])
+def test_fetch_schedule_against_a_hand_count(sizes, bm, rows, early):
+    assert GM.fetch_schedule(sizes, bm) == (rows, pytest.approx(early))
+    # the second N block's copies: each one's lead is its predecessor's
+    E = np.count_nonzero(sizes)
+    leads = np.asarray(_copy_leads(sizes, bm, 2)[-E:] if E else [0])
+    assert np.mean(leads >= 2) == pytest.approx(early)
+    m = -(-max(sum(sizes), 1) // bm) * bm
+    assert int(GM.visits(jnp.asarray(sizes, jnp.int32), m, bm).n) * bm \
+        == rows
+
+
+def test_padded_layout_shares_no_block():
+    sizes = np.asarray((0, 1, 255, 256, 257))
+    assert GM.padded(sizes, 256).tolist() == [0, 256, 256, 256, 512]
+    rows, _ = GM.fetch_schedule(GM.padded(sizes, 256), 256)
+    assert rows == int(GM.padded(sizes, 256).sum())
 
 
 def _layer(E, dtype, seed=0, **over):

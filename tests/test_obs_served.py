@@ -20,6 +20,7 @@ from repro.core.collab import CollabRuntime
 from repro.core.costs import A6000_SERVER, JETSON_NX, WIFI_5GHZ
 from repro.core.schedule import StageTimes
 from repro.data.pipeline import CorrelatedTaskStream
+from repro.kernels import grouped_matmul as GM
 from repro.models import model as M
 from repro.obs import served
 from repro.serving.engine import CoachEngine
@@ -191,6 +192,9 @@ def test_expert_loads_per_layer_after_the_result(tmp_path):
             assert s.parent == decide and s.t0 >= pulled
             assert len(s.meta["loads"]) == cfg.num_experts
             assert sum(s.meta["loads"]) == 8 * cfg.experts_per_token
+            assert (s.meta["rows"], s.meta["early"]) == GM.fetch_schedule(
+                GM.padded(np.asarray(s.meta["loads"])))
+            assert s.meta["rows"] >= sum(s.meta["loads"])
 
 
 def test_forced_collection_is_one_gc_span(traced):

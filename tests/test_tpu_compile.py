@@ -2,7 +2,8 @@
 v5e, at the width of h2o-danube-3-4b (D = 3840), without a chip; the
 flash-attention kernel at danube's and Mixtral's heads, the cloud
 segment's program with its attention on that kernel, and a Mixtral stage's
-cloud segment with its experts on the grouped-matmul kernel.
+cloud segment with its experts on the grouped-matmul kernel, and that
+kernel alone at Mixtral's expert shapes.
 
 The TPU compiler refuses what interpret mode accepts: lane slices it
 cannot lower, tiles that overflow VMEM, grids that do not divide the
@@ -18,6 +19,7 @@ hold, so only the worker that runs this file does so.
 import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core.collab import CollabRuntime, split_params_multi
 from repro.kernels.attention import flash_attention
+from repro.kernels import grouped_matmul as GM
 from repro.kernels.boundary import fused_boundary
 from repro.kernels.semantic_cache import semantic_probe
 from repro.kernels.uaq import uaq_dequantize, uaq_quantize
@@ -133,11 +136,35 @@ def test_cloud_segment_takes_flash_kernel_for_v5e(one_chip, monkeypatch):
     assert "bf16[8,2048,1024,4]" not in text
 
 
+# Mixtral-8x7B's expert matrices: gate and up as a pair, then down
+@pytest.mark.parametrize("K,N,pair", [(4096, 14336, 2), (14336, 4096, 1)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, K, N, pair):
+    """The expert kernel as the dropless layer calls it at 2048 tokens:
+    4096 routed rows and room for padding at ``BLOCK_M``, each matrix a
+    slab of a 4-layer stack; two weight slots per matrix and the row
+    blocks fit VMEM."""
+    E, bm = 8, GM.BLOCK_M
+    m = -(-(4096 + E * (bm - 1)) // bm) * bm
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(lhs, sizes, i, *stacks):
+        slabs = tuple(GM.Slab(w, i) for w in stacks)
+        return GM.grouped_matmul(lhs, slabs if pair == 2 else slabs[0],
+                                 sizes, interpret=False)
+
+    _compile(layer, spec((m, K)), spec((E,), jnp.int32),
+             spec((), jnp.int32), *[spec((4, E, K, N))] * pair)
+
+
 def test_mixtral_stage_takes_grouped_matmul_for_v5e(one_chip, monkeypatch):
     """``_last_forward`` of the benchmark's 4-layer Mixtral stage at
     (1, 2048, 4096), with the dispatch a TPU process makes: every expert
     layer runs the grouped-matmul kernel, and the capacity dispatch's
-    padded (1, 8, 2049, 14336) expert buffer is gone."""
+    padded (1, 8, 2049, 14336) expert buffer is gone, and no layer's
+    (8, K, N) expert matrices are sliced out of the stack."""
     cfg = dataclasses.replace(get_config("mixtral-8x7b"), num_layers=4)
     segs = jax.eval_shape(lambda: split_params_multi(
         M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16), cfg, (1,)))
@@ -148,6 +175,13 @@ def test_mixtral_stage_takes_grouped_matmul_for_v5e(one_chip, monkeypatch):
                              sharding=one_chip)
     monkeypatch.setattr(layers, "attention_path", lambda *a: "pallas")
     monkeypatch.setattr(moe, "expert_path", lambda *a: "pallas")
-    text = _compile(rt._last_forward, segs[-1], h).as_text()
+    compiled = _compile(rt._last_forward, segs[-1], h)
+    text = compiled.as_text()
     assert "coach_grouped_matmul" in text
     assert "bf16[1,8,2049,14336]" not in text
+    # the cloud's 3 layers of expert matrices are read in place from their
+    # stacks: no layer's 0.94 GB matrices are sliced out and copied
+    experts = set(re.findall(
+        r"bf16\[(?:\d+,)*8,(?:4096,14336|14336,4096)\]", text))
+    assert experts == {"bf16[3,8,4096,14336]", "bf16[3,8,14336,4096]"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
