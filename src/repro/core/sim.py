@@ -366,6 +366,10 @@ class SimPlan:
         self.early_exit = self.exit_hop is not None
 
     @property
+    def n_hops(self) -> int:
+        return len(self.tx)
+
+    @property
     def n_stages(self) -> int:
         """Number of compute segments the task actually runs."""
         return (self.exit_hop + 1) if self.exit_hop is not None \
