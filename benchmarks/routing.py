@@ -14,7 +14,7 @@ bottleneck's service rate) is replayed per (policy, m):
                                  classic serial chain)
 
 Both engines run every cell: ``engine = "sim"`` is the staged pool
-replay (``sim.simulate_pool_stream`` via ``core.pipeline.run_pipeline``),
+replay (``sim.simulate_stream`` via ``core.pipeline.run_pipeline``),
 ``engine = "async"`` the per-replica asyncio workers behind per-pool
 dispatchers on the virtual clock.  ``benchmarks/validate_bench.py``
 gates the artifact: for jsq and po2 the ``m = 2`` row must deliver

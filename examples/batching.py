@@ -17,8 +17,8 @@ Watch three things in the output: the realized batch sizes (dynamic —
 the greedy drain takes what the backlog offers, so they sit well below
 the caps), the throughput/p99 pair (batching on an overloaded stream is
 a Pareto win: the backlog clears faster than it grows), and the
-``pinned_to_sim`` flag (the asyncio executor's batched timeline stays
-bit-identical to the arithmetic staged replay).
+``pinned_to_sim`` flag (the asyncio executor's batched timeline matches
+the arithmetic staged replay, ``core.sim.simulate_stream``, to 1e-6).
 
   PYTHONPATH=src python examples/batching.py \
       [--tiers 2|3] [--overload 2.0] [--slack-stages 2.0] [--tasks 300]

@@ -1,12 +1,12 @@
-"""Multi-tenant serving walkthrough: three tenants, one hop chain.
+"""Multi-tenant serving walkthrough: three tenants, one shared deployment.
 
 Several end-device task streams share a single collaborative VGG16
 deployment (Jetson-NX end -> A6000 cloud over WiFi; pass ``--tiers 3``
 for the end -> AGX-Orin edge -> cloud chain).  Each tenant gets its own
 COACH online state (semantic cache, thresholds, bandwidth EMAs) inside a
 ``MultiTenantCoachEngine``; a pluggable admission policy decides which
-tenant's task enters the shared ``2n+1`` resource chain whenever the end
-worker frees up:
+tenant's task enters the shared ``2n+1`` resources whenever the end
+tier frees up:
 
   interactive   sparse arrivals, tight SLO, weight 4
   batch         bursts of back-to-back tasks, loose SLO, weight 1

@@ -13,7 +13,8 @@ drains (the scale-out Pareto win), and the informed policies (jsq, po2)
 beating the random baseline — at ``m = 2`` po2 probes both replicas and
 *is* JSQ; the gap opens at ``m = 4``.  The ``pinned_to_sim`` flag
 confirms the per-replica asyncio executor's timeline matches the
-arithmetic staged pool replay.
+arithmetic staged replay (``core.sim.simulate_stream``); ``m = 1`` is
+the serial chain, a pool of one replica per tier.
 
   PYTHONPATH=src python examples/replicated_tiers.py \
       [--tiers 2|3] [--overload 4.0] [--tasks 240] [--policies jsq,po2]

@@ -4,8 +4,9 @@ the per-cause idle table, and export a Perfetto/Chrome trace.
 ResNet101 is partitioned by the real offline planner onto the 3-tier
 deployment (Jetson-NX + AGX-Orin + A6000 — the same device/link table
 the ``multihop`` bench uses), a steady stream with the hop-level
-semantic-exit cascade runs through the event simulator with a live
-``TraceRecorder``, and the observability layer (``repro.obs``) answers
+semantic-exit cascade runs through the event simulator
+(``core.sim.simulate_stream``, every tier a pool of one replica) with a
+live ``TraceRecorder``, and the observability layer (``repro.obs``) answers
 the question ``bubble_fraction`` can't: not *how much* each resource
 idled, but *why* — warmup, drain, upstream starvation, batch formation,
 exit releases, and the rest of the closed cause enum, with the
@@ -64,7 +65,7 @@ def main() -> None:
     assert_traces_match(rec, rec_a, tol=1e-6)
 
     att = attribute(rec, resources=chain_resources(
-        pr.n_hops, pr.pool_sizes or None))
+        pr.n_hops, pr.pool_sizes))
     print(f"model=resnet101 tiers={N_TIERS} tasks={args.tasks} "
           f"exit_ratio={pr.exit_ratio:.2%} makespan={pr.makespan:.3f}s "
           f"spans={len(rec)} (sim == async at 1e-6)")

@@ -1,15 +1,17 @@
 """Continuous-task pipeline executor (discrete-event).
 
-``2n+1`` serial resources — end device, per-hop links, intermediate edge
-tiers, cloud — process a stream of tasks (Fig. 2); the paper's 3-resource
-testbed is ``n_hops = 1``.  Per task the stage durations come from the
-offline partition's ``StageTimes``; the online component may override
-transmission bits (adaptive quantization) or skip everything past the end
-device (early exit).  Intra-task layer parallelism is honoured through
-per-hop tx/rx offsets measured by the single-task event simulation (Fig. 4
-virtual-block overlap).  The event loop itself lives in
-``repro.core.sim.simulate_stream`` — the same core that scores offline
-partitions — so planning and replay share one semantics.
+``2n+1`` resources — end device, per-hop links, intermediate edge tiers,
+cloud — process a stream of tasks (Fig. 2); the paper's 3-resource
+testbed is ``n_hops = 1``.  Links are serial FIFO; each compute tier is
+a pool of replicas, one by default (the serial chain).  Per task the
+stage durations come from the offline partition's ``StageTimes``; the
+online component may override transmission bits (adaptive quantization)
+or skip everything past the end device (early exit).  Intra-task layer
+parallelism is honoured through per-hop tx/rx offsets measured by the
+single-task event simulation (Fig. 4 virtual-block overlap).  The event
+loop itself lives in ``repro.core.sim.simulate_stream`` — the same core
+that scores offline partitions — so planning and replay share one
+semantics.
 
 Outputs latency, throughput, and explicit per-resource bubble accounting
 (idle time within the active window) — the quantities COACH is designed to
@@ -133,9 +135,9 @@ class PipelineResult:
     # executor's recorded timeline) — empty tuples when not recorded
     compute_intervals: Tuple[Tuple[sim.Interval, ...], ...] = ()
     link_intervals: Tuple[Tuple[sim.Interval, ...], ...] = ()
-    # replicas per compute tier when the run used replicated pools
-    # (() = classic single-replica chain); compute_busy[k] then sums the
-    # tier's replicas, so utilization is against m * makespan
+    # replicas per compute tier (() = not recorded, one each);
+    # compute_busy[k] sums the tier's replicas, so utilization is
+    # against m * makespan
     pool_sizes: Tuple[int, ...] = ()
 
     # ---- classic 3-resource views
@@ -245,8 +247,11 @@ def plan_from_stage_times(st: StageTimes, early_exit: bool = False,
 
 
 def result_from_stream(res: sim.StreamResult) -> PipelineResult:
-    """Wrap a raw resource timeline (from ``sim.simulate_stream`` or the
-    async hop-queue executor) into the engine-facing result type."""
+    """Wrap a resource timeline (from ``sim.simulate_stream`` or the
+    async hop-queue executor) into the engine-facing result type.  The
+    tier view merges each pool's replica intervals; ``pool_sizes`` keeps
+    the replica counts so utilization is judged against
+    ``m * makespan``."""
     recs = [TaskRecord(i, arr, d, d - arr, ee, eh)
             for i, (arr, d, ee, eh) in enumerate(zip(res.arrivals, res.done,
                                                      res.early_exit,
@@ -254,18 +259,8 @@ def result_from_stream(res: sim.StreamResult) -> PipelineResult:
     return PipelineResult(recs, res.makespan, res.compute_busy,
                           res.link_busy,
                           compute_intervals=res.compute_intervals,
-                          link_intervals=res.link_intervals)
-
-
-def result_from_pool_stream(res: sim.PoolStreamResult) -> PipelineResult:
-    """Wrap a replicated-tier timeline (``sim.simulate_pool_stream`` or
-    the async pool executor) into the engine-facing result type.  The
-    tier view merges each pool's replica intervals; ``pool_sizes`` keeps
-    the replica counts so utilization is judged against
-    ``m * makespan``."""
-    pr = result_from_stream(res.as_stream_result())
-    pr.pool_sizes = tuple(p.m for p in res.pools)
-    return pr
+                          link_intervals=res.link_intervals,
+                          pool_sizes=tuple(p.m for p in res.pools))
 
 
 def run_pipeline(plans: Sequence[TaskPlan],
@@ -279,15 +274,14 @@ def run_pipeline(plans: Sequence[TaskPlan],
     """Execute the task stream.  ``link`` (classic) or ``links`` (one per
     hop) with a bandwidth trace re-integrates each task's transmission
     time at its actual start time (dynamic networks, Fig. 5).
-    ``batch_caps`` enables per-tier continuous micro-batching (see
-    ``sim.simulate_stream``).  ``pools`` (per-tier replica pools, see
-    ``sim.PoolSpec``) with a ``router`` (``serving.routing`` policy,
-    duck-typed here so the core stays serving-free) runs the replicated
-    DAG path instead of the serial chain.  ``sink`` (a
-    ``repro.obs.trace`` span sink) records the timeline as spans; the
-    async executor emits the same spans, so traces are differentially
-    pinned like results.  ``migrate`` is the online re-planning hook of
-    ``sim.simulate_stream`` (chain path only)."""
+    ``batch_caps`` enables per-tier continuous micro-batching, ``pools``
+    (per-tier replica pools, see ``sim.PoolSpec``) with a ``router``
+    (``serving.routing`` policy, duck-typed here so the core stays
+    serving-free) replicates tiers, and ``migrate`` is the online
+    re-planning hook (see ``sim.simulate_stream`` for all three).
+    ``sink`` (a ``repro.obs.trace`` span sink) records the timeline as
+    spans; the async executor emits the same spans, so traces are
+    differentially pinned like results."""
     n = len(plans)
     if arrivals is None:
         arrivals = [i * arrival_period for i in range(n)]
@@ -298,17 +292,9 @@ def run_pipeline(plans: Sequence[TaskPlan],
     # every tier's (idle) resources
     n_hops = max(max(p.n_hops for p in plans), len(links))
     sim_plans = [p.as_sim_plan(n_hops) for p in plans]
-    if pools is not None:
-        assert router is not None, "replicated tiers need a router policy"
-        assert migrate is None, \
-            "plan migration composes with the unbatched chain path only"
-        pres = sim.simulate_pool_stream(sim_plans, arrivals, pools, router,
-                                        links=links, batch_caps=batch_caps,
-                                        sink=sink)
-        return result_from_pool_stream(pres)
-    res = sim.simulate_stream(sim_plans, arrivals, links=links,
-                              batch_caps=batch_caps, sink=sink,
-                              migrate=migrate)
+    res = sim.simulate_stream(sim_plans, arrivals, links=links, pools=pools,
+                              router=router, batch_caps=batch_caps,
+                              sink=sink, migrate=migrate)
     return result_from_stream(res)
 
 

@@ -3,8 +3,8 @@
 This module is the single discrete-event core behind both the offline
 partition scorer (``repro.core.schedule.evaluate_partition``) and the
 task-stream executor (``repro.core.pipeline.run_pipeline``).  A
-collaborative deployment is modelled as ``2n+1`` alternating *serial FIFO
-resources*
+collaborative deployment is modelled as ``2n+1`` alternating FIFO
+resources
 
     compute_0, link_0, compute_1, link_1, ..., link_{n-1}, compute_n
 
@@ -30,7 +30,7 @@ quantities (Eq. 2-6, generalized per hop ``k``):
   Eq. 6              objective = sum of bubbles + max stage, computed by
                      ``repro.core.schedule.StageTimes`` from this timeline.
 
-Two entry points:
+Entry points:
 
 ``simulate_partitioned_task``
     One task through a partitioned ``ModelGraph``: each segment executes
@@ -46,9 +46,18 @@ Two entry points:
     A stream of tasks, each a ``SimPlan`` of per-segment compute
     durations and per-hop transmission durations (with optional
     intra-task overlap offsets measured by the single-task simulation),
-    replayed over the same ``2n+1`` serial resources.  Per-hop links with
-    a bandwidth trace re-integrate each transfer at its actual start
-    time (dynamic networks, Fig. 5).
+    replayed tier by tier over the same resources.  Compute tier ``k``
+    is a *pool* (``PoolSpec``) of ``m`` replicas with speed multipliers
+    behind a pluggable router (join-shortest-queue /
+    power-of-two-choices / random / tenant-affinity, in
+    ``repro.serving.routing``) that places each task at enqueue time in
+    per-stream order; a per-pool sequencer restores admission order
+    toward each serial hop link.  A serial chain is every tier a pool of
+    one unit-speed replica, the default.  Per-tier caps enable
+    continuous micro-batching; per-hop links with a bandwidth trace
+    re-integrate each transfer at its actual start time (dynamic
+    networks, Fig. 5); a ``migrate`` hook re-plans tasks at hop
+    boundaries.
 
 Both entry points share the same event semantics, so the partitioner
 scores candidates with exactly the timeline the stream executor replays.
@@ -57,24 +66,15 @@ scores candidates with exactly the timeline the stream executor replays.
 multi-tenant arrivals: several per-tenant task streams are merged into
 one admission sequence by a pluggable admission policy (FIFO /
 round-robin / weighted deficit round-robin, implemented in
-``repro.serving.tenancy``), gated by the shared ingress resource
-(``compute_0``), and the merged stream replays over the same ``2n+1``
-serial resources.  The async multi-tenant executor
-(``repro.serving.tenancy.MultiTenantHopPipeline``) realizes the same
-gate with event-driven ingress credits, so the two admission orders —
-and therefore the two timelines — are differentially pinned by
-``tests/test_tenancy.py``.
+``repro.serving.tenancy``), gated by the ingress pool's credits
+(``multitenant_admission``), and the merged stream replays through
+``simulate_stream``.
 
-``simulate_pool_stream`` generalizes the chain to a DAG of *resource
-pools*: tier ``k`` becomes ``PoolSpec`` — ``m`` replica resources with
-heterogeneous speed multipliers — behind a pluggable router policy
-(join-shortest-queue / power-of-two-choices / tenant-affinity, in
-``repro.serving.routing``) that places each task at enqueue time in
-per-stream order; a per-pool sequencer restores admission order toward
-each serial hop link.  ``m = 1`` unit pools on every tier reduce
-bit-identically to ``simulate_stream``, and the async pool executor
-(``repro.serving.async_engine.AsyncHopPipeline`` with ``pools=``) is
-differentially pinned to it by ``tests/test_pools.py``.
+The async executor (``repro.serving.async_engine.AsyncHopPipeline``,
+and ``repro.serving.tenancy.MultiTenantHopPipeline`` on top of it) runs
+the same model on per-resource workers and is differentially pinned to
+this replay by ``tests/test_async_engine.py``, ``tests/test_pools.py``
+and ``tests/test_tenancy.py``.
 """
 
 from __future__ import annotations
@@ -416,8 +416,8 @@ def greedy_batch_size(k: int, cap: int, s: float,
     reorders tasks.
 
     ``speed`` scales the batch's service time for heterogeneous pool
-    replicas (``PoolSpec.speeds``); the default 1.0 keeps the chain
-    path's float arithmetic bit-identical (``s + 1.0 * t == s + t``)."""
+    replicas (``PoolSpec.speeds``); a unit replica's arithmetic is
+    unscaled (``s + 1.0 * t == s + t``)."""
     inf = float("inf")
     d0 = plans[0].deadline
     dmin = d0 if d0 is not None else inf
@@ -434,511 +434,6 @@ def greedy_batch_size(k: int, cap: int, s: float,
     return n
 
 
-@dataclasses.dataclass
-class StreamResult:
-    """Per-resource accounting of a simulated task stream.
-
-    ``compute_intervals[k]`` / ``link_intervals[k]`` are the per-resource
-    busy intervals (one ``(start, end)`` per task that occupied the
-    resource, in admission order) — the raw timeline, exposed so an
-    executor's recorded schedule can be compared against the simulator's
-    interval by interval.
-
-    ``early_exit[i]`` is True iff task ``i`` exited before the last
-    segment; ``exit_hop[i]`` names the segment it terminated at (``None``
-    = full pipeline).  Downstream of the exit, the task occupies nothing
-    — use ``occupies_compute``/``occupies_link`` to map a resource's
-    interval list back to the tasks that produced it.
-
-    Under micro-batching a compute interval may serve several tasks at
-    once: ``compute_batch_sizes[k][b]`` counts the occupying tasks served
-    by ``compute_intervals[k][b]`` (consecutive in admission order).
-    Empty means every interval is a singleton — the unbatched 1:1
-    task-to-interval mapping.  Link transfers are never batched, so link
-    intervals always stay 1:1."""
-    arrivals: List[float]
-    done: List[float]
-    early_exit: List[bool]
-    makespan: float
-    compute_busy: Tuple[float, ...]
-    link_busy: Tuple[float, ...]
-    compute_intervals: Tuple[Tuple[Interval, ...], ...] = ()
-    link_intervals: Tuple[Tuple[Interval, ...], ...] = ()
-    exit_hop: List[Optional[int]] = dataclasses.field(default_factory=list)
-    compute_batch_sizes: Tuple[Tuple[int, ...], ...] = ()
-
-    def __post_init__(self):
-        if not self.exit_hop:
-            self.exit_hop = [0 if e else None for e in self.early_exit]
-
-
-def simulate_stream(plans: Sequence[SimPlan],
-                    arrivals: Sequence[float],
-                    links: Optional[Sequence[Optional[LinkProfile]]] = None,
-                    batch_caps: Optional[Sequence[int]] = None,
-                    sink=None,
-                    ingress_enqueues: Optional[Sequence[float]] = None,
-                    migrate=None
-                    ) -> StreamResult:
-    """Replay a task stream over the ``2n+1`` serial resources.
-
-    Tasks are admitted in order; every resource is serial FIFO.  If
-    ``links[k]`` carries a bandwidth trace, hop ``k``'s transfers are
-    re-integrated at their actual start times (the planned duration is
-    interpreted as a bit volume at the link's nominal bandwidth).
-
-    A task with ``exit_hop = e`` terminates at segment ``e``: it runs
-    compute ``0..e`` and links ``0..e-1`` and releases every downstream
-    resource at the exit instant (hop-level semantic early exit).
-
-    ``batch_caps[k]`` (one per compute segment) enables continuous
-    micro-batching on tier ``k``: a free worker drains the tasks queued
-    at its wake instant into one batch, bounded by the cap and by the
-    members' staleness deadlines (``greedy_batch_size``), and the tier
-    is occupied once for ``batched_service_time``.  ``None`` — or caps
-    of all ones — replays the classic one-task-per-slot timeline.
-
-    ``sink`` is an optional trace sink (``repro.obs.trace``): when set,
-    every enqueue/service/transfer/exit event is emitted as a span with
-    exactly the instants of this timeline — the async executor emits the
-    same spans, so traces are differentially pinned like results.  Every
-    emission is guarded by ``sink is not None`` (zero cost disabled).
-    ``ingress_enqueues[i]`` overrides the *reported* tier-0 enqueue
-    instant of task ``i`` (the multi-tenant gate dispatches at ``t_d >=
-    arrival``; the chain timeline is unaffected because the credit gate
-    never delays a task past ``max(arrival, free_0)``).
-
-    ``migrate(i, k, tx_ready) -> Optional[SimPlan]`` is the online
-    re-planning hook (``repro.scenarios``): it is consulted once per
-    task per hop, at the instant the task's hop-``k`` boundary data is
-    ready to transmit.  Returning a plan switches task ``i`` to it for
-    the remainder of its pipeline — the hop-``k`` transfer (volume,
-    offsets) and all downstream segments are priced under the new plan,
-    while everything already replayed stays charged to the old one (a
-    task completes its current segment under the old plan, then
-    continues under the new cut/bits).  The hook's arguments depend
-    only on the task's own timeline, never on a clock, so the async
-    executor reaches identical migration decisions; the switch is
-    recorded as a ``replan`` span.  The returned plan must preserve
-    the task's hop count and exit hop.  Migration composes with the
-    unbatched chain path only (no micro-batching, no pools)."""
-    assert plans, "empty stream"
-    if batch_caps is not None and any(c > 1 for c in batch_caps):
-        assert migrate is None, \
-            "plan migration composes with the unbatched chain path only"
-        return _simulate_stream_batched(plans, arrivals, links, batch_caps,
-                                        sink=sink,
-                                        ingress_enqueues=ingress_enqueues)
-    n_hops = len(plans[0].tx)
-    n_seg = n_hops + 1
-    compute_free = [0.0] * n_seg
-    link_free = [0.0] * n_hops
-    compute_busy = [0.0] * n_seg
-    link_busy = [0.0] * n_hops
-    compute_iv: List[List[Interval]] = [[] for _ in range(n_seg)]
-    link_iv: List[List[Interval]] = [[] for _ in range(n_hops)]
-    done: List[float] = []
-    exits: List[bool] = []
-    exit_hops: List[Optional[int]] = []
-    enq_acc = 0.0
-    for i, (p, arr) in enumerate(zip(plans, arrivals)):
-        assert len(p.tx) == n_hops, "mixed hop counts in one stream"
-        e = p.exit_hop if p.exit_hop is not None else n_hops
-        s = max(arr, compute_free[0])
-        d = s + p.compute[0]
-        compute_free[0] = d
-        compute_busy[0] += p.compute[0]
-        compute_iv[0].append((s, d))
-        exits.append(p.exit_hop is not None)
-        exit_hops.append(p.exit_hop)
-        if sink is not None:
-            enq_acc = arr if arr > enq_acc else enq_acc
-            e0 = ingress_enqueues[i] if ingress_enqueues is not None \
-                else enq_acc
-            sink.span(Span(ENQUEUE, ("compute", 0), e0, e0, task=i))
-            sink.span(Span(SERVICE, ("compute", 0, 0), s, d, task=i,
-                           tasks=(i,), ready=arr, batch=1))
-        if e == 0:
-            done.append(d)
-            if sink is not None:
-                sink.span(Span(EXIT_RELEASE, ("compute", 0, 0), d, d,
-                               task=i, hop=0))
-            continue
-        prev_start, prev_done = s, d
-        for k in range(e):
-            off = p.tx_offset[k]
-            tx_ready = prev_done if off is None or off >= p.compute[k] \
-                else prev_start + off
-            if migrate is not None:
-                newp = migrate(i, k, tx_ready)
-                if newp is not None:
-                    assert len(newp.tx) == n_hops \
-                        and newp.exit_hop == p.exit_hop, \
-                        "migrated plan must preserve hop count and exit hop"
-                    p = newp
-                    if sink is not None:
-                        sink.span(Span(REPLAN, ("link", k), tx_ready,
-                                       tx_ready, task=i, hop=k))
-            t_start = max(tx_ready, link_free[k])
-            t_dur = p.tx[k]
-            lk = links[k] if links is not None and k < len(links) else None
-            if lk is not None and lk.trace is not None and t_dur > 0:
-                # re-integrate the same bit volume under the live trace
-                bits = t_dur * lk.bandwidth_bps
-                t_dur = lk.transfer_time(bits, t_start)
-            t_done = t_start + t_dur
-            link_free[k] = t_done
-            link_busy[k] += t_dur
-            link_iv[k].append((t_start, t_done))
-            roff = p.rx_offset[k]
-            c_ready = t_done if roff is None \
-                else max(t_start + roff, tx_ready)
-            c_start = max(c_ready, compute_free[k + 1])
-            # downstream compute cannot finish before all data has arrived
-            c_done = max(c_start + p.compute[k + 1], t_done)
-            compute_free[k + 1] = c_done
-            compute_busy[k + 1] += p.compute[k + 1]
-            compute_iv[k + 1].append((c_start, c_start + p.compute[k + 1]))
-            if sink is not None:
-                sink.span(Span(XFER, ("link", k), t_start, t_done,
-                               task=i, ready=tx_ready))
-                # next-tier enqueue = the executor link worker's put
-                # instant (partial-forward under an rx offset)
-                tq = t_start + min(max(c_ready - t_start, 0.0), t_dur)
-                sink.span(Span(ENQUEUE, ("compute", k + 1), tq, tq, task=i))
-                sink.span(Span(SERVICE, ("compute", k + 1, 0), c_start,
-                               c_start + p.compute[k + 1], task=i,
-                               tasks=(i,), ready=c_ready, batch=1))
-            prev_start, prev_done = c_start, c_done
-        done.append(prev_done)
-        if sink is not None and p.exit_hop is not None:
-            sink.span(Span(EXIT_RELEASE, ("compute", e, 0), prev_done,
-                           prev_done, task=i, hop=e))
-    arrivals = list(arrivals[:len(done)])
-    makespan = max(done) - min(arrivals)
-    return StreamResult(arrivals=arrivals, done=done, early_exit=exits,
-                        makespan=makespan,
-                        compute_busy=tuple(compute_busy),
-                        link_busy=tuple(link_busy),
-                        compute_intervals=tuple(tuple(iv) for iv in compute_iv),
-                        link_intervals=tuple(tuple(iv) for iv in link_iv),
-                        exit_hop=exit_hops)
-
-
-def _simulate_stream_batched(
-        plans: Sequence[SimPlan],
-        arrivals: Sequence[float],
-        links: Optional[Sequence[Optional[LinkProfile]]],
-        batch_caps: Sequence[int],
-        sink=None,
-        ingress_enqueues: Optional[Sequence[float]] = None) -> StreamResult:
-    """Staged replay of ``simulate_stream`` with per-tier micro-batching.
-
-    Tiers are replayed one at a time (tier 0, link 0, tier 1, ...) —
-    legal because tasks flow strictly forward, so a tier's inputs are
-    fully determined by the previous link's outputs.  Each compute tier
-    drains its pending tasks with the same greedy
-    drain-up-to-cap-or-deadline rule the event-driven workers in
-    ``repro.serving.async_engine`` apply: batch membership is decided
-    against the queue state at the worker's *wake* instant, service is
-    priced by ``batched_service_time``, and exit-hop members leave the
-    batch at their tier.  Members of a multi-task batch forward serially
-    (the batch launch owns the tier until it completes, so the Fig. 4
-    intra-task overlap offsets only apply to singleton batches).  With
-    every cap at 1 the replay uses the same float expressions as the
-    classic interleaved loop."""
-    n_hops = len(plans[0].tx)
-    n_seg = n_hops + 1
-    caps = [int(batch_caps[k]) if k < len(batch_caps) else 1
-            for k in range(n_seg)]
-    assert all(c >= 1 for c in caps), "batch caps must be >= 1"
-    for p in plans:
-        assert len(p.tx) == n_hops, "mixed hop counts in one stream"
-    # tier-0 batches gather same-instant arrivals, so batching the ingress
-    # tier needs arrival order = admission order (deeper tiers see
-    # monotone hand-off instants by construction, any arrival order)
-    assert caps[0] <= 1 or all(
-        a0 <= a1 for a0, a1 in zip(arrivals, arrivals[1:])), \
-        "batching tier 0 needs non-decreasing arrivals (admission order)"
-    compute_busy = [0.0] * n_seg
-    link_busy = [0.0] * n_hops
-    compute_iv: List[List[Interval]] = [[] for _ in range(n_seg)]
-    comp_bs: List[List[int]] = [[] for _ in range(n_seg)]
-    link_iv: List[List[Interval]] = [[] for _ in range(n_hops)]
-    done: List[float] = [0.0] * len(plans)
-    link_free = [0.0] * n_hops
-
-    # pending task state entering the current tier, FIFO by admission:
-    # (task index, queue-enqueue instant, input-ready instant, data-done)
-    pend: List[Tuple[int, float, float, float]] = []
-    enq = 0.0
-    for i, arr in enumerate(arrivals):
-        enq = arr if arr > enq else enq   # the admitter is serial
-        pend.append((i, enq, float(arr), float(arr)))
-        if sink is not None:
-            e0 = ingress_enqueues[i] if ingress_enqueues is not None else enq
-            sink.span(Span(ENQUEUE, ("compute", 0), e0, e0, task=i))
-
-    for k in range(n_seg):
-        cap = caps[k]
-        free = 0.0
-        nxt: List[Tuple[int, float]] = []   # (task index, tx_ready) -> link k
-        i = 0
-        while i < len(pend):
-            idx0, enq0, ready0, dd0 = pend[i]
-            wake = max(enq0, free)
-            s = max(ready0, wake)
-            n_b = 1
-            if cap > 1:
-                # candidate set = FIFO queue snapshot at the wake instant
-                # (enqueue instants are non-decreasing, so it is a prefix)
-                j = i + 1
-                while j < len(pend) and pend[j][1] <= wake:
-                    j += 1
-                cand = pend[i:j]
-                n_b = greedy_batch_size(
-                    k, cap, s, [plans[e[0]] for e in cand],
-                    [e[2] for e in cand])
-            batch = pend[i:i + n_b]
-            i += n_b
-            if n_b == 1:
-                p = plans[idx0]
-                comp = p.compute[k]
-                compute_busy[k] += comp
-                compute_iv[k].append((s, s + comp))
-                comp_bs[k].append(1)
-                if sink is not None:
-                    sink.span(Span(SERVICE, ("compute", k, 0), s, s + comp,
-                                   task=idx0, tasks=(idx0,), ready=ready0,
-                                   batch=1))
-                fin = max(s + comp, dd0)
-                free = fin
-                if k == n_hops or (p.exit_hop is not None
-                                   and k >= p.exit_hop):
-                    done[idx0] = fin
-                    if sink is not None and p.exit_hop is not None:
-                        sink.span(Span(EXIT_RELEASE, ("compute", k, 0),
-                                       fin, fin, task=idx0, hop=p.exit_hop))
-                else:
-                    off = p.tx_offset[k]
-                    tx_ready = fin if off is None or off >= comp else s + off
-                    nxt.append((idx0, tx_ready))
-                continue
-            dur = batched_service_time([plans[e[0]] for e in batch], k)
-            compute_busy[k] += dur
-            compute_iv[k].append((s, s + dur))
-            comp_bs[k].append(n_b)
-            if sink is not None:
-                sink.span(Span(SERVICE, ("compute", k, 0), s, s + dur,
-                               task=idx0,
-                               tasks=tuple(e[0] for e in batch),
-                               ready=ready0, batch=n_b))
-                for (idx_m, _, ready_m, _) in batch[1:]:
-                    if s > ready_m:
-                        sink.span(Span(BATCH_FORM, ("compute", k, 0),
-                                       ready_m, s, task=idx_m))
-            end = s + dur
-            fin = end
-            for (idx_m, _, _, dd_m) in batch:
-                p = plans[idx_m]
-                fin = max(end, dd_m)   # data-done gates each completion
-                if k == n_hops or (p.exit_hop is not None
-                                   and k >= p.exit_hop):
-                    done[idx_m] = fin
-                    if sink is not None and p.exit_hop is not None:
-                        sink.span(Span(EXIT_RELEASE, ("compute", k, 0),
-                                       fin, fin, task=idx_m, hop=p.exit_hop))
-                else:
-                    nxt.append((idx_m, fin))
-            free = fin
-
-        if k == n_hops:
-            break
-        new_pend: List[Tuple[int, float, float, float]] = []
-        for (idx, tx_ready) in nxt:
-            p = plans[idx]
-            t_start = max(tx_ready, link_free[k])
-            t_dur = p.tx[k]
-            lk = links[k] if links is not None and k < len(links) else None
-            if lk is not None and lk.trace is not None and t_dur > 0:
-                bits = t_dur * lk.bandwidth_bps
-                t_dur = lk.transfer_time(bits, t_start)
-            t_done = t_start + t_dur
-            link_free[k] = t_done
-            link_busy[k] += t_dur
-            link_iv[k].append((t_start, t_done))
-            roff = p.rx_offset[k]
-            c_ready = t_done if roff is None \
-                else max(t_start + roff, tx_ready)
-            # the task reaches the next tier's queue the moment enough of
-            # the tensor is across — the same instant (same float
-            # expression) the executor's link worker performs its put
-            fwd = min(max(c_ready - t_start, 0.0), t_dur)
-            new_pend.append((idx, t_start + fwd, c_ready, t_done))
-            if sink is not None:
-                sink.span(Span(XFER, ("link", k), t_start, t_done,
-                               task=idx, ready=tx_ready))
-                sink.span(Span(ENQUEUE, ("compute", k + 1), t_start + fwd,
-                               t_start + fwd, task=idx))
-        pend = new_pend
-
-    arr_list = list(arrivals)
-    makespan = max(done) - min(arr_list)
-    return StreamResult(arrivals=arr_list, done=done,
-                        early_exit=[p.exit_hop is not None for p in plans],
-                        makespan=makespan,
-                        compute_busy=tuple(compute_busy),
-                        link_busy=tuple(link_busy),
-                        compute_intervals=tuple(tuple(iv) for iv in compute_iv),
-                        link_intervals=tuple(tuple(iv) for iv in link_iv),
-                        exit_hop=[p.exit_hop for p in plans],
-                        compute_batch_sizes=tuple(tuple(b)
-                                                  for b in comp_bs))
-
-
-# ============================================================ multi-tenant
-TenantSlot = Tuple[int, int]  # (tenant index, per-tenant task index)
-
-
-def multitenant_admission_order(
-        plans: Sequence[Sequence[SimPlan]],
-        arrivals: Sequence[Sequence[float]],
-        policy,
-        sink=None,
-        return_enqueues: bool = False):
-    """Merge per-tenant FIFO streams into one global admission sequence.
-
-    Admission is gated by the shared ingress resource (``compute_0``):
-    each dispatch decision happens at ``t_d = max(free_0, earliest
-    pending arrival)``, the *candidates* are the tenants whose head task
-    has arrived by ``t_d``, and ``policy.pick(candidates, heads)``
-    chooses among them (``heads[t] = (arrival, per-tenant index,
-    SimPlan)``).  Within a tenant, tasks are admitted strictly in
-    arrival (index) order — the policy only interleaves *across*
-    tenants.
-
-    ``policy`` is any object with ``reset(n_tenants)`` and
-    ``pick(candidates, heads) -> tenant`` (the admission schedulers live
-    in ``repro.serving.tenancy``; the policy state machine is shared
-    between this arithmetic gate and the executor's event-driven ingress
-    credits, so the differential harness pins the *gating semantics*,
-    not the policy code).
-
-    ``sink`` emits a ``credit_wait`` span per dispatch held past its
-    task's arrival (the executor's dispatcher emits the same span at its
-    put instant).  ``return_enqueues=True`` additionally returns the
-    per-slot dispatch instants ``t_d`` (the true tier-0 enqueue times,
-    fed to ``simulate_stream(ingress_enqueues=...)`` for tracing).
-    """
-    n_t = len(plans)
-    assert len(arrivals) == n_t
-    for t in range(n_t):
-        assert len(plans[t]) == len(arrivals[t]), f"tenant {t} length mismatch"
-        assert all(a0 <= a1 for a0, a1 in zip(arrivals[t], arrivals[t][1:])), \
-            f"tenant {t} arrivals must be non-decreasing"
-    total = sum(len(p) for p in plans)
-    heads = [0] * n_t
-    free0 = 0.0
-    order: List[TenantSlot] = []
-    enqueues: List[float] = []
-    policy.reset(n_t)
-    while len(order) < total:
-        pend = [t for t in range(n_t) if heads[t] < len(plans[t])]
-        t_min = min(arrivals[t][heads[t]] for t in pend)
-        t_d = max(free0, t_min)
-        cands = [t for t in pend if arrivals[t][heads[t]] <= t_d]
-        info = {t: (arrivals[t][heads[t]], heads[t], plans[t][heads[t]])
-                for t in cands}
-        t = policy.pick(cands, info)
-        assert t in info, f"policy picked non-candidate tenant {t}"
-        i = heads[t]
-        heads[t] += 1
-        if sink is not None and t_d > arrivals[t][i]:
-            sink.span(Span(CREDIT_WAIT, ("compute", 0), arrivals[t][i],
-                           t_d, task=len(order)))
-        order.append((t, i))
-        enqueues.append(t_d)
-        free0 = max(arrivals[t][i], free0) + plans[t][i].compute[0]
-    return (order, enqueues) if return_enqueues else order
-
-
-@dataclasses.dataclass
-class MultiTenantStreamResult:
-    """A merged multi-tenant timeline plus its tenant tagging.
-
-    ``stream`` is the merged-stream result in admission order;
-    ``order[j]`` names the tenant and per-tenant task index occupying
-    global slot ``j``.  ``n_tenants`` is the declared tenant count (not
-    derived from ``order`` — a tenant that admitted zero tasks still
-    counts).  Per-resource busy intervals follow the same slot order
-    (a resource's interval list only contains the slots that occupy it —
-    a task exiting at segment ``e`` occupies compute ``0..e`` and links
-    ``0..e-1``; see ``occupies_compute``/``occupies_link``), so an
-    executor's recorded multi-tenant schedule can be compared per tenant
-    as well as per resource."""
-    stream: StreamResult
-    order: Tuple[TenantSlot, ...]
-    n_tenants: int = 0
-
-    def tenant_slots(self, tenant: int) -> List[int]:
-        """Global slot indices occupied by ``tenant``, in admission
-        (= per-tenant FIFO) order."""
-        return [j for j, (t, _) in enumerate(self.order) if t == tenant]
-
-    def tenant_view(self, tenant: int
-                    ) -> Tuple[List[float], List[float], List[bool]]:
-        """``(arrivals, done, early_exit)`` of one tenant's tasks, in
-        per-tenant order."""
-        s = self.stream
-        slots = self.tenant_slots(tenant)
-        return ([s.arrivals[j] for j in slots], [s.done[j] for j in slots],
-                [s.early_exit[j] for j in slots])
-
-    def tenant_exit_hops(self, tenant: int) -> List[Optional[int]]:
-        """Per-task exit hops of one tenant, in per-tenant order."""
-        return [self.stream.exit_hop[j] for j in self.tenant_slots(tenant)]
-
-    def tenant_latencies(self, tenant: int) -> List[float]:
-        arr, done, _ = self.tenant_view(tenant)
-        return [d - a for a, d in zip(arr, done)]
-
-
-def simulate_multitenant_stream(
-        plans: Sequence[Sequence[SimPlan]],
-        arrivals: Sequence[Sequence[float]],
-        policy,
-        links: Optional[Sequence[Optional[LinkProfile]]] = None,
-        batch_caps: Optional[Sequence[int]] = None,
-        sink=None,
-        migrate=None
-        ) -> MultiTenantStreamResult:
-    """Replay tagged multi-tenant task streams over the shared ``2n+1``
-    resources: compute the policy's admission order (gated by the
-    ingress resource), then replay the merged stream with
-    ``simulate_stream``.  This is the reference timeline the async
-    multi-tenant executor is pinned to.
-
-    ``batch_caps`` enables per-tier micro-batching on the merged stream.
-    The ingress tier's cap is forced to 1: multi-tenant admission is
-    credit-gated one task at a time (the dispatcher holds the next task
-    until ``compute_0`` frees), so the ingress queue never holds more
-    than one task and batching there would diverge from the admission
-    gate both engines implement."""
-    order, enqueues = multitenant_admission_order(plans, arrivals, policy,
-                                                  sink=sink,
-                                                  return_enqueues=True)
-    assert order, "empty multi-tenant stream"
-    merged_plans = [plans[t][i] for (t, i) in order]
-    merged_arr = [arrivals[t][i] for (t, i) in order]
-    if batch_caps is not None:
-        batch_caps = [1] + [int(c) for c in batch_caps[1:]]
-    res = simulate_stream(merged_plans, merged_arr, links=links,
-                          batch_caps=batch_caps, sink=sink,
-                          ingress_enqueues=enqueues, migrate=migrate)
-    return MultiTenantStreamResult(stream=res, order=tuple(order),
-                                   n_tenants=len(plans))
-
-
 # ============================================================ resource pools
 @dataclasses.dataclass(frozen=True)
 class PoolSpec:
@@ -946,11 +441,11 @@ class PoolSpec:
 
     ``speeds[r]`` is replica ``r``'s service-time multiplier: a plan's
     segment-``k`` occupation on replica ``r`` costs
-    ``speeds[r] * plan.compute[k]`` (so 1.0 is the chain's reference
-    device, 2.0 a half-speed replica, 0.5 a double-speed one).  A pool
-    of one unit-speed replica is exactly the chain's serial resource —
-    ``simulate_pool_stream`` over all-``m=1`` unit pools is bit-identical
-    to ``simulate_stream`` (tested)."""
+    ``speeds[r] * plan.compute[k]`` (so 1.0 is the reference device, 2.0
+    a half-speed replica, 0.5 a double-speed one).  A pool of one
+    unit-speed replica is a serial FIFO resource: a serial chain is
+    every tier such a pool (``1.0 * x == x``, so its float arithmetic is
+    unscaled)."""
     speeds: Tuple[float, ...] = (1.0,)
 
     def __post_init__(self):
@@ -969,8 +464,9 @@ def as_pools(pools, n_seg: int) -> Tuple[PoolSpec, ...]:
     """Normalize a per-tier pool description into ``PoolSpec`` tuples.
 
     Each entry may be a ``PoolSpec``, an ``int`` replica count (unit
-    speeds), or a sequence of speed multipliers.  Missing tail entries
-    default to a single unit-speed replica (the chain resource)."""
+    speeds), or a sequence of speed multipliers.  ``None`` and missing
+    tail entries default to a single unit-speed replica (a serial
+    resource)."""
     out: List[PoolSpec] = []
     for k in range(n_seg):
         p = pools[k] if pools is not None and k < len(pools) else 1
@@ -985,102 +481,172 @@ def as_pools(pools, n_seg: int) -> Tuple[PoolSpec, ...]:
 
 
 @dataclasses.dataclass
-class PoolStreamResult:
-    """Per-replica accounting of a stream replayed over resource pools.
+class StreamResult:
+    """Per-resource accounting of a replayed (or executed) task stream.
 
-    The tier-level lists of ``StreamResult`` split per replica:
-    ``replica_intervals[k][r]`` / ``replica_busy[k][r]`` /
-    ``replica_batch_sizes[k][r]`` describe replica ``r`` of tier ``k``
-    (links stay serial, one per hop).  ``routes[i][k]`` names the replica
-    task ``i`` ran on at tier ``k`` (``None`` = the task never reached
-    that tier, i.e. it exited upstream).  ``as_stream_result()`` merges
-    the per-replica timelines back into the tier-level ``StreamResult``
-    shape for metric code that does not care about placement."""
+    Tier view: ``compute_intervals[k]`` / ``link_intervals[k]`` are the
+    per-resource busy intervals — one ``(start, end)`` per launch on
+    compute tier ``k`` (merged across its replicas in start order) and
+    one per transfer on link ``k`` — exposed so an executor's recorded
+    schedule can be compared against the simulator's interval by
+    interval.  ``compute_busy[k]`` sums the tier's replicas.
+
+    ``early_exit[i]`` is True iff task ``i`` exited before the last
+    segment; ``exit_hop[i]`` names the segment it terminated at (``None``
+    = full pipeline).  Downstream of the exit, the task occupies nothing
+    — use ``occupies_compute``/``occupies_link`` to map a resource's
+    interval list back to the tasks that produced it.
+
+    Under micro-batching a compute interval may serve several tasks at
+    once: ``compute_batch_sizes[k][b]`` counts the occupying tasks served
+    by ``compute_intervals[k][b]``.  Empty means every interval is a
+    singleton — the 1:1 task-to-interval mapping.  Link transfers are
+    never batched, so link intervals always stay 1:1.
+
+    Replica view: ``replica_intervals[k][r]`` / ``replica_busy[k][r]`` /
+    ``replica_batch_sizes[k][r]`` describe replica ``r`` of tier ``k``,
+    ``routes[i][k]`` names the replica task ``i`` ran on at tier ``k``
+    (``None`` = the task exited upstream), and ``pools`` the tiers'
+    ``PoolSpec``s.  ``from_replicas`` derives the tier view from it."""
     arrivals: List[float]
     done: List[float]
     early_exit: List[bool]
-    exit_hop: List[Optional[int]]
     makespan: float
+    compute_busy: Tuple[float, ...]
     link_busy: Tuple[float, ...]
-    link_intervals: Tuple[Tuple[Interval, ...], ...]
-    replica_busy: Tuple[Tuple[float, ...], ...]
-    replica_intervals: Tuple[Tuple[Tuple[Interval, ...], ...], ...]
-    replica_batch_sizes: Tuple[Tuple[Tuple[int, ...], ...], ...]
-    routes: Tuple[Tuple[Optional[int], ...], ...]
+    compute_intervals: Tuple[Tuple[Interval, ...], ...] = ()
+    link_intervals: Tuple[Tuple[Interval, ...], ...] = ()
+    exit_hop: List[Optional[int]] = dataclasses.field(default_factory=list)
+    compute_batch_sizes: Tuple[Tuple[int, ...], ...] = ()
+    replica_busy: Tuple[Tuple[float, ...], ...] = ()
+    replica_intervals: Tuple[Tuple[Tuple[Interval, ...], ...], ...] = ()
+    replica_batch_sizes: Tuple[Tuple[Tuple[int, ...], ...], ...] = ()
+    routes: Tuple[Tuple[Optional[int], ...], ...] = ()
     pools: Tuple[PoolSpec, ...] = ()
 
-    @property
-    def compute_busy(self) -> Tuple[float, ...]:
-        """Tier-level busy time: sum over the tier's replicas."""
-        return tuple(sum(rb) for rb in self.replica_busy)
+    def __post_init__(self):
+        if not self.exit_hop:
+            self.exit_hop = [0 if e else None for e in self.early_exit]
 
-    def as_stream_result(self) -> StreamResult:
-        """Tier-level view: per-tier intervals merged across replicas in
-        start-time order (stable by replica index), batch sizes carried
-        along; emitted batch sizes only when some batch held > 1 task,
-        matching ``simulate_stream``'s empty-means-singletons convention."""
+    @classmethod
+    def from_replicas(cls, arrivals: Sequence[float], done: List[float],
+                      exit_hop: List[Optional[int]],
+                      link_busy: Sequence[float],
+                      link_intervals: Sequence[Sequence[Interval]],
+                      replica_busy: Sequence[Sequence[float]],
+                      replica_intervals, replica_batch_sizes,
+                      routes: Sequence[Sequence[Optional[int]]],
+                      pools: Tuple[PoolSpec, ...]) -> "StreamResult":
+        """Build the result from per-replica timelines: each tier's
+        intervals merge across replicas in start-time order (stable by
+        replica index) with their batch sizes carried along, which
+        are kept only when some batch held more than one task."""
         comp_iv: List[Tuple[Interval, ...]] = []
         comp_bs: List[Tuple[int, ...]] = []
-        for k in range(len(self.replica_intervals)):
-            tagged = []
-            for r, ivs in enumerate(self.replica_intervals[k]):
-                bss = self.replica_batch_sizes[k][r]
-                for iv, bs in zip(ivs, bss):
-                    tagged.append((iv[0], iv[1], r, bs))
-            tagged.sort(key=lambda t: (t[0], t[1], t[2]))
+        for ivs_k, bss_k in zip(replica_intervals, replica_batch_sizes):
+            tagged = sorted(
+                ((iv[0], iv[1], r, bs)
+                 for r, (ivs, bss) in enumerate(zip(ivs_k, bss_k))
+                 for iv, bs in zip(ivs, bss)), key=lambda t: t[:3])
             comp_iv.append(tuple((t[0], t[1]) for t in tagged))
             comp_bs.append(tuple(t[3] for t in tagged))
         batched = any(b > 1 for bs in comp_bs for b in bs)
-        return StreamResult(
-            arrivals=list(self.arrivals), done=list(self.done),
-            early_exit=list(self.early_exit), makespan=self.makespan,
-            compute_busy=self.compute_busy, link_busy=self.link_busy,
+        arr_list = list(arrivals)
+        return cls(
+            arrivals=arr_list, done=done,
+            early_exit=[eh is not None for eh in exit_hop],
+            makespan=max(done) - min(arr_list),
+            compute_busy=tuple(sum(rb) for rb in replica_busy),
+            link_busy=tuple(link_busy),
             compute_intervals=tuple(comp_iv),
-            link_intervals=self.link_intervals,
-            exit_hop=list(self.exit_hop),
-            compute_batch_sizes=tuple(comp_bs) if batched else ())
+            link_intervals=tuple(tuple(iv) for iv in link_intervals),
+            exit_hop=exit_hop,
+            compute_batch_sizes=tuple(comp_bs) if batched else (),
+            replica_busy=tuple(tuple(rb) for rb in replica_busy),
+            replica_intervals=tuple(tuple(tuple(iv) for iv in tier)
+                                    for tier in replica_intervals),
+            replica_batch_sizes=tuple(tuple(tuple(bs) for bs in tier)
+                                      for tier in replica_batch_sizes),
+            routes=tuple(tuple(rt) for rt in routes),
+            pools=pools)
 
 
-def simulate_pool_stream(plans: Sequence[SimPlan],
-                         arrivals: Sequence[float],
-                         pools,
-                         router,
-                         links: Optional[Sequence[Optional[LinkProfile]]] = None,
-                         batch_caps: Optional[Sequence[int]] = None,
-                         tenants: Optional[Sequence[Optional[int]]] = None,
-                         enqueues: Optional[Sequence[float]] = None,
-                         sink=None
-                         ) -> PoolStreamResult:
-    """Replay a task stream over a DAG of per-tier *resource pools*.
+def simulate_stream(plans: Sequence[SimPlan],
+                    arrivals: Sequence[float],
+                    links: Optional[Sequence[Optional[LinkProfile]]] = None,
+                    pools=None,
+                    router=None,
+                    batch_caps: Optional[Sequence[int]] = None,
+                    tenants: Optional[Sequence[Optional[int]]] = None,
+                    enqueues: Optional[Sequence[float]] = None,
+                    sink=None,
+                    migrate=None) -> StreamResult:
+    """Replay a task stream over the ``2n+1`` resources, tier by tier.
 
-    Generalizes ``simulate_stream``: tier ``k`` is ``pools[k].m`` replica
-    resources (heterogeneous ``speeds`` allowed) behind a router; links
-    stay serial FIFO.  ``router`` is any object with ``reset(pools)`` and
-    ``route(k, ready, compute, tenant) -> replica`` (the policies live in
-    ``repro.serving.routing``; like the admission policies, the state
-    machine is shared with the executor so the differential harness pins
-    the routing *semantics*).  Routing decisions are made at
-    enqueue/arrival time in per-stream order, and router state is kept
-    strictly per tier, so the executor's interleaving of tiers in wall
-    time reaches identical decisions to this tier-by-tier staged replay.
+    Tasks are admitted in order.  Tier ``k`` is ``pools[k]`` (see
+    ``as_pools``; ``None`` = one unit-speed replica per tier, the serial
+    chain); links are serial FIFO.  If ``links[k]`` carries a bandwidth
+    trace, hop ``k``'s transfers are re-integrated at their actual start
+    times (the planned duration is interpreted as a bit volume at the
+    link's nominal bandwidth).  A task with ``exit_hop = e`` runs compute
+    ``0..e`` and links ``0..e-1`` and releases every downstream resource
+    at the exit instant (hop-level semantic early exit).
 
-    Per tier the staged replay is: (1) *dispatch* — route every pending
-    task, in admission order, to a replica; (2) *replica replay* — each
-    replica drains its own FIFO sub-queue under the chain's batching rule
-    (``greedy_batch_size`` with the replica's ``speed``); (3)
-    *sequencer* — completed tasks are forwarded to the hop link in
-    admission order, each at the running max of the release instants so
-    far (the executor's per-pool sequencer worker realizes the same
-    merge); (4) *link* — the serial hop link replays exactly as in
-    ``simulate_stream``.  With every pool at ``m = 1`` and unit speed,
-    every expression reduces to the chain path's — bit-identical
-    timelines (tested).
+    Tiers are replayed one at a time — legal because tasks flow strictly
+    forward, so a tier's inputs are fully determined by the previous
+    link's outputs.  Per tier:
 
-    ``tenants[i]`` tags task ``i`` for tenant-affinity routing;
-    ``enqueues[i]`` overrides task ``i``'s tier-0 enqueue instant (used
-    by the credit-gated multi-tenant admission; both must be
-    non-decreasing — admission order)."""
+    1. *dispatch* — ``router.route(k, ready, compute, tenant)`` places
+       every pending task, in admission order, on a replica.  ``router``
+       is any object with ``reset(pools)`` and that ``route`` (the
+       policies live in ``repro.serving.routing``; the state machine is
+       shared with the executor, so the differential harness pins the
+       routing *semantics*).  A tier of one replica places the task on
+       replica 0 without asking, so a router is needed only where some
+       pool has ``m > 1``.
+    2. *replica replay* — each replica drains its own FIFO sub-queue.
+       ``batch_caps[k]`` (one per compute segment; ``None`` = all ones)
+       enables continuous micro-batching: a free replica drains the
+       tasks queued at its wake instant into one batch, bounded by the
+       cap and the members' staleness deadlines (``greedy_batch_size``),
+       and is occupied once for ``speed * batched_service_time``.
+       Members of a multi-task batch forward serially (the Fig. 4
+       intra-task overlap offsets apply to singleton batches only).
+    3. *sequencer* — completed tasks are forwarded to the hop link in
+       admission order, each at the running max of the release instants
+       so far.  One serial replica releases in order, so there it is the
+       identity.
+    4. *link* — the serial hop link, FIFO.
+
+    ``tenants[i]`` tags task ``i`` for tenant-affinity routing.
+    ``enqueues[i]`` overrides task ``i``'s tier-0 enqueue instant (the
+    credit-gated multi-tenant admission dispatches at ``t_d >=
+    arrival``); it must be non-decreasing (admission order).  Without
+    it, tier 0 enqueues at the running max of arrivals (the admitter is
+    serial).
+
+    ``sink`` is an optional trace sink (``repro.obs.trace``): every
+    enqueue/route/service/transfer/exit event is emitted as a span with
+    exactly the instants of this timeline — the async executor emits the
+    same spans, so traces are differentially pinned like results.  A
+    tier of one replica emits no ``route`` span (nothing was chosen
+    there).  Every emission is guarded by ``sink is not None``.
+
+    ``migrate(i, k, tx_ready) -> Optional[SimPlan]`` is the online
+    re-planning hook (``repro.scenarios``): it is consulted once per
+    task per hop, at the instant the task's hop-``k`` boundary data is
+    ready to transmit.  Returning a plan switches task ``i`` to it for
+    the remainder of its pipeline — the hop-``k`` transfer (volume,
+    offsets) and all downstream segments are priced under the new plan,
+    while everything already replayed stays charged to the old one.
+    The hook's arguments depend only on the task's own timeline, never
+    on a clock, so the async executor reaches identical migration
+    decisions; the switch is recorded as a ``replan`` span.  The
+    returned plan must preserve the task's hop count and exit hop.
+    Migration needs one replica and batch cap 1 on every tier."""
     assert plans, "empty stream"
+    plans = list(plans)   # migration switches a task's plan in place
+    arrivals = list(arrivals[:len(plans)])
     n_hops = len(plans[0].tx)
     n_seg = n_hops + 1
     pools = as_pools(pools, n_seg)
@@ -1089,17 +655,27 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
     assert all(c >= 1 for c in caps), "batch caps must be >= 1"
     for p in plans:
         assert len(p.tx) == n_hops, "mixed hop counts in one stream"
+    assert router is not None or all(p.m == 1 for p in pools), \
+        "replicated tiers need a router policy"
+    assert migrate is None or all(
+        p.m == 1 and c == 1 for p, c in zip(pools, caps)), \
+        "plan migration needs one replica and batch cap 1 on every tier"
     if tenants is None:
         tenants = [None] * len(plans)
     assert len(tenants) == len(plans)
     if enqueues is None:
-        assert all(a0 <= a1 for a0, a1 in zip(arrivals, arrivals[1:])), \
-            "pool streams need non-decreasing arrivals (admission order)"
+        # tier-0 batches gather same-instant arrivals and tier-0 routing
+        # runs in admission order, so both need arrivals in that order
+        assert (caps[0] == 1 and pools[0].m == 1) or all(
+            a0 <= a1 for a0, a1 in zip(arrivals, arrivals[1:])), \
+            "batching or replicating tier 0 needs non-decreasing " \
+            "arrivals (admission order)"
     else:
         assert len(enqueues) == len(plans)
         assert all(e0 <= e1 for e0, e1 in zip(enqueues, enqueues[1:])), \
             "enqueue instants must be non-decreasing (admission order)"
-    router.reset(pools)
+    if router is not None:
+        router.reset(pools)
 
     replica_busy: List[List[float]] = [[0.0] * p.m for p in pools]
     replica_iv: List[List[List[Interval]]] = \
@@ -1135,17 +711,19 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
         assign: List[List[Tuple[int, float, float, float]]] = \
             [[] for _ in range(m)]
         for seq_j, ent in enumerate(pend):
-            r = router.route(k, ent[2], plans[ent[0]].compute[k],
-                             tenants[ent[0]])
-            assert 0 <= r < m, f"router placed task on replica {r} of {m}"
+            r = 0
+            if m > 1:
+                r = router.route(k, ent[2], plans[ent[0]].compute[k],
+                                 tenants[ent[0]])
+                assert 0 <= r < m, f"router placed task on replica {r} of {m}"
+                if sink is not None:
+                    sink.span(Span(ROUTE, ("compute", k, r), ent[2], ent[2],
+                                   task=ent[0], ready=ent[2], replica=r,
+                                   seq=seq_j))
             routes[ent[0]][k] = r
             assign[r].append(ent)
-            if sink is not None:
-                sink.span(Span(ROUTE, ("compute", k, r), ent[2], ent[2],
-                               task=ent[0], ready=ent[2], replica=r,
-                               seq=seq_j))
         # ---- replica replay: each replica drains its own FIFO sub-queue
-        # under the chain's drain-up-to-cap-or-deadline batching rule
+        # under the drain-up-to-cap-or-deadline batching rule
         # release[idx] = (release instant, tx_ready | None if terminal)
         release: Dict[int, Tuple[float, Optional[float]]] = {}
         for r in range(m):
@@ -1159,6 +737,9 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
                 s = max(ready0, wake)
                 n_b = 1
                 if cap > 1:
+                    # candidate set = FIFO queue snapshot at the wake
+                    # instant (enqueue instants are non-decreasing, so it
+                    # is a prefix)
                     j = i + 1
                     while j < len(sub) and sub[j][1] <= wake:
                         j += 1
@@ -1178,6 +759,8 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
                         sink.span(Span(SERVICE, ("compute", k, r), s,
                                        s + comp, task=idx0, tasks=(idx0,),
                                        ready=ready0, batch=1))
+                    # downstream compute cannot finish before all data
+                    # has arrived
                     fin = max(s + comp, dd0)
                     free = fin
                     if k == n_hops or (p.exit_hop is not None
@@ -1230,9 +813,7 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
         # ---- sequencer: restore admission order toward the serial link.
         # A task can go on the wire only once every earlier task has been
         # released by its replica (forwarded or declared terminal), so its
-        # hand-off instant is the running max of release instants — on an
-        # m=1 unit pool releases are already monotone and this is the
-        # identity (bitwise chain equivalence).
+        # hand-off instant is the running max of release instants
         fwd = 0.0
         nxt: List[Tuple[int, float, float]] = []
         for ent in pend:
@@ -1243,14 +824,25 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
                 if sink is not None and fwd > rel:
                     sink.span(Span(SEQ_HOLD, ("link", k), rel, fwd,
                                    task=ent[0]))
-        # ---- link k: serial FIFO, same expressions as simulate_stream
+        # ---- link k: serial FIFO
         new_pend: List[Tuple[int, float, float, float]] = []
         for (idx, tx_ready, fwd_j) in nxt:
+            if migrate is not None:
+                newp = migrate(idx, k, tx_ready)
+                if newp is not None:
+                    assert len(newp.tx) == n_hops \
+                        and newp.exit_hop == plans[idx].exit_hop, \
+                        "migrated plan must preserve hop count and exit hop"
+                    plans[idx] = newp
+                    if sink is not None:
+                        sink.span(Span(REPLAN, ("link", k), tx_ready,
+                                       tx_ready, task=idx, hop=k))
             p = plans[idx]
             t_start = max(tx_ready, fwd_j, link_free[k])
             t_dur = p.tx[k]
             lk = links[k] if links is not None and k < len(links) else None
             if lk is not None and lk.trace is not None and t_dur > 0:
+                # re-integrate the same bit volume under the live trace
                 bits = t_dur * lk.bandwidth_bps
                 t_dur = lk.transfer_time(bits, t_start)
             t_done = t_start + t_dur
@@ -1260,6 +852,9 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
             roff = p.rx_offset[k]
             c_ready = t_done if roff is None \
                 else max(t_start + roff, tx_ready)
+            # the task reaches the next tier's queue the moment enough of
+            # the tensor is across — the same instant (same float
+            # expression) the executor's link worker performs its put
             fwd_frac = min(max(c_ready - t_start, 0.0), t_dur)
             new_pend.append((idx, t_start + fwd_frac, c_ready, t_done))
             if sink is not None:
@@ -1270,63 +865,71 @@ def simulate_pool_stream(plans: Sequence[SimPlan],
                                task=idx))
         pend = new_pend
 
-    arr_list = list(arrivals)
-    makespan = max(done) - min(arr_list)
-    return PoolStreamResult(
-        arrivals=arr_list, done=done,
-        early_exit=[p.exit_hop is not None for p in plans],
-        exit_hop=[p.exit_hop for p in plans],
-        makespan=makespan,
-        link_busy=tuple(link_busy),
-        link_intervals=tuple(tuple(iv) for iv in link_iv),
-        replica_busy=tuple(tuple(rb) for rb in replica_busy),
-        replica_intervals=tuple(tuple(tuple(iv) for iv in tier)
-                                for tier in replica_iv),
-        replica_batch_sizes=tuple(tuple(tuple(bs) for bs in tier)
-                                  for tier in replica_bs),
-        routes=tuple(tuple(rt) for rt in routes),
-        pools=pools)
+    return StreamResult.from_replicas(
+        arrivals, done, [p.exit_hop for p in plans],
+        link_busy, link_iv, replica_busy, replica_iv, replica_bs, routes,
+        pools)
 
 
-def multitenant_pool_admission(
+# ============================================================ multi-tenant
+TenantSlot = Tuple[int, int]  # (tenant index, per-tenant task index)
+
+
+def multitenant_admission(
         plans: Sequence[Sequence[SimPlan]],
         arrivals: Sequence[Sequence[float]],
         policy,
-        pools,
-        router,
+        pools=None,
+        router=None,
         sink=None) -> Tuple[List[TenantSlot], List[float]]:
-    """Pool-ingress admission gate: merge per-tenant streams gated by
-    *pool* ingress credits.
+    """Merge per-tenant FIFO streams into one admission sequence, gated
+    by the ingress pool's credits.
 
-    Generalizes ``multitenant_admission_order`` from one ingress resource
-    to a tier-0 pool of ``m`` replicas: a credit is a token issued the
-    moment *any* tier-0 replica frees, so up to ``m`` tasks are in flight
-    at the ingress at once.  Arithmetically the credit pool is a min-heap
-    of completion instants seeded with ``m`` zeros (the executor's
-    replicas each put one credit before their first get and one at every
-    completion): each dispatch pops the earliest credit ``c`` and happens
-    at ``t_d = max(c, earliest pending arrival)``; the admitted head is
-    routed (``router.route`` on tier 0 — the same call sequence the
-    replay and the executor's dispatcher make), and the task's completion
-    on its replica is pushed back as the next credit.
+    A credit is a token issued the moment any tier-0 replica frees, so
+    up to ``m`` tasks are in flight at the ingress at once (one, for a
+    serial ingress).  Arithmetically the credits are a min-heap of
+    completion instants seeded with ``m`` zeros (the executor's replicas
+    each put one credit before their first get and one at every
+    completion): each dispatch pops the earliest credit ``c`` and
+    happens at ``t_d = max(c, earliest pending arrival)``; the
+    *candidates* are the tenants whose head task has arrived by ``t_d``,
+    and ``policy.pick(candidates, heads)`` chooses among them
+    (``heads[t] = (arrival, per-tenant index, SimPlan)``).  Within a
+    tenant, tasks are admitted strictly in arrival (index) order — the
+    policy only interleaves *across* tenants.  The admitted head is
+    placed on its tier-0 replica (``router.route`` where the pool has
+    ``m > 1`` — the same call sequence the replay and the executor's
+    dispatcher make), and its completion there is pushed back as the
+    next credit.
+
+    ``policy`` is any object with ``reset(n_tenants)`` and
+    ``pick(candidates, heads) -> tenant`` (the admission schedulers live
+    in ``repro.serving.tenancy``; the policy state machine is shared
+    between this arithmetic gate and the executor's event-driven ingress
+    credits, so the differential harness pins the *gating semantics*,
+    not the policy code).  ``sink`` emits a ``credit_wait`` span per
+    dispatch held past its task's arrival (the executor's dispatcher
+    emits the same span at its put instant).
 
     Returns ``(order, enqueues)``: the admission sequence plus each
-    task's dispatch instant ``t_d`` — the replay needs it because under
-    affinity-style routing a task can be held by the credit gate past its
-    routed replica's free instant."""
+    task's dispatch instant ``t_d``, the tier-0 enqueue instant the
+    replay needs (under affinity-style routing a task can be held by the
+    credit gate past its routed replica's free instant)."""
     n_t = len(plans)
     assert len(arrivals) == n_t
     for t in range(n_t):
         assert len(plans[t]) == len(arrivals[t]), f"tenant {t} length mismatch"
         assert all(a0 <= a1 for a0, a1 in zip(arrivals[t], arrivals[t][1:])), \
             f"tenant {t} arrivals must be non-decreasing"
-    n_seg = len(plans[0][0].compute) if plans and plans[0] else 1
-    pools = as_pools(pools, n_seg)
-    router.reset(pools)
-    speeds = pools[0].speeds
-    credits = [0.0] * pools[0].m
+    first = next((ps[0] for ps in plans if ps), None)
+    pools = as_pools(pools, len(first.compute) if first is not None else 1)
+    ingress = pools[0]
+    if ingress.m > 1:
+        assert router is not None, "a replicated ingress needs a router"
+        router.reset(pools)
+    credits = [0.0] * ingress.m
     heapq.heapify(credits)
-    free0 = [0.0] * pools[0].m
+    free0 = [0.0] * ingress.m
     total = sum(len(p) for p in plans)
     heads = [0] * n_t
     order: List[TenantSlot] = []
@@ -1351,52 +954,91 @@ def multitenant_pool_admission(
         enqueues.append(t_d)
         arr = arrivals[t][i]
         p = plans[t][i]
-        r = router.route(0, arr, p.compute[0], t)
+        r = router.route(0, arr, p.compute[0], t) if ingress.m > 1 else 0
         # same float expressions as the replay's tier-0 replica:
         # wake = max(enq, free), s = max(ready, wake), fin = s + speed*c
         s = max(arr, max(t_d, free0[r]))
-        fin = s + speeds[r] * p.compute[0]
+        fin = s + ingress.speeds[r] * p.compute[0]
         free0[r] = fin
         heapq.heappush(credits, fin)
     return order, enqueues
 
 
 @dataclasses.dataclass
-class MultiTenantPoolStreamResult(MultiTenantStreamResult):
-    """Multi-tenant result over pooled tiers: the tenant-tagged
-    tier-level view (``stream`` is the merged ``as_stream_result()``)
-    plus the per-replica pool timeline in ``pool``."""
-    pool: Optional[PoolStreamResult] = None
+class MultiTenantStreamResult:
+    """A merged multi-tenant timeline plus its tenant tagging.
+
+    ``stream`` is the merged-stream result in admission order (with its
+    replica view); ``order[j]`` names the tenant and per-tenant task
+    index occupying global slot ``j``.  ``n_tenants`` is the declared
+    tenant count (not derived from ``order`` — a tenant that admitted
+    zero tasks still counts).  Per-resource busy intervals follow the
+    same slot order (a resource's interval list only contains the slots
+    that occupy it — a task exiting at segment ``e`` occupies compute
+    ``0..e`` and links ``0..e-1``; see ``occupies_compute``/
+    ``occupies_link``), so an executor's recorded multi-tenant schedule
+    can be compared per tenant as well as per resource."""
+    stream: StreamResult
+    order: Tuple[TenantSlot, ...]
+    n_tenants: int = 0
+
+    def tenant_slots(self, tenant: int) -> List[int]:
+        """Global slot indices occupied by ``tenant``, in admission
+        (= per-tenant FIFO) order."""
+        return [j for j, (t, _) in enumerate(self.order) if t == tenant]
+
+    def tenant_view(self, tenant: int
+                    ) -> Tuple[List[float], List[float], List[bool]]:
+        """``(arrivals, done, early_exit)`` of one tenant's tasks, in
+        per-tenant order."""
+        s = self.stream
+        slots = self.tenant_slots(tenant)
+        return ([s.arrivals[j] for j in slots], [s.done[j] for j in slots],
+                [s.early_exit[j] for j in slots])
+
+    def tenant_exit_hops(self, tenant: int) -> List[Optional[int]]:
+        """Per-task exit hops of one tenant, in per-tenant order."""
+        return [self.stream.exit_hop[j] for j in self.tenant_slots(tenant)]
+
+    def tenant_latencies(self, tenant: int) -> List[float]:
+        arr, done, _ = self.tenant_view(tenant)
+        return [d - a for a, d in zip(arr, done)]
 
 
-def simulate_multitenant_pool_stream(
+def simulate_multitenant_stream(
         plans: Sequence[Sequence[SimPlan]],
         arrivals: Sequence[Sequence[float]],
         policy,
-        pools,
-        router,
         links: Optional[Sequence[Optional[LinkProfile]]] = None,
+        pools=None,
+        router=None,
         batch_caps: Optional[Sequence[int]] = None,
-        sink=None
-        ) -> MultiTenantPoolStreamResult:
-    """Replay tagged multi-tenant streams over pooled tiers: compute the
-    pool-credit admission order, then replay the merged tenant-tagged
-    stream with ``simulate_pool_stream``.  The ingress tier's batch cap
-    is forced to 1 — admission stays credit-gated one task per credit —
-    but every tier-0 *replica* still admits independently, so ingress
-    throughput scales with the pool."""
-    order, enqueues = multitenant_pool_admission(
-        plans, arrivals, policy, pools, router, sink=sink)
+        sink=None,
+        migrate=None
+        ) -> MultiTenantStreamResult:
+    """Replay tagged multi-tenant task streams over the shared tiers:
+    compute the policy's admission order (``multitenant_admission``),
+    then replay the merged tenant-tagged stream with ``simulate_stream``
+    from each task's dispatch instant.  This is the reference timeline
+    the async multi-tenant executor is pinned to.
+
+    ``batch_caps`` enables per-tier micro-batching on the merged stream.
+    The ingress tier's cap is forced to 1: admission is credit-gated one
+    task per credit (the dispatcher holds the next task until a tier-0
+    replica frees), so batching there would diverge from the admission
+    gate both engines implement.  Every tier-0 replica still admits
+    independently, so ingress throughput scales with the pool."""
+    order, enqueues = multitenant_admission(plans, arrivals, policy,
+                                            pools=pools, router=router,
+                                            sink=sink)
     assert order, "empty multi-tenant stream"
-    merged_plans = [plans[t][i] for (t, i) in order]
-    merged_arr = [arrivals[t][i] for (t, i) in order]
-    merged_tenants = [t for (t, _) in order]
     if batch_caps is not None:
         batch_caps = [1] + [int(c) for c in batch_caps[1:]]
-    res = simulate_pool_stream(merged_plans, merged_arr, pools, router,
-                               links=links, batch_caps=batch_caps,
-                               tenants=merged_tenants, enqueues=enqueues,
-                               sink=sink)
-    return MultiTenantPoolStreamResult(stream=res.as_stream_result(),
-                                       order=tuple(order),
-                                       n_tenants=len(plans), pool=res)
+    res = simulate_stream([plans[t][i] for (t, i) in order],
+                          [arrivals[t][i] for (t, i) in order],
+                          links=links, pools=pools, router=router,
+                          batch_caps=batch_caps,
+                          tenants=[t for (t, _) in order],
+                          enqueues=enqueues, sink=sink, migrate=migrate)
+    return MultiTenantStreamResult(stream=res, order=tuple(order),
+                                   n_tenants=len(plans))

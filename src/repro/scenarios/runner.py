@@ -14,8 +14,8 @@ run the deterministic re-planning pass (``replan_timeline``), and
 execute the versioned plan schedule with hop-boundary migration on both
 engines.  :func:`run_churn_scenario` is the replicated-pool path:
 compile replica down-windows into an :class:`AvailabilityRouter` and
-pin the churn storyline (the chain ``migrate`` hook does not apply on
-the pool path — the sim rejects it — so churn runs are static-plan).
+pin the churn storyline (migration needs one replica per tier — both
+engines refuse it otherwise — so churn runs are static-plan).
 """
 
 from __future__ import annotations
@@ -151,8 +151,8 @@ def run_churn_scenario(plans: Sequence[TaskPlan],
     """Execute one replicated-pool churn storyline on both engines.
 
     Replica dropout manifests only through the availability-aware
-    router; the plan set is static (the chain ``migrate`` hook is
-    chain-path-only).  The pin covers placement: both engines must route
+    router; the plan set is static (``migrate`` needs one replica per
+    tier).  The pin covers placement: both engines must route
     around the same down-windows identically."""
     arrivals = timeline.arrivals(period, n_tasks)
     plan_list = [plans[i % len(plans)] for i in range(len(arrivals))]

@@ -2,66 +2,67 @@
 ``core/sim``.
 
 ``core.sim.simulate_stream`` models a collaborative deployment as
-``2n+1`` alternating serial FIFO resources.  This module *executes* that
-model instead of replaying it: one asyncio worker per resource, chained
-by bounded ``HopQueue``s, so segment ``k`` of task ``i`` genuinely runs
+``2n+1`` alternating resources — compute tiers, each a pool of replicas,
+and serial hop links — and replays it tier by tier.  This module
+*executes* that model instead: asyncio workers chained by bounded
+``HopQueue``s, so segment ``k`` of task ``i`` genuinely runs
 concurrently with segment ``k-1`` of task ``i+1`` and every hop's
 transmission is an awaitable priced by its ``LinkProfile``.
 
-Resource-worker <-> ``core/sim`` correspondence (the invariant the
-differential test ``tests/test_async_engine.py`` pins):
-
-  =====================  ==========================================
-  ``simulate_stream``    ``AsyncHopPipeline``
-  =====================  ==========================================
-  ``compute_free[k]``    compute worker ``k``'s position in virtual
-                         time (a serial worker is "free" exactly when
-                         its loop returns to ``HopQueue.get``)
-  ``link_free[k]``       link worker ``k``'s position in virtual time
-  task admission order   FIFO order of the queue chain (each worker
-                         processes and forwards in order)
-  ``tx_ready``           ``_Msg.ready_at`` of the message the compute
-                         worker forwards to its link queue (``prev_done``
-                         for a serial plan, ``prev_start + tx_offset``
-                         for an overlapped one)
-  ``c_ready``            ``_Msg.ready_at`` the link worker stamps for
-                         the downstream compute worker
-                         (``t_done``, or ``t_start + rx_offset``)
-  ``c_done = max(...)``  the downstream worker sleeps its compute time,
-                         then ``sleep_until(data_done)`` — it cannot
-                         finish before all data has arrived
-  trace re-integration   the link worker reprices the planned bit
-                         volume with ``LinkProfile.transfer_time`` at
-                         the transfer's actual virtual start
-  boundary quantize +    the fused single-pass boundary kernel
-  semantic probe         (``kernels.boundary`` via ``CollabRuntime.
-  (priced inside         segment_handle(probe_centers=)``): worker
-  ``compute[k]``)        ``k``'s segment forward emits the hop-``k``
-                         wire packet *and* the ``BoundaryProbe`` in one
-                         HBM read of the activation; the lifted
-                         ``ProbeResult`` feeds the enqueue-time
-                         decision in place of the scheduler's recompute
-  =====================  ==========================================
-
-With ``pools=`` the chain generalizes to *replicated tiers*
-(``core.sim.simulate_pool_stream``; pinned by ``tests/test_pools.py``):
+Worker <-> ``core/sim`` correspondence (the invariant the differential
+tests ``tests/test_async_engine.py`` and ``tests/test_pools.py`` pin):
 
   ==========================  =====================================
-  ``simulate_pool_stream``    ``AsyncHopPipeline(pools=...)``
+  ``simulate_stream``         ``AsyncHopPipeline``
   ==========================  =====================================
-  dispatch (router placing    one *dispatcher* worker per tier: gets
-  each pending task, in       from the pool input queue, calls
-  admission order)            ``router.route`` per task in seq order,
-                              forwards to the chosen replica's queue
-  replica replay (per-        one worker per replica: the chain
-  replica FIFO + batching,    compute worker with its service times
-  ``speed * compute[k]``)     scaled by ``PoolSpec.speeds[r]``
-  sequencer (running max of   one *sequencer* worker per hop: buffers
-  release instants restores   ``(seq, msg)`` releases and forwards to
-  admission order)            the serial link strictly in seq order
+  dispatch (router placing    one *dispatcher* worker per tier of
+  each pending task, in       ``m > 1``: gets from the tier queue,
+  admission order)            calls ``router.route`` per task in seq
+                              order, forwards to the chosen
+                              replica's queue (a tier of one replica
+                              has none: its replica reads the tier
+                              queue)
+  replica replay (per-        one worker per replica, service times
+  replica FIFO + batching,    scaled by ``PoolSpec.speeds[r]``; a
+  ``speed * compute[k]``)     replica is "free" exactly when its
+                              loop returns to ``HopQueue.get``
+  sequencer (running max of   one *sequencer* worker per hop behind a
+  release instants restores   pool of ``m > 1``: buffers ``(seq,
+  admission order)            msg)`` releases and forwards to the
+                              serial link strictly in seq order (one
+                              replica releases in order: none)
+  ``link_free[k]``            link worker ``k``'s position in
+                              virtual time
+  ``tx_ready``                ``_Msg.ready_at`` of the message a
+                              replica forwards toward its link
+                              (``prev_done`` for a serial plan,
+                              ``prev_start + tx_offset`` for an
+                              overlapped one)
+  ``c_ready``                 ``_Msg.ready_at`` the link worker
+                              stamps for the next tier (``t_done``,
+                              or ``t_start + rx_offset``)
+  ``c_done = max(...)``       the replica sleeps its compute time,
+                              then ``sleep_until(data_done)`` — it
+                              cannot finish before all data arrived
+  trace re-integration        the link worker reprices the planned
+                              bit volume with ``LinkProfile.
+                              transfer_time`` at the actual start
+  ``migrate``                 consulted by the link worker at the
+                              task's ``tx_ready``
   pool ingress credits        every tier-0 replica puts one credit
   (min-heap of completion     before each ``get`` — a token the
   instants, ``m`` zeros)      moment *any* ingress replica frees
+  boundary quantize +         the fused single-pass boundary kernel
+  semantic probe (priced      (``kernels.boundary`` via
+  inside ``compute[k]``)      ``CollabRuntime.segment_handle(
+                              probe_centers=)``): worker ``k``'s
+                              segment forward emits the hop-``k``
+                              wire packet *and* the
+                              ``BoundaryProbe`` in one HBM read of
+                              the activation; the lifted
+                              ``ProbeResult`` feeds the enqueue-time
+                              decision in place of the scheduler's
+                              recompute
   ==========================  =====================================
 
 Router state is strictly per tier and never reads the clock, so the
@@ -74,9 +75,9 @@ discrete-event driver (timers fire only when every worker is blocked, so
 a run is a bit-reproducible event simulation — this is what makes the
 executor directly comparable to ``simulate_stream``); ``WallClock`` maps
 the same awaits onto real ``asyncio.sleep``.  With unbounded queues the
-virtual-clock timeline reproduces ``simulate_stream`` exactly; bounded
-queues add admission/backpressure (an upstream worker stalls on ``put``
-when its hop queue is full), which the pure simulator does not model.
+virtual-clock timeline reproduces ``simulate_stream``; bounded queues
+add admission/backpressure (an upstream worker stalls on ``put`` when
+its hop queue is full), which the pure simulator does not model.
 
 ``AsyncCoachEngine`` rides the online component on top: ``OnlineScheduler``
 decisions (early exit Eq. 10, adaptive precision Eq. 11) are made at
@@ -90,9 +91,9 @@ precomputed ``ProbeResult``(s); the cascade consumes them directly
 
 Multi-tenant admission lives one layer up in ``repro.serving.tenancy``:
 ``AsyncHopPipeline.run`` accepts a pluggable admitter (``admit_fn``)
-which is released by *ingress credits* — a token issued each time the
-end worker is about to block on its input queue — so a policy scheduler
-can gate per-tenant streams on the shared ingress resource.
+which is released by *ingress credits* — a token issued each time a
+tier-0 replica is about to block on its input queue — so a policy
+scheduler can gate per-tenant streams on the shared ingress pool.
 """
 
 from __future__ import annotations
@@ -375,11 +376,14 @@ _STOP = object()
 
 
 class AsyncHopPipeline:
-    """``2n+1`` resource workers chained by hop queues (see module doc).
+    """Resource workers chained by hop queues (see module doc).
 
     ``segment_fn(k, idx, payload) -> payload`` optionally runs real
     compute (e.g. ``CollabRuntime.segment_handle(k)``) on each compute
     worker; the last segment's outputs are collected in ``outputs``.
+    ``pools`` (see ``sim.as_pools``; ``None`` = one unit-speed replica
+    per tier, the serial chain) replicates tiers behind ``router``,
+    which only pools of ``m > 1`` need.
     """
 
     def __init__(self, n_hops: int,
@@ -402,12 +406,10 @@ class AsyncHopPipeline:
             for k, c in enumerate(batch_caps[:self.n_seg]):
                 assert int(c) >= 1, "batch caps must be >= 1"
                 self.batch_caps[k] = int(c)
-        # replicated tiers: per-tier PoolSpec + a router policy object
-        # (repro.serving.routing); None = the classic 2n+1 chain
-        self.pools = sim.as_pools(pools, self.n_seg) \
-            if pools is not None else None
-        if self.pools is not None:
-            assert router is not None, "pool execution needs a router policy"
+        # per-tier PoolSpec + a router policy object (repro.serving.routing)
+        self.pools = sim.as_pools(pools, self.n_seg)
+        assert router is not None or all(p.m == 1 for p in self.pools), \
+            "replicated tiers need a router policy"
         self.router = router
         # span sink (``repro.obs.trace``): every worker emits the same
         # spans, at the same virtual instants, as the simulator's staged
@@ -419,17 +421,17 @@ class AsyncHopPipeline:
         # task's boundary-ready instant, so both engines evaluate it
         # with identical arguments and reach identical plan switches.
         self.migrate = migrate
-        if migrate is not None:
-            assert self.pools is None and all(
-                c <= 1 for c in self.batch_caps), \
-                "plan migration composes with the unbatched chain path only"
+        assert migrate is None or all(
+            p.m == 1 and c == 1
+            for p, c in zip(self.pools, self.batch_caps)), \
+            "plan migration needs one replica and batch cap 1 on every tier"
         self.outputs: dict = {}
 
     def run(self, plan_fn: Callable[[int, float], Any], n_tasks: int,
             arrivals: Optional[Sequence[float]],
             payloads: Optional[Sequence[Any]] = None,
             admit_fn: Optional[Callable] = None) -> sim.StreamResult:
-        """Admit ``n_tasks`` tasks at ``arrivals`` and execute the chain.
+        """Admit ``n_tasks`` tasks at ``arrivals`` and execute them.
 
         ``plan_fn(i, t_arr)`` is called *at enqueue time* (in task order,
         at the task's virtual arrival) and returns the task's
@@ -442,32 +444,38 @@ class AsyncHopPipeline:
         ``_Msg``s with distinct ``idx`` in ``[0, n_tasks)`` into ``q0``
         followed by ``_STOP``, and call ``record(idx, arrival)`` for
         each.  ``credits`` is a clock-aware queue receiving one token
-        every time the ingress compute worker (resource 0) is about to
-        block on its input queue — i.e. exactly when it becomes free —
-        so a policy admitter can gate dispatch on the shared ingress
-        resource (and, through bounded hop queues, on downstream
-        backpressure).  With ``admit_fn`` set, ``plan_fn``/``arrivals``/
-        ``payloads`` are ignored.
+        every time a tier-0 replica is about to block on its input
+        queue — i.e. exactly when it becomes free — so a policy admitter
+        can gate dispatch on the ingress pool (and, through bounded hop
+        queues, on downstream backpressure).  With ``admit_fn`` set,
+        ``plan_fn``/``arrivals``/``payloads`` are ignored.
 
-        With ``pools=`` configured the run executes the replicated-tier
-        topology instead and returns a ``sim.PoolStreamResult`` (per-
-        replica timelines + routes); ``credits`` then receives one token
-        whenever *any* tier-0 replica is about to block on its queue."""
-        if self.pools is not None:
-            return self._run_pool(plan_fn, n_tasks, arrivals, payloads,
-                                  admit_fn)
+        Per tier the topology is a *dispatcher* worker routing each task
+        to a replica's queue, one worker per replica, and (before each
+        hop link) a *sequencer* worker restoring admission order.  A
+        tier of one replica has nothing to route or reorder, so its
+        replica reads the tier's queue and feeds the link directly: a
+        serial chain is one worker per resource.  Differentially pinned
+        to ``sim.simulate_stream``."""
         assert n_tasks > 0
         assert admit_fn is not None or (arrivals is not None
                                         and len(arrivals) >= n_tasks)
         clock = self.clock
         n_hops, n_seg = self.n_hops, self.n_seg
-        comp_busy = [0.0] * n_seg
+        pools, router = self.pools, self.router
+        if router is not None:
+            router.reset(pools)
+        replica_busy: List[List[float]] = [[0.0] * p.m for p in pools]
+        replica_iv: List[List[List[sim.Interval]]] = \
+            [[[] for _ in range(p.m)] for p in pools]
+        replica_bs: List[List[List[int]]] = \
+            [[[] for _ in range(p.m)] for p in pools]
         link_busy = [0.0] * n_hops
-        comp_iv: List[List[sim.Interval]] = [[] for _ in range(n_seg)]
-        comp_bs: List[List[int]] = [[] for _ in range(n_seg)]
         link_iv: List[List[sim.Interval]] = [[] for _ in range(n_hops)]
         done = [0.0] * n_tasks
         exit_hops: List[Optional[int]] = [None] * n_tasks
+        routes: List[List[Optional[int]]] = \
+            [[None] * n_seg for _ in range(n_tasks)]
         arrs = [0.0] * n_tasks if admit_fn is not None \
             else list(arrivals[:n_tasks])
         self.outputs = {}
@@ -496,21 +504,76 @@ class AsyncHopPipeline:
                                   payload=payload))
             await q0.put(_STOP)
 
-        async def compute_worker(k: int, qin: HopQueue,
-                                 qout: Optional[HopQueue]):
-            # span emission is on the hot path: prefix tuples + a bound
-            # sink method, not Span(...) construction (see TraceRecorder)
+        async def dispatcher(k: int, qin: HopQueue,
+                             rqs: Sequence[HopQueue]):
+            # routes in strict queue (= admission) order; decisions read
+            # only the message's carried ready time and the router's own
+            # per-tier state, never the clock, so they match the staged
+            # simulator's placements exactly
+            seq = 0
             emit = sink.span if sink is not None else None
-            res = ("compute", k, 0)
+            while True:
+                msg = await qin.get()
+                if msg is _STOP:
+                    for rq in rqs:
+                        await rq.put(_STOP)
+                    return
+                r = router.route(k, msg.ready_at, msg.plan.compute[k],
+                                 msg.tenant)
+                msg.seq = seq
+                seq += 1
+                if emit is not None:
+                    # the placement is a function of the message, not the
+                    # clock, so the span is stamped at the task's ready
+                    # instant — identically to the staged dispatch
+                    t = msg.ready_at
+                    emit((ROUTE, ("compute", k, r), t, t, msg.idx, None,
+                          t, None, None, r, msg.seq))
+                await rqs[r].put(msg)
+
+        async def replica_worker(k: int, r: int, qin: HopQueue,
+                                 out: Optional[HopQueue], tagged: bool):
+            # completions go to ``out``: the pool's sequencer, as
+            # (seq, msg | None) releases (``tagged``), or — on a tier of
+            # one replica — straight to the hop link, which takes only
+            # the tasks that go on
+            emit = sink.span if sink is not None else None
+            res = ("compute", k, r)
             cap = self.batch_caps[k]
+            speed = pools[k].speeds[r]
+
+            async def release(m: _Msg, nxt: Optional[_Msg]):
+                if out is None:
+                    return
+                if tagged:
+                    await out.put((m.seq, nxt))
+                elif nxt is not None:
+                    await out.put(nxt)
+
+            def forward(m: _Msg) -> _Msg:
+                return _Msg(m.idx, m.plan, ready_at=clock.now,
+                            data_done=clock.now, payload=m.payload,
+                            tenant=m.tenant)
+
+            def finish(m: _Msg):
+                p = m.plan
+                done[m.idx] = clock.now
+                exit_hops[m.idx] = p.exit_hop
+                self.outputs[m.idx] = m.payload
+                if emit is not None and p.exit_hop is not None:
+                    t = clock.now
+                    emit((EXIT_RELEASE, res, t, t, m.idx, None, None, None,
+                          p.exit_hop))
+
             while True:
                 if k == 0 and credits is not None:
                     await credits.put(None)
                 msg = await qin.get()
                 if msg is _STOP:
-                    if qout is not None:
-                        await qout.put(_STOP)
+                    if out is not None:
+                        await out.put(_STOP)
                     return
+                routes[msg.idx][k] = r
                 if cap > 1:
                     # -------- continuous micro-batching (greedy drain) --
                     # membership is fixed against the queue state at the
@@ -529,18 +592,18 @@ class AsyncHopPipeline:
                     s = clock.now             # = max(ready, wake)
                     n_b = sim.greedy_batch_size(
                         k, cap, s, [m.plan for m in cand],
-                        [m.ready_at for m in cand])
+                        [m.ready_at for m in cand], speed=speed)
                     if n_b > 1:
                         batch = [msg] + qin.drain(n_b - 1)
-                        dur = sim.batched_service_time(
+                        dur = speed * sim.batched_service_time(
                             [m.plan for m in batch], k)
                         if self.segment_fn is not None:
                             for m in batch:
                                 m.payload = self.segment_fn(
                                     k, m.idx, m.payload)
-                        comp_busy[k] += dur
-                        comp_iv[k].append((s, s + dur))
-                        comp_bs[k].append(len(batch))
+                        replica_busy[k][r] += dur
+                        replica_iv[k][r].append((s, s + dur))
+                        replica_bs[k][r].append(len(batch))
                         if emit is not None:
                             emit((SERVICE, res, s, s + dur, msg.idx,
                                   tuple(m.idx for m in batch),
@@ -554,33 +617,25 @@ class AsyncHopPipeline:
                         # still gates on its own upstream data-done, and
                         # exit-hop members leave the batch at this tier
                         for m in batch:
+                            routes[m.idx][k] = r
                             await clock.sleep_until(m.data_done)
                             p = m.plan
                             if k == n_hops or (p.exit_hop is not None
                                                and k >= p.exit_hop):
-                                done[m.idx] = clock.now
-                                exit_hops[m.idx] = p.exit_hop
-                                self.outputs[m.idx] = m.payload
-                                if emit is not None \
-                                        and p.exit_hop is not None:
-                                    t = clock.now
-                                    emit((EXIT_RELEASE, res, t, t, m.idx,
-                                          None, None, None, p.exit_hop))
+                                finish(m)
+                                await release(m, None)
                             else:
-                                await qout.put(_Msg(
-                                    m.idx, p, ready_at=clock.now,
-                                    data_done=clock.now,
-                                    payload=m.payload))
+                                await release(m, forward(m))
                         continue
                 await clock.sleep_until(msg.ready_at)
-                start = clock.now                 # = max(ready, worker free)
+                start = clock.now             # = max(ready, replica free)
                 p = msg.plan
-                comp = p.compute[k]
+                comp = speed * p.compute[k]
                 if self.segment_fn is not None:
                     msg.payload = self.segment_fn(k, msg.idx, msg.payload)
-                comp_busy[k] += comp
-                comp_iv[k].append((start, start + comp))
-                comp_bs[k].append(1)
+                replica_busy[k][r] += comp
+                replica_iv[k][r].append((start, start + comp))
+                replica_bs[k][r].append(1)
                 if emit is not None:
                     emit((SERVICE, res, start, start + comp, msg.idx,
                           (msg.idx,), msg.ready_at, 1))
@@ -595,24 +650,55 @@ class AsyncHopPipeline:
                     await clock.sleep(comp)
                     await clock.sleep_until(data_done)   # c_done gate
                     if last:
-                        done[msg.idx] = clock.now
-                        exit_hops[msg.idx] = p.exit_hop
-                        self.outputs[msg.idx] = msg.payload
-                        if emit is not None and p.exit_hop is not None:
-                            t = clock.now
-                            emit((EXIT_RELEASE, res, t, t, msg.idx,
-                                  None, None, None, p.exit_hop))
+                        finish(msg)
+                        await release(msg, None)
                     else:
-                        await qout.put(_Msg(msg.idx, p, ready_at=clock.now,
-                                            data_done=clock.now,
-                                            payload=msg.payload))
+                        await release(msg, forward(msg))
                 else:                                    # Fig. 4 overlap
                     await clock.sleep(off)
-                    await qout.put(_Msg(msg.idx, p, ready_at=clock.now,
-                                        data_done=clock.now,
-                                        payload=msg.payload))
+                    await release(msg, forward(msg))
                     await clock.sleep(comp - off)
                     await clock.sleep_until(data_done)
+
+        async def sequencer(k: int, sq: HopQueue, qout: HopQueue, m: int):
+            # restore admission order toward the serial hop link: buffer
+            # out-of-order releases, forward strictly by seq (a terminal
+            # release — (seq, None) — just advances the cursor); the
+            # forward instant is therefore the running max of release
+            # instants, the expression the simulator's sequencer stage
+            # computes
+            buf: dict = {}
+            next_seq = 0
+            stops = 0
+            emit = sink.span if sink is not None else None
+            lres = ("link", k)
+            while True:
+                item = await sq.get()
+                if item is _STOP:
+                    stops += 1
+                    if stops == m:
+                        assert not buf, "sequencer stopped with buffered " \
+                            "tasks (replica lost a release)"
+                        await qout.put(_STOP)
+                        return
+                    continue
+                s_id, out = item
+                # the get returns at the release's put instant (the
+                # sequencer never sleeps between gets, so the clock
+                # cannot advance past a queued release) — stamp it as
+                # the release instant for the hold span
+                buf[s_id] = (out, clock.now)
+                while next_seq in buf:
+                    nxt, rel = buf.pop(next_seq)
+                    next_seq += 1
+                    if nxt is not None:
+                        # forward instant = running max of releases; any
+                        # excess over this task's own release is the
+                        # sequencer restoring admission order
+                        if emit is not None and clock.now > rel:
+                            emit((SEQ_HOLD, lres, rel, clock.now,
+                                  nxt.idx))
+                        await qout.put(nxt)
 
         async def link_worker(k: int, qin: HopQueue, qout: HopQueue):
             link = self.links[k] if k < len(self.links) else None
@@ -663,350 +749,41 @@ class AsyncHopPipeline:
                     t = clock.now
                     emit((ENQUEUE, nres, t, t, msg.idx))
                 await qout.put(_Msg(msg.idx, msg.plan, ready_at=c_ready,
-                                    data_done=t_done, payload=msg.payload))
-                await clock.sleep(dur - fwd)
-
-        async def main():
-            # queue j feeds resource j in the alternating chain
-            # compute_0, link_0, compute_1, ..., link_{n-1}, compute_n
-            queues = [HopQueue(clock, self.capacity)
-                      for _ in range(2 * n_hops + 1)]
-            workers = [clock.spawn(admit_fn(queues[0], credits, record)
-                                   if admit_fn is not None
-                                   else admit(queues[0]))]
-            for k in range(n_seg):
-                qout = queues[2 * k + 1] if k < n_hops else None
-                workers.append(clock.spawn(
-                    compute_worker(k, queues[2 * k], qout)))
-            for k in range(n_hops):
-                workers.append(clock.spawn(
-                    link_worker(k, queues[2 * k + 1], queues[2 * k + 2])))
-            await asyncio.gather(*workers)
-
-        self.clock.run(main())
-        # batch sizes are only meaningful when batching is on; emit ()
-        # otherwise so unbatched runs stay field-identical to the
-        # legacy simulator output
-        batching = any(c > 1 for c in self.batch_caps)
-        return sim.StreamResult(
-            arrivals=arrs, done=done,
-            early_exit=[eh is not None for eh in exit_hops],
-            makespan=max(done) - min(arrs),
-            compute_busy=tuple(comp_busy), link_busy=tuple(link_busy),
-            compute_intervals=tuple(tuple(iv) for iv in comp_iv),
-            link_intervals=tuple(tuple(iv) for iv in link_iv),
-            exit_hop=exit_hops,
-            compute_batch_sizes=tuple(tuple(b) for b in comp_bs)
-            if batching else ())
-
-    def _run_pool(self, plan_fn, n_tasks: int,
-                  arrivals: Optional[Sequence[float]],
-                  payloads: Optional[Sequence[Any]] = None,
-                  admit_fn: Optional[Callable] = None
-                  ) -> sim.PoolStreamResult:
-        """Replicated-tier topology: per tier one dispatcher worker, one
-        worker per replica, and (before each hop link) one sequencer
-        worker restoring admission order (see the module correspondence
-        table).  Differentially pinned to ``sim.simulate_pool_stream``."""
-        assert n_tasks > 0
-        assert admit_fn is not None or (arrivals is not None
-                                        and len(arrivals) >= n_tasks)
-        clock = self.clock
-        n_hops, n_seg = self.n_hops, self.n_seg
-        pools, router = self.pools, self.router
-        router.reset(pools)
-        replica_busy: List[List[float]] = [[0.0] * p.m for p in pools]
-        replica_iv: List[List[List[sim.Interval]]] = \
-            [[[] for _ in range(p.m)] for p in pools]
-        replica_bs: List[List[List[int]]] = \
-            [[[] for _ in range(p.m)] for p in pools]
-        link_busy = [0.0] * n_hops
-        link_iv: List[List[sim.Interval]] = [[] for _ in range(n_hops)]
-        done = [0.0] * n_tasks
-        exit_hops: List[Optional[int]] = [None] * n_tasks
-        routes: List[List[Optional[int]]] = \
-            [[None] * n_seg for _ in range(n_tasks)]
-        arrs = [0.0] * n_tasks if admit_fn is not None \
-            else list(arrivals[:n_tasks])
-        self.outputs = {}
-        credits = HopQueue(clock) if admit_fn is not None else None
-        sink = self.sink
-
-        def record(idx: int, arrival: float):
-            arrs[idx] = arrival
-
-        async def admit(q0: HopQueue):
-            emit = sink.span if sink is not None else None
-            res0 = ("compute", 0)
-            for i in range(n_tasks):
-                arr = arrivals[i]
-                await clock.sleep_until(arr)
-                plan = plan_fn(i, arr)
-                if isinstance(plan, TaskPlan):
-                    plan = plan.as_sim_plan(n_hops)
-                assert len(plan.tx) == n_hops, "plan/deployment hop mismatch"
-                payload = payloads[i] if payloads is not None else None
-                if emit is not None:
-                    t = clock.now
-                    emit((ENQUEUE, res0, t, t, i))
-                await q0.put(_Msg(i, plan, ready_at=arr, data_done=arr,
-                                  payload=payload))
-            await q0.put(_STOP)
-
-        async def dispatcher(k: int, qin: HopQueue,
-                             rqs: Sequence[HopQueue]):
-            # routes in strict queue (= admission) order; decisions read
-            # only the message's carried ready time and the router's own
-            # per-tier state, never the clock, so they match the staged
-            # simulator's placements exactly
-            seq = 0
-            emit = sink.span if sink is not None else None
-            while True:
-                msg = await qin.get()
-                if msg is _STOP:
-                    for rq in rqs:
-                        await rq.put(_STOP)
-                    return
-                r = router.route(k, msg.ready_at, msg.plan.compute[k],
-                                 msg.tenant)
-                routes[msg.idx][k] = r
-                msg.seq = seq
-                seq += 1
-                if emit is not None:
-                    # the placement is a function of the message, not the
-                    # clock, so the span is stamped at the task's ready
-                    # instant — identically to the staged dispatch
-                    t = msg.ready_at
-                    emit((ROUTE, ("compute", k, r), t, t, msg.idx, None,
-                          t, None, None, r, msg.seq))
-                await rqs[r].put(msg)
-
-        async def replica_worker(k: int, r: int, qin: HopQueue,
-                                 sq: Optional[HopQueue]):
-            # the chain compute worker, speed-scaled; completions are
-            # released to the pool's sequencer as (seq, msg | None)
-            emit = sink.span if sink is not None else None
-            res = ("compute", k, r)
-            cap = self.batch_caps[k]
-            speed = pools[k].speeds[r]
-            while True:
-                if k == 0 and credits is not None:
-                    await credits.put(None)
-                msg = await qin.get()
-                if msg is _STOP:
-                    if sq is not None:
-                        await sq.put(_STOP)
-                    return
-                if cap > 1:
-                    # membership against this replica's queue at the wake
-                    # instant (same rule as the chain batching worker)
-                    await clock.settle()
-                    cand = [msg]
-                    for m in qin.snapshot():
-                        if m is _STOP:
-                            break
-                        cand.append(m)
-                    await clock.sleep_until(msg.ready_at)
-                    s = clock.now
-                    n_b = sim.greedy_batch_size(
-                        k, cap, s, [m.plan for m in cand],
-                        [m.ready_at for m in cand], speed=speed)
-                    if n_b > 1:
-                        batch = [msg] + qin.drain(n_b - 1)
-                        dur = speed * sim.batched_service_time(
-                            [m.plan for m in batch], k)
-                        if self.segment_fn is not None:
-                            for m in batch:
-                                m.payload = self.segment_fn(
-                                    k, m.idx, m.payload)
-                        replica_busy[k][r] += dur
-                        replica_iv[k][r].append((s, s + dur))
-                        replica_bs[k][r].append(len(batch))
-                        if emit is not None:
-                            emit((SERVICE, res, s, s + dur, msg.idx,
-                                  tuple(m.idx for m in batch),
-                                  msg.ready_at, len(batch)))
-                            for m in batch[1:]:
-                                if s > m.ready_at:
-                                    emit((BATCH_FORM, res, m.ready_at, s,
-                                          m.idx))
-                        await clock.sleep(dur)
-                        for m in batch:
-                            await clock.sleep_until(m.data_done)
-                            p = m.plan
-                            if k == n_hops or (p.exit_hop is not None
-                                               and k >= p.exit_hop):
-                                done[m.idx] = clock.now
-                                exit_hops[m.idx] = p.exit_hop
-                                self.outputs[m.idx] = m.payload
-                                if emit is not None \
-                                        and p.exit_hop is not None:
-                                    t = clock.now
-                                    emit((EXIT_RELEASE, res, t, t, m.idx,
-                                          None, None, None, p.exit_hop))
-                                if sq is not None:
-                                    await sq.put((m.seq, None))
-                            else:
-                                await sq.put((m.seq, _Msg(
-                                    m.idx, p, ready_at=clock.now,
-                                    data_done=clock.now,
-                                    payload=m.payload, tenant=m.tenant)))
-                        continue
-                await clock.sleep_until(msg.ready_at)
-                start = clock.now             # = max(ready, replica free)
-                p = msg.plan
-                comp = speed * p.compute[k]
-                if self.segment_fn is not None:
-                    msg.payload = self.segment_fn(k, msg.idx, msg.payload)
-                replica_busy[k][r] += comp
-                replica_iv[k][r].append((start, start + comp))
-                replica_bs[k][r].append(1)
-                if emit is not None:
-                    emit((SERVICE, res, start, start + comp, msg.idx,
-                          (msg.idx,), msg.ready_at, 1))
-                data_done = msg.data_done
-                last = k == n_hops or \
-                    (p.exit_hop is not None and k >= p.exit_hop)
-                off = None if last else p.tx_offset[k]
-                if last or off is None or off >= comp:   # serial stage
-                    await clock.sleep(comp)
-                    await clock.sleep_until(data_done)   # c_done gate
-                    if last:
-                        done[msg.idx] = clock.now
-                        exit_hops[msg.idx] = p.exit_hop
-                        self.outputs[msg.idx] = msg.payload
-                        if emit is not None and p.exit_hop is not None:
-                            t = clock.now
-                            emit((EXIT_RELEASE, res, t, t, msg.idx,
-                                  None, None, None, p.exit_hop))
-                        if sq is not None:
-                            await sq.put((msg.seq, None))
-                    else:
-                        await sq.put((msg.seq, _Msg(
-                            msg.idx, p, ready_at=clock.now,
-                            data_done=clock.now, payload=msg.payload,
-                            tenant=msg.tenant)))
-                else:                                    # Fig. 4 overlap
-                    await clock.sleep(off)
-                    await sq.put((msg.seq, _Msg(
-                        msg.idx, p, ready_at=clock.now,
-                        data_done=clock.now, payload=msg.payload,
-                        tenant=msg.tenant)))
-                    await clock.sleep(comp - off)
-                    await clock.sleep_until(data_done)
-
-        async def sequencer(k: int, sq: HopQueue, qout: HopQueue, m: int):
-            # restore admission order toward the serial hop link: buffer
-            # out-of-order releases, forward strictly by seq (a terminal
-            # release — (seq, None) — just advances the cursor); the
-            # forward instant is therefore the running max of release
-            # instants, the expression the simulator's sequencer stage
-            # computes
-            buf: dict = {}
-            next_seq = 0
-            stops = 0
-            emit = sink.span if sink is not None else None
-            lres = ("link", k)
-            while True:
-                item = await sq.get()
-                if item is _STOP:
-                    stops += 1
-                    if stops == m:
-                        assert not buf, "sequencer stopped with buffered " \
-                            "tasks (replica lost a release)"
-                        await qout.put(_STOP)
-                        return
-                    continue
-                s_id, out = item
-                # the get returns at the release's put instant (the
-                # sequencer never sleeps between gets, so the clock
-                # cannot advance past a queued release) — stamp it as
-                # the release instant for the hold span
-                buf[s_id] = (out, clock.now)
-                while next_seq in buf:
-                    nxt, rel = buf.pop(next_seq)
-                    next_seq += 1
-                    if nxt is not None:
-                        # forward instant = running max of releases; any
-                        # excess over this task's own release is the
-                        # sequencer restoring admission order
-                        if emit is not None and clock.now > rel:
-                            emit((SEQ_HOLD, lres, rel, clock.now,
-                                  nxt.idx))
-                        await qout.put(nxt)
-
-        async def link_worker(k: int, qin: HopQueue, qout: HopQueue):
-            link = self.links[k] if k < len(self.links) else None
-            emit = sink.span if sink is not None else None
-            lres = ("link", k)
-            nres = ("compute", k + 1)
-            while True:
-                msg = await qin.get()
-                if msg is _STOP:
-                    await qout.put(_STOP)
-                    return
-                await clock.sleep_until(msg.ready_at)    # tx_ready
-                t_start = clock.now
-                dur = msg.plan.tx[k]
-                if link is not None and link.trace is not None and dur > 0:
-                    bits = dur * link.bandwidth_bps
-                    dur = link.transfer_time(bits, t_start)
-                t_done = t_start + dur
-                roff = msg.plan.rx_offset[k]
-                c_ready = t_done if roff is None \
-                    else max(t_start + roff, msg.ready_at)
-                link_busy[k] += dur
-                link_iv[k].append((t_start, t_done))
-                if emit is not None:
-                    emit((XFER, lres, t_start, t_done, msg.idx, None,
-                          msg.ready_at))
-                fwd = min(max(c_ready - t_start, 0.0), dur)
-                await clock.sleep(fwd)
-                if emit is not None:
-                    t = clock.now
-                    emit((ENQUEUE, nres, t, t, msg.idx))
-                await qout.put(_Msg(msg.idx, msg.plan, ready_at=c_ready,
                                     data_done=t_done, payload=msg.payload,
                                     tenant=msg.tenant))
                 await clock.sleep(dur - fwd)
 
         async def main():
-            # per tier: pool input queue -> dispatcher -> replica queues
-            # -> replicas -> sequencer -> hop link -> next pool input
+            # per tier: tier queue -> [dispatcher -> replica queues ->]
+            # replicas -> [sequencer ->] hop link -> next tier queue
             pin = [HopQueue(clock, self.capacity) for _ in range(n_seg)]
             workers = [clock.spawn(admit_fn(pin[0], credits, record)
                                    if admit_fn is not None
                                    else admit(pin[0]))]
             for k in range(n_seg):
                 m = pools[k].m
-                rqs = [HopQueue(clock, self.capacity) for _ in range(m)]
-                sq = HopQueue(clock) if k < n_hops else None
-                workers.append(clock.spawn(dispatcher(k, pin[k], rqs)))
-                for r in range(m):
+                lq = HopQueue(clock, self.capacity) if k < n_hops else None
+                if m == 1:
                     workers.append(clock.spawn(
-                        replica_worker(k, r, rqs[r], sq)))
+                        replica_worker(k, 0, pin[k], lq, tagged=False)))
+                else:
+                    rqs = [HopQueue(clock, self.capacity) for _ in range(m)]
+                    sq = HopQueue(clock) if k < n_hops else None
+                    workers.append(clock.spawn(dispatcher(k, pin[k], rqs)))
+                    for r in range(m):
+                        workers.append(clock.spawn(
+                            replica_worker(k, r, rqs[r], sq, tagged=True)))
+                    if k < n_hops:
+                        workers.append(clock.spawn(sequencer(k, sq, lq, m)))
                 if k < n_hops:
-                    lq = HopQueue(clock, self.capacity)
-                    workers.append(clock.spawn(sequencer(k, sq, lq, m)))
                     workers.append(clock.spawn(
                         link_worker(k, lq, pin[k + 1])))
             await asyncio.gather(*workers)
 
         self.clock.run(main())
-        return sim.PoolStreamResult(
-            arrivals=arrs, done=done,
-            early_exit=[eh is not None for eh in exit_hops],
-            exit_hop=exit_hops,
-            makespan=max(done) - min(arrs),
-            link_busy=tuple(link_busy),
-            link_intervals=tuple(tuple(iv) for iv in link_iv),
-            replica_busy=tuple(tuple(rb) for rb in replica_busy),
-            replica_intervals=tuple(tuple(tuple(iv) for iv in tier)
-                                    for tier in replica_iv),
-            replica_batch_sizes=tuple(tuple(tuple(bs) for bs in tier)
-                                      for tier in replica_bs),
-            routes=tuple(tuple(rt) for rt in routes),
-            pools=pools)
+        return sim.StreamResult.from_replicas(
+            arrs, done, exit_hops, link_busy, link_iv, replica_busy,
+            replica_iv, replica_bs, routes, pools)
 
 
 def run_pipeline_async(plans: Sequence[TaskPlan],
@@ -1026,15 +803,14 @@ def run_pipeline_async(plans: Sequence[TaskPlan],
     per-resource workers instead of replayed by ``simulate_stream``.
     With ``queue_capacity = 0`` (unbounded) and a ``VirtualClock`` the
     two timelines agree to float precision (including per-tier
-    micro-batching via ``batch_caps``).  ``pools`` + ``router`` spawn one
-    worker per replica behind per-pool dispatchers and pin against
-    ``sim.simulate_pool_stream`` instead.  ``sink`` (a
-    ``repro.obs.trace`` span sink) records the executed timeline; the
-    same call against ``core.pipeline.run_pipeline`` yields a matching
-    trace (``assert_traces_match``).  ``migrate`` is the online
-    re-planning hook (see ``sim.simulate_stream``); passing the same
-    hook object (reset between runs) to both entry points keeps the
-    differential pin across mid-stream plan switches."""
+    micro-batching via ``batch_caps`` and replicated tiers via
+    ``pools`` + ``router``).  ``sink`` (a ``repro.obs.trace`` span sink)
+    records the executed timeline; the same call against
+    ``core.pipeline.run_pipeline`` yields a matching trace
+    (``assert_traces_match``).  ``migrate`` is the online re-planning
+    hook (see ``sim.simulate_stream``); passing the same hook object
+    (reset between runs) to both entry points keeps the differential pin
+    across mid-stream plan switches."""
     n = len(plans)
     if arrivals is None:
         arrivals = [i * arrival_period for i in range(n)]
@@ -1049,9 +825,6 @@ def run_pipeline_async(plans: Sequence[TaskPlan],
                             pools=pools, router=router, sink=sink,
                             migrate=migrate)
     res = pipe.run(lambda i, _arr: sps[i], n, arrivals, payloads=payloads)
-    if isinstance(res, sim.PoolStreamResult):
-        from repro.core.pipeline import result_from_pool_stream
-        return result_from_pool_stream(res)
     return result_from_stream(res)
 
 
@@ -1086,10 +859,5 @@ class AsyncCoachEngine(EngineBase):
                                 sink=self.cfg.trace,
                                 migrate=self.cfg.migrate)
         res = pipe.run(admit, n, [i * arrival_period for i in range(n)])
-        if isinstance(res, sim.PoolStreamResult):
-            from repro.core.pipeline import result_from_pool_stream
-            pr = result_from_pool_stream(res)
-        else:
-            pr = result_from_stream(res)
-        return self._stats(pr, n, acc["exits"], acc["bits"], acc["wire"],
-                           acc["correct"])
+        return self._stats(result_from_stream(res), n, acc["exits"],
+                           acc["bits"], acc["wire"], acc["correct"])

@@ -6,9 +6,10 @@ the async hop-queue engine (``repro.serving.async_engine``): offline
 stage times, semantic cache + threshold calibration, the online
 scheduler, per-task decision making, and TaskPlan construction.  The two
 engines differ only in *how* the resulting plans occupy the ``2n+1``
-resources — one task at a time through ``core.sim.simulate_stream``
-(sync), or concurrently through per-resource asyncio workers (async) —
-so concurrency can never change decisions, only timing.
+resources — replayed by ``core.sim.simulate_stream`` after every
+decision is made (sync), or concurrently through per-resource asyncio
+workers (async) — so concurrency can never change decisions, only
+timing.
 """
 
 from __future__ import annotations
@@ -48,7 +49,7 @@ class EngineConfig:
     #                                the auto finder redistributes a
     #                                hard-clamped tier's slack downstream
     # ---- replicated tiers (pool of replicas per tier + router policy;
-    #      see core.sim.PoolSpec / serving.routing)
+    #      see core.sim.PoolSpec / serving.routing; None = one each)
     pool_sizes: Optional[Sequence[int]] = None  # replicas per tier
     pool_speeds: Optional[Sequence[Sequence[float]]] = None  # per-replica
     #                                service-time multipliers (overrides
@@ -65,7 +66,7 @@ class EngineConfig:
     #      hook consulted at every hop boundary; the same hook (reset
     #      between runs) drives the sim replay and the executor, so the
     #      differential pin extends across mid-stream plan switches.
-    #      Chain path only (no pools, no micro-batching).
+    #      Needs one replica and batch cap 1 on every tier.
     migrate: Any = None
 
 
@@ -185,25 +186,19 @@ class EngineBase:
         elif cfg.ingress_cap is not None and self.batch_caps:
             self.batch_caps[0] = min(self.batch_caps[0],
                                      int(cfg.ingress_cap))
-        # ---- replicated tiers: per-tier replica pools from config
-        # (None = the classic single-replica chain).  The engines hand
-        # these to the executors together with a serving.routing router.
-        if cfg.pool_speeds is not None:
-            self.pools: Optional[Tuple[sim.PoolSpec, ...]] = sim.as_pools(
-                [tuple(float(s) for s in sp) for sp in cfg.pool_speeds],
-                len(stage_compute))
-        elif cfg.pool_sizes is not None:
-            self.pools = sim.as_pools(
-                [int(m) for m in cfg.pool_sizes], len(stage_compute))
-        else:
-            self.pools = None
+        # ---- replicated tiers: per-tier replica pools from config (one
+        # unit-speed replica per tier by default).  The engines hand these
+        # to the executors together with a serving.routing router.
+        speeds = cfg.pool_speeds if cfg.pool_speeds is not None \
+            else [(1.0,) * int(m) for m in cfg.pool_sizes or ()]
+        self.pools: Tuple[sim.PoolSpec, ...] = sim.as_pools(
+            [tuple(float(s) for s in sp) for sp in speeds],
+            len(stage_compute))
 
     def make_router(self):
-        """Fresh router instance from the config (None when the engine
-        runs the classic chain).  Fresh per call: router state is a replay
-        log, so two runs must never share one instance."""
-        if self.pools is None:
-            return None
+        """Fresh router instance from the config (only tiers of more than
+        one replica consult it).  Fresh per call: router state is a
+        replay log, so two runs must never share one instance."""
         from repro.serving.routing import make_router
         router = make_router(self.cfg.router, seed=self.cfg.router_seed)
         if self.cfg.metrics is not None:
@@ -403,5 +398,5 @@ class EngineBase:
         if trace is not None and len(getattr(trace, "spans", ())) > 0:
             populate_from_trace(reg, trace)
             att = attribute(trace, resources=chain_resources(
-                pipeline.n_hops, pipeline.pool_sizes or None))
+                pipeline.n_hops, pipeline.pool_sizes))
             populate_from_attribution(reg, att)

@@ -17,8 +17,9 @@ The JAX compute is real (CollabRuntime executes both segments); the
 is not a Jetson + A6000 pair (DESIGN.md §2).
 
 ``CoachEngine`` here is the *synchronous reference*: tasks are decided
-and accounted one at a time, with all overlap delegated to
-``core.sim.simulate_stream``.  The executor whose real workers overlap
+and accounted one at a time, and the decided stream is replayed by
+``core.sim.simulate_stream`` (every tier a pool, one replica unless
+configured).  The executor whose real workers overlap
 tasks the way the simulator models lives in
 ``repro.serving.async_engine``; both share ``repro.serving.base``.
 """

@@ -2,13 +2,13 @@
 
 A router places each task on one replica of a tier's pool
 (``repro.core.sim.PoolSpec``) the instant the task is enqueued at that
-tier.  Like the admission policies in ``repro.serving.tenancy``, the
-policy object is a deterministic state machine shared verbatim between
-the arithmetic simulator (``core.sim.simulate_pool_stream``, which
-dispatches tier by tier) and the event-driven executor
-(``serving.async_engine``, whose per-pool dispatcher workers interleave
-tiers in wall time) — so the differential harness pins the *routing
-semantics*, not the policy code.
+tier; a tier of one replica never asks it.  Like the admission policies
+in ``repro.serving.tenancy``, the policy object is a deterministic state
+machine shared verbatim between the arithmetic simulator
+(``core.sim.simulate_stream``, which dispatches tier by tier) and the
+event-driven executor (``serving.async_engine``, whose per-pool
+dispatcher workers interleave tiers in wall time) — so the differential
+harness pins the *routing semantics*, not the policy code.
 
 Two rules make that sharing sound:
 

@@ -2,8 +2,8 @@
 
 COACH's throughput story assumes a shared cloud tier serving many end
 devices, but a single ``AsyncHopPipeline`` executes one task stream.
-This module admits *several* per-tenant task streams through one shared
-``2n+1`` resource chain:
+This module admits *several* per-tenant task streams through the shared
+``2n+1`` resources (each compute tier a pool of one or more replicas):
 
   ``TenantSpec``            one tenant's workload contract: arrival
                             process, fairness weight, latency SLO.
@@ -14,11 +14,11 @@ This module admits *several* per-tenant task streams through one shared
   ``MultiTenantHopPipeline``  per-tenant admit workers (decisions happen
                             at each task's arrival instant) feeding one
                             policy dispatcher that is released by
-                            *ingress credits*: the shared end worker
+                            *ingress credits*: each tier-0 replica
                             issues a credit exactly when it becomes
-                            free, so admission is gated by the first
-                            resource of the chain (and, with bounded
-                            hop queues, by downstream backpressure).
+                            free, so admission is gated by the ingress
+                            pool (and, with bounded hop queues, by
+                            downstream backpressure).
   ``MultiTenantCoachEngine``  one COACH engine state per tenant (own
                             semantic cache, thresholds, per-hop
                             bandwidth EMAs) sharing the executor; co-
@@ -31,8 +31,8 @@ and full resource timeline equal ``core.sim.simulate_multitenant_stream``
 — which computes the same ingress gate arithmetically — to float
 precision, for every admission policy.  The policy *state machines* are
 shared between the two sides; the *gating semantics* (event-driven
-credits vs. arithmetic ``free_0``) are implemented independently, which
-is exactly what the harness pins.
+credits vs. the arithmetic credit heap of ``sim.multitenant_admission``)
+are implemented independently, which is exactly what the harness pins.
 
 Fairness-vs-bubble tradeoff: FIFO admits a bursty tenant's backlog ahead
 of everyone — by work conservation it is minimax-optimal for *raw*
@@ -54,7 +54,6 @@ import numpy as np
 
 from repro.core import sim
 from repro.core.pipeline import (PipelineResult, TaskPlan, TaskRecord,
-                                 result_from_pool_stream,
                                  result_from_stream)
 from repro.obs.trace import CREDIT_WAIT, ENQUEUE, Span
 from repro.serving.async_engine import (AsyncHopPipeline, HopQueue,
@@ -119,7 +118,7 @@ class AdmissionPolicy:
     """Decides which candidate tenant's head task enters the shared
     chain next.
 
-    The interface is shared by ``core.sim.multitenant_admission_order``
+    The interface is shared by ``core.sim.multitenant_admission``
     (arithmetic ingress gate) and ``MultiTenantHopPipeline`` (event-
     driven ingress credits): ``reset(n_tenants)`` clears state, then
     ``pick(candidates, heads)`` is called once per admitted task with
@@ -243,9 +242,9 @@ class MultiTenantHopPipeline:
     One admit worker per tenant sleeps to each task's arrival and calls
     that tenant's ``plan_fn`` *at the arrival instant* (per-tenant
     decision order is therefore independent of co-tenants); a single
-    dispatcher, released by ingress credits each time the shared end
-    worker frees, picks the next tenant via the admission policy and
-    forwards the head task into the chain.  See the module docstring for
+    dispatcher, released by ingress credits each time a tier-0 replica
+    frees, picks the next tenant via the admission policy and forwards
+    the head task into the pipeline.  See the module docstring for
     the differential contract with ``core.sim``."""
 
     def __init__(self, n_hops: int, links=None, clock=None,
@@ -255,14 +254,13 @@ class MultiTenantHopPipeline:
                  batch_caps: Optional[Sequence[int]] = None,
                  pools=None, router=None, sink=None, migrate=None):
         # tier 0 never batches under multi-tenancy: admission is credit-
-        # gated one task at a time, so the ingress queue holds at most
-        # one task and a tier-0 drain would diverge from the admission
-        # gate (``sim.simulate_multitenant_stream`` applies the same
-        # clamp to stay pinned).  With ``pools=`` the ingress credit
-        # generalizes to *pool* ingress — every tier-0 replica issues a
-        # credit when it frees, so up to ``m`` tasks are admitted into
-        # the ingress pool at once (``sim.multitenant_pool_admission``
-        # computes the same gate as a min-heap of completion instants)
+        # gated one task per credit, so a tier-0 drain would diverge
+        # from the admission gate (``sim.simulate_multitenant_stream``
+        # applies the same clamp to stay pinned).  Every tier-0 replica
+        # issues a credit when it frees, so up to ``m`` tasks are
+        # admitted into the ingress pool at once
+        # (``sim.multitenant_admission`` computes the same gate as a
+        # min-heap of completion instants)
         if batch_caps is not None:
             batch_caps = [1] + [int(c) for c in batch_caps[1:]]
         # the migration hook is keyed by the *global admission slot*
@@ -320,7 +318,7 @@ class MultiTenantHopPipeline:
             async def dispatch():
                 admitted = 0
                 while admitted < total:
-                    await credits.get()   # shared end worker became free
+                    await credits.get()   # a tier-0 replica became free
                     await clock.settle()
                     while True:
                         cands = [t for t in range(n_t) if ready[t]]
@@ -350,9 +348,8 @@ class MultiTenantHopPipeline:
                     record(idx, arr)
                     if sink is not None:
                         # dispatch instant = the admission gate's t_d
-                        # (``sim.multitenant_admission_order`` /
-                        # ``multitenant_pool_admission`` compute the same
-                        # instants arithmetically)
+                        # (``sim.multitenant_admission`` computes the
+                        # same instants arithmetically)
                         if clock.now > arr:
                             sink.span(Span(CREDIT_WAIT, ("compute", 0),
                                            arr, clock.now, task=idx))
@@ -385,10 +382,6 @@ class MultiTenantHopPipeline:
                 raise errs[0]
 
         res = self.pipe.run(None, total, None, admit_fn=admit_fn)
-        if isinstance(res, sim.PoolStreamResult):
-            return sim.MultiTenantPoolStreamResult(
-                stream=res.as_stream_result(), order=tuple(order),
-                n_tenants=n_t, pool=res)
         return sim.MultiTenantStreamResult(stream=res, order=tuple(order),
                                            n_tenants=n_t)
 
@@ -402,13 +395,13 @@ def run_multitenant_async(plans_by_tenant: Sequence[Sequence[TaskPlan]],
                           batch_caps: Optional[Sequence[int]] = None,
                           pools=None, router=None, sink=None, migrate=None
                           ) -> sim.MultiTenantStreamResult:
-    """Async-executor counterpart of ``sim.simulate_multitenant_stream``
-    (or, with ``pools=``, of ``sim.simulate_multitenant_pool_stream``):
+    """Async-executor counterpart of ``sim.simulate_multitenant_stream``:
     same plan normalization, same result type, but the merged stream is
     *executed* by per-resource workers behind a policy dispatcher.  With
     unbounded queues and a ``VirtualClock`` the two admission orders and
-    timelines agree to float precision (including per-tier micro-batching
-    via ``batch_caps``; tier 0 is clamped to cap 1 on both sides)."""
+    timelines agree to float precision (including replicated tiers via
+    ``pools`` + ``router`` and per-tier micro-batching via
+    ``batch_caps``; tier 0 is clamped to cap 1 on both sides)."""
     if links is None:
         links = [None]
     n_hops = max(max(p.n_hops for ps in plans_by_tenant for p in ps),
@@ -661,11 +654,7 @@ class MultiTenantCoachEngine:
                                      for rec in pr.tasks]))
             reports.append(TenantReport(spec=spec, stats=stats,
                                         slo_attainment=slo))
-        if isinstance(mt, sim.MultiTenantPoolStreamResult) \
-                and mt.pool is not None:
-            merged = result_from_pool_stream(mt.pool)
-        else:
-            merged = result_from_stream(mt.stream)
+        merged = result_from_stream(mt.stream)
         if self.cfg.metrics is not None:
             # once, from the merged chain view (the per-tenant engines
             # run with metrics=None — see __init__)
@@ -680,7 +669,7 @@ class MultiTenantCoachEngine:
                 populate_from_trace(reg, trace)
                 populate_from_attribution(reg, attribute(
                     trace, resources=chain_resources(
-                        merged.n_hops, merged.pool_sizes or None)))
+                        merged.n_hops, merged.pool_sizes)))
         return MultiTenantStats(
             pipeline=merged, order=mt.order,
             reports=reports, policy=self.policy.name,

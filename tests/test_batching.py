@@ -8,8 +8,9 @@ helpers ``sim.greedy_batch_size`` / ``sim.batched_service_time`` make
 the float arithmetic identical — so their timelines agree to 1e-6 on
 2-/3-hop chains, caps {1, 2, 4, mixed}, mid-pipeline exits, staleness
 deadlines, and dynamic-bandwidth links.  ``cap = 1`` must reproduce the
-unbatched replay bit-identically (singleton batches fall through to the
-legacy code paths on both sides).
+unbatched replay bit-identically (singleton batches take the same
+singleton path on both sides), and equal the serial-chain reference
+replay (``tests/chain_reference.py``).
 
 On top of that: ``HopQueue.get_nowait/drain/snapshot`` semantics
 (including the drain-must-snapshot-at-wake race the batching worker
@@ -37,6 +38,7 @@ from repro.serving.batching import (auto_batch_caps, find_batch_cap,
 from repro.serving.engine import CoachEngine
 from repro.serving.tenancy import (MultiTenantCoachEngine, TenantSpec,
                                    make_policy, run_multitenant_async)
+from tests.chain_reference import serial_chain
 from tests.test_async_engine import _assert_timelines_agree
 
 TOL = 1e-6
@@ -151,8 +153,8 @@ def test_differential_batched_burst_arrivals():
 @pytest.mark.parametrize("n_hops", [2, 3])
 def test_cap_one_is_bit_identical_to_unbatched(n_hops):
     """Acceptance: ``batch_caps`` of all ones reproduces today's
-    timelines *bit-identically* (not 1e-6) — the batched entry point
-    routes to the untouched legacy replay."""
+    timelines *bit-identically* (not 1e-6) — every batch is a
+    singleton, priced exactly like an unbatched task."""
     for seed in range(3):
         plans = _batched_plans(seed, n_hops=n_hops)
         a = run_pipeline(plans, arrival_period=2e-3)
@@ -170,16 +172,17 @@ def test_cap_one_is_bit_identical_to_unbatched(n_hops):
 
 
 def test_staged_replay_all_ones_matches_legacy_bitwise():
-    """The staged tier-by-tier batched replay with every cap at 1 uses
-    the same float expressions as the classic interleaved loop: the
-    timelines are equal with ``==`` on the seeds pinned here."""
+    """The staged tier-by-tier replay with every cap at 1 uses the same
+    float expressions as the task-by-task serial-chain reference loop:
+    the timelines are equal with ``==`` on the seeds pinned here."""
     for seed in range(3):
         plans = [p.as_sim_plan(2)
                  for p in _batched_plans(seed + 20, n_hops=2)]
         arrivals = [i * 2e-3 for i in range(len(plans))]
-        a = sim.simulate_stream(plans, arrivals)
-        b = sim._simulate_stream_batched(plans, arrivals, None, [1, 1, 1])
+        a = serial_chain(plans, arrivals)
+        b = sim.simulate_stream(plans, arrivals, batch_caps=[1, 1, 1])
         assert a.done == b.done
+        assert a.compute_busy == b.compute_busy
         assert a.compute_intervals == b.compute_intervals
         assert a.link_intervals == b.link_intervals
 

@@ -129,7 +129,7 @@ def test_multitenant_pool_ingress_credit_reachable():
     pools = [PoolSpec(speeds=(0.2, 1.0)), PoolSpec(speeds=(1.0,))]
     arr = [[0.0] * 6]
     ts, ta = TraceRecorder(), TraceRecorder()
-    S.simulate_multitenant_pool_stream(
+    S.simulate_multitenant_stream(
         [[p.as_sim_plan(1) for p in mk]], arr, policy=make_policy("fifo"),
         pools=pools, router=make_router("jsq", seed=0), sink=ts)
     run_multitenant_async([list(mk)], arr, policy="fifo",

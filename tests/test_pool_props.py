@@ -4,11 +4,12 @@ The main property is the PR's acceptance invariant at generative scale:
 for any drawn stream (task count, service times, hop exits), pool shape
 (replica counts, heterogeneous speeds), and router policy, the async
 pool executor under the virtual clock reproduces
-``sim.simulate_pool_stream`` — completions, routes, per-replica busy
-intervals — to 1e-6; single-replica pools reduce bit-identically to the
-serial chain.  The cold-cache exit rule is also pinned generatively: no
-scheduler configuration may terminate a task while fewer than two labels
-are warm.  (Module is collect-ignored by ``conftest.py`` when hypothesis
+``sim.simulate_stream`` — completions, routes, per-replica busy
+intervals — to 1e-6; single-replica pools equal the serial-chain
+reference replay (``tests/chain_reference.py``) bit for bit.  The
+cold-cache exit rule is also pinned generatively: no scheduler
+configuration may terminate a task while fewer than two labels are
+warm.  (Module is collect-ignored by ``conftest.py`` when hypothesis
 is not installed.)
 """
 
@@ -20,6 +21,7 @@ from repro.core import online as ON
 from repro.core import sim
 from repro.serving.async_engine import AsyncHopPipeline, VirtualClock
 from repro.serving.routing import ROUTER_POLICIES, make_router
+from tests.chain_reference import serial_chain
 
 TOL = 1e-6
 
@@ -58,8 +60,8 @@ def pool_scenarios(draw):
 def test_pool_executor_pinned_to_simulator(sc):
     plans, arr, pools, policy, seed = sc
     n_hops = len(plans[0].tx)
-    ps = sim.simulate_pool_stream(plans, arr, pools,
-                                  make_router(policy, seed=seed))
+    ps = sim.simulate_stream(plans, arr, pools=pools,
+                             router=make_router(policy, seed=seed))
     pipe = AsyncHopPipeline(n_hops, clock=VirtualClock(), pools=pools,
                             router=make_router(policy, seed=seed))
     pa = pipe.run(lambda i, _a: plans[i], len(plans), arr)
@@ -79,12 +81,14 @@ def test_pool_executor_pinned_to_simulator(sc):
 def test_m1_pool_is_bitwise_chain(sc):
     plans, arr, pools, policy, _seed = sc
     m1 = [1] * len(pools)
-    ref = sim.simulate_stream(plans, arr)
-    res = sim.simulate_pool_stream(plans, arr, m1, make_router(policy))
-    sr = res.as_stream_result()
-    assert sr.done == ref.done
-    assert sr.compute_intervals == ref.compute_intervals
-    assert sr.link_intervals == ref.link_intervals
+    ref = serial_chain(plans, arr)
+    res = sim.simulate_stream(plans, arr, pools=m1,
+                              router=make_router(policy))
+    assert res.done == ref.done
+    assert res.compute_busy == ref.compute_busy
+    assert res.link_busy == ref.link_busy
+    assert res.compute_intervals == ref.compute_intervals
+    assert res.link_intervals == ref.link_intervals
 
 
 @settings(max_examples=40, deadline=None)
@@ -94,8 +98,8 @@ def test_pool_routes_are_valid_and_conserving(sc):
     replica; tiers past a hop exit are never routed; replica interval
     counts sum to the tier's task load."""
     plans, arr, pools, policy, seed = sc
-    res = sim.simulate_pool_stream(plans, arr, pools,
-                                   make_router(policy, seed=seed))
+    res = sim.simulate_stream(plans, arr, pools=pools,
+                              router=make_router(policy, seed=seed))
     for p, rt in zip(plans, res.routes):
         for k, r in enumerate(rt):
             if sim.occupies_compute(p.exit_hop, k):

@@ -3,13 +3,14 @@
 The tentpole contract of the pool DAG: per-tier replica pools
 (``sim.PoolSpec``, heterogeneous speeds allowed) behind a pluggable
 router (``serving.routing``) must time identically in the arithmetic
-simulator (``sim.simulate_pool_stream``: staged dispatch -> per-replica
+simulator (``sim.simulate_stream``: staged dispatch -> per-replica
 replay -> sequencer) and the event-driven executor
 (``AsyncHopPipeline(pools=...)``: dispatcher / replica / sequencer
 workers under the virtual clock) — completion times, routes, and
 per-replica busy intervals to 1e-6, across every router policy and
-``m in {1, 2, 4}``.  An ``m = 1`` pool must reduce *bit-identically* to
-the legacy serial chain.  Micro-batching (per-tier caps) composes with
+``m in {1, 2, 4}``.  ``m = 1`` pools must equal the serial-chain
+reference replay (``tests/chain_reference.py``) *bit for bit*, and
+their traces carry no ``route`` span.  Micro-batching (per-tier caps) composes with
 replication on both sides.
 
 Deterministic regression tests for the two ``core.online`` bugfixes ride
@@ -23,8 +24,8 @@ import pytest
 
 from repro.core import online as ON
 from repro.core import sim
-from repro.core.pipeline import (TaskPlan, result_from_pool_stream,
-                                 run_pipeline)
+from repro.core.pipeline import TaskPlan, result_from_stream, run_pipeline
+from repro.obs.trace import ROUTE, TraceRecorder, assert_traces_match
 from repro.serving.async_engine import (AsyncCoachEngine, AsyncHopPipeline,
                                         VirtualClock, run_pipeline_async)
 from repro.serving.base import EngineConfig
@@ -32,6 +33,7 @@ from repro.serving.engine import CoachEngine
 from repro.serving.routing import (ROUTER_POLICIES, RouterPolicy,
                                    make_router)
 from repro.serving.tenancy import MultiTenantHopPipeline, make_policy
+from tests.chain_reference import serial_chain
 from tests.test_async_engine import (_random_multihop_plans,
                                      _random_single_hop_plans)
 from tests.test_batching import _batched_plans
@@ -51,15 +53,14 @@ def _pin_pool(plans, arrivals, pools, policy, n_hops, seed=0, links=None,
     """Run both sides on identical inputs and assert the timelines and
     placements agree to ``tol``."""
     sps = _sim_plans(plans, n_hops)
-    ps = sim.simulate_pool_stream(sps, arrivals, pools,
-                                  make_router(policy, seed=seed),
-                                  links=links, batch_caps=batch_caps)
+    ps = sim.simulate_stream(sps, arrivals, links=links, pools=pools,
+                             router=make_router(policy, seed=seed),
+                             batch_caps=batch_caps)
     pipe = AsyncHopPipeline(n_hops, links=links, clock=VirtualClock(),
                             pools=pools,
                             router=make_router(policy, seed=seed),
                             batch_caps=batch_caps)
     pa = pipe.run(lambda i, _a: sps[i], len(sps), arrivals)
-    assert isinstance(pa, sim.PoolStreamResult)
     assert ps.routes == pa.routes
     for a, b in zip(ps.done, pa.done):
         assert abs(a - b) <= tol
@@ -103,23 +104,24 @@ def test_make_router_names_and_passthrough():
 @pytest.mark.parametrize("n_hops", [1, 2, 3])
 def test_m1_pools_reduce_bitwise_to_chain(n_hops):
     """Single-replica pools are the serial chain, *bit-identically*: the
-    staged pool replay takes the same float expressions (``1.0 * x`` is
-    exact), and one serial replica's release instants are monotone, so
-    the sequencer never delays a forward."""
+    staged replay takes the same float expressions as the task-by-task
+    reference loop (``1.0 * x`` is exact), and one serial replica's
+    release instants are monotone, so the sequencer never delays a
+    forward.  The router is never consulted."""
     plans = _random_multihop_plans(11, n=40, n_hops=n_hops) if n_hops > 1 \
         else _random_single_hop_plans(11, n=40)
     sps = _sim_plans(plans, n_hops)
     arr = [i * 1.5e-3 for i in range(len(sps))]
-    ref = sim.simulate_stream(sps, arr)
-    for policy in POLICIES:
-        res = sim.simulate_pool_stream(sps, arr, [1] * (n_hops + 1),
-                                       make_router(policy))
-        sr = res.as_stream_result()
-        assert sr.done == ref.done                      # bitwise
-        assert sr.compute_busy == ref.compute_busy
-        assert sr.link_busy == ref.link_busy
-        assert sr.compute_intervals == ref.compute_intervals
-        assert sr.link_intervals == ref.link_intervals
+    ref = serial_chain(sps, arr)
+    for pools, router in [(None, None)] + [
+            ([1] * (n_hops + 1), make_router(policy))
+            for policy in POLICIES]:
+        res = sim.simulate_stream(sps, arr, pools=pools, router=router)
+        assert res.done == ref.done                      # bitwise
+        assert res.compute_busy == ref.compute_busy
+        assert res.link_busy == ref.link_busy
+        assert res.compute_intervals == ref.compute_intervals
+        assert res.link_intervals == ref.link_intervals
         # every tier a task reached placed it on the only replica
         assert all(r in (None, 0) for rt in res.routes for r in rt)
 
@@ -185,10 +187,9 @@ def test_pool_throughput_scales_on_bottleneck_tier():
                        tx_offset=(None, None), rx_offset=(None, None))
            for _ in range(n)]
     arr = [i * 0.1e-3 for i in range(n)]
-    t1 = sim.simulate_pool_stream(sps, arr, [1, 1, 1],
-                                  make_router("jsq")).makespan
-    t2 = sim.simulate_pool_stream(sps, arr, [1, 2, 1],
-                                  make_router("jsq")).makespan
+    t1 = sim.simulate_stream(sps, arr).makespan
+    t2 = sim.simulate_stream(sps, arr, pools=[1, 2, 1],
+                             router=make_router("jsq")).makespan
     assert t1 / t2 >= 1.8
 
 
@@ -196,12 +197,12 @@ def test_pool_throughput_scales_on_bottleneck_tier():
 def test_pool_stream_result_tier_view_and_bubbles():
     plans = _random_multihop_plans(3, n=30, n_hops=2)
     arr = [i * 0.5e-3 for i in range(len(plans))]
-    res = sim.simulate_pool_stream(_sim_plans(plans, 2), arr, [1, 2, 1],
-                                   make_router("jsq"))
+    res = sim.simulate_stream(_sim_plans(plans, 2), arr, pools=[1, 2, 1],
+                              router=make_router("jsq"))
     # tier busy = sum of its replicas
     for k in range(3):
         assert abs(res.compute_busy[k] - sum(res.replica_busy[k])) < 1e-12
-    pr = result_from_pool_stream(res)
+    pr = result_from_stream(res)
     assert pr.pool_sizes == (1, 2, 1)
     # utilization judged against m * makespan keeps bubbles in [0, 1]
     for k in range(3):
@@ -214,11 +215,50 @@ def test_run_pipeline_pool_path_matches_pool_sim():
     arr = [i * 1e-3 for i in range(len(plans))]
     pr = run_pipeline(plans, arrivals=arr, links=[None, None],
                       pools=[1, 2, 1], router=make_router("po2", seed=2))
-    ref = sim.simulate_pool_stream(_sim_plans(plans, 2), arr, [1, 2, 1],
-                                   make_router("po2", seed=2))
+    ref = sim.simulate_stream(_sim_plans(plans, 2), arr, pools=[1, 2, 1],
+                              router=make_router("po2", seed=2))
     assert pr.pool_sizes == (1, 2, 1)
     assert abs(pr.makespan - ref.makespan) < 1e-12
     assert [t.done for t in pr.tasks] == list(ref.done)
+
+
+@pytest.mark.parametrize("pools", [None, [1, 1, 1], [1, 2, 1]])
+def test_one_replica_tiers_trace_no_route_span(pools):
+    """Nothing is chosen on a tier of one replica, so neither engine
+    emits a ``route`` span there (a chain traces span for span as a
+    chain); tiers of ``m > 1`` route every task they serve, and the
+    two engines' traces still match."""
+    plans = _random_multihop_plans(17, n=24, n_hops=2)
+    arr = [i * 1e-3 for i in range(len(plans))]
+    router = (lambda: make_router("jsq")) if pools else (lambda: None)
+    ts, ta = TraceRecorder(), TraceRecorder()
+    pr = run_pipeline(plans, arrivals=arr, links=[None, None], pools=pools,
+                      router=router(), sink=ts)
+    run_pipeline_async(plans, arrivals=arr, links=[None, None],
+                       clock=VirtualClock(), pools=pools, router=router(),
+                       sink=ta)
+    assert_traces_match(ts, ta, tol=TOL)
+    routed = {s.resource[1] for s in ts.spans if s.kind == ROUTE}
+    assert routed == ({1} if pools == [1, 2, 1] else set())
+    n_routes = sum(1 for s in ts.spans if s.kind == ROUTE)
+    assert n_routes == (len(pr.compute_intervals[1])
+                        if pools == [1, 2, 1] else 0)
+
+
+@pytest.mark.parametrize("pools,caps", [([1, 2, 1], None),
+                                        (None, [1, 2, 1])])
+def test_migrate_refused_with_replicas_or_batching(pools, caps):
+    """The re-planning hook needs one replica and batch cap 1 on every
+    tier; both engines refuse it otherwise, before any task runs."""
+    plans = _random_multihop_plans(19, n=8, n_hops=2)
+    router = make_router("jsq") if pools else None
+    kw = dict(arrivals=[i * 1e-3 for i in range(len(plans))],
+              links=[None, None], pools=pools, batch_caps=caps,
+              migrate=lambda idx, k, tx_ready: None)
+    with pytest.raises(AssertionError, match="plan migration"):
+        run_pipeline(plans, router=router, **kw)
+    with pytest.raises(AssertionError, match="plan migration"):
+        run_pipeline_async(plans, router=router, clock=VirtualClock(), **kw)
 
 
 def test_run_pipeline_async_pool_path_pinned_to_sync():
@@ -240,7 +280,7 @@ def test_run_pipeline_async_pool_path_pinned_to_sync():
 def test_differential_multitenant_pool_pinned(policy):
     """Pool ingress credits (a token whenever *any* tier-0 replica
     frees) generalize the single-replica credit gate: executor ==
-    ``simulate_multitenant_pool_stream`` on order + merged timeline."""
+    ``simulate_multitenant_stream`` on order + merged timeline."""
     rng = np.random.RandomState(29)
     n_hops, weights = 2, [1.0, 2.5, 0.5]
     plans, arrs = [], []
@@ -257,16 +297,16 @@ def test_differential_multitenant_pool_pinned(policy):
         plans.append(ps)
         arrs.append(ar)
     pools = [2, 2, 1]
-    mt_sim = sim.simulate_multitenant_pool_stream(
-        plans, arrs, make_policy(policy, weights=weights), pools,
-        make_router("jsq", seed=4))
+    mt_sim = sim.simulate_multitenant_stream(
+        plans, arrs, make_policy(policy, weights=weights), pools=pools,
+        router=make_router("jsq", seed=4))
     pipe = MultiTenantHopPipeline(
         n_hops, clock=VirtualClock(),
         policy=make_policy(policy, weights=weights), pools=pools,
         router=make_router("jsq", seed=4))
     mt_ex = pipe.run([(lambda t: (lambda i, _a: plans[t][i]))(t)
                       for t in range(3)], arrs)
-    assert isinstance(mt_ex, sim.MultiTenantPoolStreamResult)
+    assert mt_ex.stream.routes == mt_sim.stream.routes
     assert mt_ex.order == mt_sim.order
     for a, b in zip(mt_sim.stream.done, mt_ex.stream.done):
         assert abs(a - b) <= TOL
@@ -280,12 +320,11 @@ def test_multitenant_pool_affinity_keeps_tenants_sticky():
               for _ in range(8)] for _ in range(2)]
     arrs = [[i * 0.4e-3 for i in range(8)],
             [0.1e-3 + i * 0.4e-3 for i in range(8)]]
-    res = sim.simulate_multitenant_pool_stream(
-        plans, arrs, make_policy("rr"), [1, 2], make_router("affinity"))
-    pool = res.pool
-    assert pool is not None
+    res = sim.simulate_multitenant_stream(
+        plans, arrs, make_policy("rr"), pools=[1, 2],
+        router=make_router("affinity"))
     by_tenant = {}
-    for (t, _i), rt in zip(res.order, pool.routes):
+    for (t, _i), rt in zip(res.order, res.stream.routes):
         by_tenant.setdefault(t, set()).add(rt[1])
     assert all(len(reps) == 1 for reps in by_tenant.values())
     assert by_tenant[0] != by_tenant[1]   # JSQ seeding spread them

@@ -49,7 +49,7 @@ def tenant_mixes(draw):
 @given(mix=tenant_mixes(), policy=st.sampled_from(["fifo", "rr", "wdrr"]))
 def test_admission_order_conserves_tasks_and_fifo(mix, policy):
     plans, arrivals, weights = mix
-    order = sim.multitenant_admission_order(
+    order, _ = sim.multitenant_admission(
         plans, arrivals, make_policy(policy, weights=weights))
     expected = {(t, i) for t in range(len(plans))
                 for i in range(len(plans[t]))}
@@ -132,7 +132,7 @@ def test_batched_multitenant_conserves_tasks_and_stream_order(mix, policy):
 @settings(max_examples=30, deadline=None)
 @given(mix=batched_mixes(), policy=st.sampled_from(["fifo", "rr", "wdrr"]))
 def test_cap_one_multitenant_is_decision_identical(mix, policy):
-    """All-ones caps route to the untouched legacy replay: admission
+    """All-ones caps take the singleton path of every tier: admission
     order and timelines are *bitwise* equal to running without caps
     (policies are stateful, so each run gets a fresh instance)."""
     plans, arrivals, weights, caps = mix
