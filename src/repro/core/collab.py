@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.kernels import grouped_matmul as GM
@@ -158,16 +159,56 @@ class WirePacket:
             out_dtype=out_dtype, channels=self.channels)
 
 
-@dataclasses.dataclass
 class BoundaryProbe:
     """Semantic-probe outputs of one fused boundary pass (Eq. 8-9 on the
     GAP feature, computed in the same HBM read that quantized the wire
-    packet).  ``best`` indexes into the ``centers`` matrix the pass was
-    given (the caller's trained-center view, not the full label space)."""
-    feat: jnp.ndarray  # (B, D) GAP features (feeds Eq. 7 center updates)
-    sep: jnp.ndarray   # (B,)  task separability (Eq. 9)
-    best: jnp.ndarray  # (B,)  int32 argmax similarity (Eq. 10)
-    sims: jnp.ndarray  # (B, L) similarity degrees in [0, 1] (Eq. 8)
+    packet), as host data.  ``best`` indexes into the ``centers`` matrix
+    the pass was given (the caller's trained-center view, not the full
+    label space).
+
+    The probe starts the host copy of each device output when it is made,
+    right after the pass is dispatched, so the copies land while the next
+    tier computes.  The first read of any field waits for all four at
+    once (one ``coach.d2h`` span, site ``probe``); every field is then a
+    numpy array that keeps the batch dimension."""
+
+    __slots__ = ("_out", "_host")
+
+    def __init__(self, feat, sep, best, sims):
+        self._out = (feat, sep, best, sims)
+        self._host = None
+        for x in self._out:
+            x.copy_to_host_async()
+
+    def _fetch(self) -> tuple:
+        if self._host is None:
+            if served.on():
+                with served.span(served.D2H, site="probe"):
+                    self._host = jax.device_get(self._out)
+            else:
+                self._host = jax.device_get(self._out)
+            self._out = None
+        return self._host
+
+    @property
+    def feat(self) -> np.ndarray:
+        """(B, D) GAP features (feeds Eq. 7 center updates)."""
+        return self._fetch()[0]
+
+    @property
+    def sep(self) -> np.ndarray:
+        """(B,) task separability (Eq. 9)."""
+        return self._fetch()[1]
+
+    @property
+    def best(self) -> np.ndarray:
+        """(B,) int32 argmax similarity (Eq. 10)."""
+        return self._fetch()[2]
+
+    @property
+    def sims(self) -> np.ndarray:
+        """(B, L) similarity degrees in [0, 1] (Eq. 8)."""
+        return self._fetch()[3]
 
 
 class CollabRuntime:
@@ -326,7 +367,8 @@ class CollabRuntime:
         with a single HBM read of the boundary activation.  Returns the
         hop-0 wire packet and the probe outputs (GAP feature included),
         instead of the raw activation the classic ``end_step`` hands
-        back for a second probe read."""
+        back for a second probe read.  The probe is host data, fetched
+        while the next tier computes (``BoundaryProbe``)."""
         return self.segment_step(0, inputs, bits=bits, centers=centers)
 
     def probe(self, h, centers):

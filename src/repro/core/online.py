@@ -171,32 +171,22 @@ class ProbeResult:
         space.  ``valid`` is the trained-label index map; with fewer
         than two trained centers there is no genuine second-highest
         degree, so the separability is forced to 0 (never
-        exit-eligible), matching ``separability``.  Each of the three
-        reads is a device-to-host pull when the inputs live on a device."""
+        exit-eligible), matching ``separability``.  The inputs are host
+        data: a ``BoundaryProbe``'s fields, already fetched."""
         if served.on():
             with served.span(served.PROBE_RESULT):
-                return cls._lift(sims, sep, best, valid, n_labels, _pull)
-        return cls._lift(sims, sep, best, valid, n_labels, _read)
+                return cls._lift(sims, sep, best, valid, n_labels)
+        return cls._lift(sims, sep, best, valid, n_labels)
 
     @classmethod
-    def _lift(cls, sims, sep, best, valid, n_labels: int, pull
-              ) -> "ProbeResult":
+    def _lift(cls, sims, sep, best, valid, n_labels: int) -> "ProbeResult":
         full = np.zeros(n_labels)
         valid = np.asarray(valid)
         if valid.size:
-            full[valid] = pull("sims", np.asarray, sims)
-        b = int(valid[pull("best", int, best)]) if valid.size else 0
-        s = pull("sep", float, sep) if valid.size >= 2 else 0.0
+            full[valid] = np.asarray(sims)
+        b = int(valid[int(best)]) if valid.size else 0
+        s = float(sep) if valid.size >= 2 else 0.0
         return cls(sims=full, sep=s, best=b)
-
-
-def _read(site: str, read, x):
-    return read(x)
-
-
-def _pull(site: str, read, x):
-    with served.span(served.D2H, site=site):
-        return read(x)
 
 
 @dataclasses.dataclass

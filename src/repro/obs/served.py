@@ -21,7 +21,8 @@ vocabulary):
 ``coach.dequantize``     dispatch of the wire dequantize
 ``coach.probe_result``   lifting the fused probe to label space
 ``coach.d2h``            one device-to-host pull: the wait plus the copy
-                         (meta ``site``)
+                         (meta ``site``; ``probe`` for the four outputs
+                         of a fused boundary pass, fetched together)
 ``coach.gc``             a collector pause (meta ``generation``)
 ``coach.experts``        one expert layer's routing in the request: tokens
                          per expert (meta ``loads``, a tuple), the

@@ -169,6 +169,15 @@ def test_end_step_fused_matches_classic_hop():
         np.asarray(rt.cloud_step(pkt_f)), np.asarray(rt.cloud_step(pkt_c)))
 
 
+PROBE_FIELDS = ("feat", "sep", "best", "sims")
+
+
+def _host_fields(probe):
+    got = [getattr(probe, f) for f in PROBE_FIELDS]
+    assert all(type(a) is np.ndarray for a in got)
+    return got
+
+
 def test_segment_handle_fused_delivers_probe():
     cfg, rt = _runtime()
     x = _inputs(cfg, jax.random.PRNGKey(3))
@@ -182,8 +191,84 @@ def test_segment_handle_fused_delivers_probe():
     pkt_f, probe = rt.end_step_fused(x, centers)
     np.testing.assert_array_equal(np.asarray(pkt.payload),
                                   np.asarray(pkt_f.payload))
-    np.testing.assert_array_equal(np.asarray(seen[0].sims),
-                                  np.asarray(probe.sims))
+    for got, want in zip(_host_fields(seen[0]), _host_fields(probe)):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_fused_probe_is_host_data_of_the_pass():
+    """The probe's fields are numpy arrays equal, bit for bit, to the
+    boundary pass's device outputs on the same activation and centers."""
+    cfg, rt = _runtime()
+    x = _inputs(cfg, jax.random.PRNGKey(5))
+    centers = jax.random.normal(jax.random.PRNGKey(6), (5, cfg.d_model))
+    _, h = rt.segment_step(0, x)
+    want = ops.boundary_pass(h, centers, 8)[3:]
+    _, probe = rt.end_step_fused(x, centers, bits=8)
+    for got, ref_out in zip(_host_fields(probe), want):
+        assert got.shape == ref_out.shape and got.dtype == ref_out.dtype
+        np.testing.assert_array_equal(got, np.asarray(ref_out))
+
+
+def test_fused_probe_host_copy_starts_at_dispatch(monkeypatch):
+    """The four host copies are started before ``end_step_fused``
+    returns, and the fields read back what was copied."""
+    cfg, rt = _runtime()
+    x = _inputs(cfg, jax.random.PRNGKey(7))
+    centers = jax.random.normal(jax.random.PRNGKey(8), (3, cfg.d_model))
+    rt.end_step_fused(x, centers)                       # compiles
+    started = []
+    array_type = type(jnp.zeros(()))
+    start = array_type.copy_to_host_async
+    monkeypatch.setattr(array_type, "copy_to_host_async",
+                        lambda a: (started.append(a), start(a))[1])
+    _, probe = rt.end_step_fused(x, centers)
+    copied = list(started)
+    assert len(copied) == len(PROBE_FIELDS)
+    for got, dev in zip(_host_fields(probe), copied):
+        np.testing.assert_array_equal(got, np.asarray(dev))
+
+
+def test_fused_probe_waits_once_under_a_session(monkeypatch):
+    """Under a profiler session the first field read is the one pull, a
+    ``coach.d2h`` span with site ``probe``; later reads pull nothing."""
+    from repro.obs import served
+    cfg, rt = _runtime()
+    x = _inputs(cfg, jax.random.PRNGKey(11))
+    centers = jax.random.normal(jax.random.PRNGKey(12), (3, cfg.d_model))
+    _, probe = rt.end_step_fused(x, centers)
+    monkeypatch.setattr(served, "on", lambda: True)
+    served.clear()
+    try:
+        _host_fields(probe)
+        _host_fields(probe)
+        got = [(s.name, s.meta) for s in served.recorded().spans]
+    finally:
+        served.clear()
+    assert got == [(served.D2H, {"site": "probe"})]
+
+
+@pytest.mark.parametrize("n_valid", [1, 3])
+def test_probe_result_lifts_host_fields(n_valid, monkeypatch):
+    """``ProbeResult.from_fused`` lifts a probe's host fields to label
+    space under a profiler session without a ``coach.d2h`` span: the
+    fields were fetched before it runs."""
+    from repro.obs import served
+    sims = np.asarray([0.25, 0.75, 0.5][:n_valid], np.float32)
+    sep, best = np.float32(0.125), np.int32(n_valid - 1)
+    valid = np.asarray([4, 1, 6][:n_valid])
+    monkeypatch.setattr(served, "on", lambda: True)
+    served.clear()
+    try:
+        pr = ON.ProbeResult.from_fused(sims, sep, best, valid, n_labels=8)
+        names = [s.name for s in served.recorded().spans]
+    finally:
+        served.clear()
+    want = np.zeros(8)
+    want[valid] = sims
+    np.testing.assert_array_equal(pr.sims, want)
+    assert pr.best == valid[-1]
+    assert pr.sep == (0.125 if n_valid >= 2 else 0.0)
+    assert names == [served.PROBE_RESULT]
 
 
 # --------------------------------------- engine differential, fused probes
